@@ -131,13 +131,13 @@ def _time_dispatch(runner, repeats: int = TIMED_REPEATS) -> float:
     """Min-of-``repeats`` wall seconds of one synced dispatch of
     ``runner`` (the runner must return a device value to block on).
     The first (untimed) call absorbs the compile."""
-    from acg_tpu._platform import device_sync
+    import jax
 
-    device_sync(runner())
+    jax.block_until_ready(runner())
     ts = []
     for _ in range(max(int(repeats), 1)):
         t0 = time.perf_counter()
-        device_sync(runner())
+        jax.block_until_ready(runner())
         ts.append(time.perf_counter() - t0)
     return min(ts)
 
@@ -156,7 +156,6 @@ def _collective_program(mesh, kind: str, nbytes: int, reps: int):
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from acg_tpu._platform import shard_map as _sm
     from acg_tpu.parallel.mesh import PARTS_AXIS
 
     nparts = int(mesh.shape[PARTS_AXIS])
@@ -194,8 +193,8 @@ def _collective_program(mesh, kind: str, nbytes: int, reps: int):
         v = lax.fori_loop(0, int(reps), lambda i, v: round_(v), v)
         return v[None]
 
-    prog = jax.jit(_sm(body, mesh=mesh, in_specs=P(PARTS_AXIS),
-                       out_specs=P(PARTS_AXIS)))
+    prog = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(PARTS_AXIS),
+                                 out_specs=P(PARTS_AXIS), check_vma=False))
     x = jax.device_put(np.ones((nparts,) + shape, np.float32),
                        NamedSharding(mesh, P(PARTS_AXIS)))
     return (lambda: prog(x)), payload
@@ -249,7 +248,6 @@ def _dma_program(mesh, maxcnt: int, reps: int, interpret: bool,
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from acg_tpu._platform import shard_map as _sm
     from acg_tpu.parallel.halo_dma import dma_exchange
     from acg_tpu.parallel.mesh import PARTS_AXIS
 
@@ -269,8 +267,8 @@ def _dma_program(mesh, maxcnt: int, reps: int, interpret: bool,
         return out[None]
 
     pspec = P(PARTS_AXIS)
-    prog = jax.jit(_sm(body, mesh=mesh, in_specs=(pspec,) * 3,
-                       out_specs=pspec))
+    prog = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
+                                 out_specs=pspec, check_vma=False))
     sh = NamedSharding(mesh, pspec)
     sb = jax.device_put(np.ones((nparts, nparts, maxcnt), np.float32),
                         sh)

@@ -118,7 +118,7 @@ class ExitCode(enum.IntEnum):
     OK = 0
     FAILURE = 1                  # solve/config failure, agreed abort
     NOTHING_COMPARABLE = 2       # bench_diff: no case in common
-    BACKEND_UNAVAILABLE = 3      # bounded backend probe failed
+    BACKEND_UNAVAILABLE = 3      # accelerator backend init failed
     DRIFT = 7                    # --fail-on-drift: EWMA latency drift
     SLO_BREACH = 8               # --fail-on-slo: declared objective
     PEER_DEAD_INJECTED = 86      # peer:dead fault fired on this rank
@@ -137,7 +137,7 @@ EXIT_CONTRACT: tuple = (
     (ExitCode.NOTHING_COMPARABLE, "bench_diff",
      "no comparable case between captures"),
     (ExitCode.BACKEND_UNAVAILABLE, "cli",
-     "accelerator backend unavailable (bounded probe failed)"),
+     "accelerator backend unavailable (backend init failed)"),
     (ExitCode.DRIFT, "soak",
      "--fail-on-drift: EWMA solve latency drifted past the gate"),
     (ExitCode.SLO_BREACH, "observatory",

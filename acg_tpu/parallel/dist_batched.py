@@ -34,7 +34,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from acg_tpu._platform import shard_map as _shard_map
 from acg_tpu.errors import AcgError, ErrorCode, NotConvergedError
 from acg_tpu.ops.spmv import acc_dtype
 from acg_tpu.parallel.dist import DistributedProblem
@@ -424,9 +423,10 @@ class BatchedDistCGSolver:
                                   b, x0, atols, rtol, maxits,
                                   unbounded=unbounded, carry=cr)
 
-            return _shard_map(
+            return jax.shard_map(
                 smb, mesh=self.mesh, in_specs=specs,
                 out_specs=out_specs,
+                check_vma=False,
             )(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, atols,
               rtol, maxits, *extra)
 
@@ -549,20 +549,18 @@ class BatchedDistCGSolver:
                 jnp.asarray(crit.residual_atol, sdt),
                 jnp.asarray(crit.residual_rtol, sdt),
                 jnp.int32(crit.maxits))
-        from acg_tpu._platform import block_until_ready_works, device_sync
-        block_until_ready_works()
         t_warm = time.perf_counter()
         with telemetry.annotate("compile"):
             for _ in range(max(warmup, 0)):
-                device_sync(program(*args,
-                                    unbounded=crit.unbounded)[0])
+                jax.block_until_ready(program(*args,
+                                              unbounded=crit.unbounded)[0])
         if warmup > 0:
             telemetry.add_timing(st, "compile",
                                  time.perf_counter() - t_warm)
         t0 = time.perf_counter()
         with telemetry.annotate("solve"):
             out = program(*args, unbounded=crit.unbounded)
-            device_sync(out[0])
+            jax.block_until_ready(out[0])
         t_solve = time.perf_counter() - t0
         st.tsolve += t_solve
         telemetry.add_timing(st, "solve", t_solve)
@@ -656,7 +654,6 @@ class BatchedDistCGSolver:
         path."""
         from acg_tpu import checkpoint as ckpt_mod
         from acg_tpu import metrics, observatory, telemetry
-        from acg_tpu._platform import block_until_ready_works, device_sync
         cfg = self.ckpt
         st = self.stats
         prob = self.problem
@@ -727,7 +724,6 @@ class BatchedDistCGSolver:
                 st, "resume",
                 f"resumed batched dist solve ({nrhs} RHS) at "
                 f"iteration {consumed}")
-        block_until_ready_works()
         seq = 0
         nsnaps = 0
         ck_secs = 0.0
@@ -746,7 +742,7 @@ class BatchedDistCGSolver:
                 else:
                     res, tbuf, core = run(x_cur, abs_tol, 0.0, m,
                                           carry)
-                device_sync(res[0])
+                jax.block_until_ready(res[0])
                 k_chunk = int(res[2])
                 consumed += k_chunk
                 executed += k_chunk

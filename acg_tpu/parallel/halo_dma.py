@@ -48,9 +48,11 @@ transport (``halo.cu:41-107``).
 Validation status: the gating, routing, and barrier-count logic are all
 exercised in CI (interpret mode, uniform-gate rings plus randomized
 star/line/clustered topologies vs the xla transport); the compiled
-multi-chip path has NOT yet run on real ICI -- this build's environment
-exposes one chip -- so first contact on a pod slice should start with
-``--comm xla`` agreement checks at small sizes.
+kernel compiles for a described v5e:2x2 (``tests/test_tpu_compile.py``)
+and has run over real ICI on a single-controller 2x2 v5e host, matching
+the xla transport's iterations and error norms on a graph-partitioned
+2M-row solve (``chip_smoke.py --four-chips``, PR 21).  Multi-controller
+(one process per host) has not run on TPU.
 """
 
 from __future__ import annotations
@@ -74,13 +76,13 @@ def dma_transport_status(refresh: bool = False) -> tuple:
     transport in THIS process topology -- the conftest two-process-probe
     pattern, library-side.
 
-    Single-controller: available (the compiled put-with-signal path is
-    proven on silicon by ``scripts/dma_probe.py``; interpret mode is
-    CI-covered).  Multi-controller on TPU: unavailable -- the compiled
-    multi-chip path has never run on real ICI, and a wrong guess
-    deadlocks a pod, so the verdict is a self-describing downgrade, not
-    a probe.  Multi-controller off-TPU: the interpret emulation pairs
-    DMA ops with collectives, so the probe ATTEMPTS one tiny
+    Single-controller: available (the compiled path ran over ICI on
+    four v5e chips, ``chip_smoke.py --four-chips``; interpret mode is
+    CI-covered).  Multi-controller on TPU: unavailable -- that layout
+    has never run, and a wrong guess deadlocks a pod, so the verdict is
+    a self-describing downgrade, not a probe.  Multi-controller
+    off-TPU: the interpret emulation pairs DMA ops with collectives, so
+    the probe ATTEMPTS one tiny
     cross-process psum over a mesh with ONE DEVICE PER PROCESS (every
     controller reaches solver setup together, so the collective is
     matched) and then AGREES the verdict across controllers over the
@@ -99,15 +101,14 @@ def dma_transport_status(refresh: bool = False) -> tuple:
     if jax.devices()[0].platform == "tpu":
         _dma_status = (
             False,
-            "the compiled multi-chip put-with-signal path has never "
-            "run on real ICI (scripts/dma_probe.py pins the "
-            "single-chip lowering only)")
+            "the put-with-signal path has run single-controller "
+            "only (chip_smoke.py --four-chips); multi-controller "
+            "pods are unverified")
         return _dma_status
     try:
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-        from acg_tpu._platform import shard_map as _sm
         from acg_tpu.parallel.multihost import put_global
         # one device per PROCESS: jax.devices()[:n] can be all local,
         # which would probe nothing cross-process
@@ -116,8 +117,9 @@ def dma_transport_status(refresh: bool = False) -> tuple:
             by_proc.setdefault(d.process_index, d)
         devs = np.asarray([by_proc[p] for p in sorted(by_proc)])
         mesh = Mesh(devs, ("probe",))
-        f = jax.jit(_sm(lambda a: lax.psum(a, "probe"), mesh=mesh,
-                        in_specs=P("probe"), out_specs=P()))
+        f = jax.jit(jax.shard_map(lambda a: lax.psum(a, "probe"),
+                                  mesh=mesh, in_specs=P("probe"),
+                                  out_specs=P(), check_vma=False))
         a = put_global(np.ones((devs.size,), np.float32),
                        sharding=NamedSharding(mesh, P("probe")))
         np.asarray(f(a))
@@ -143,28 +145,13 @@ def dma_transport_status(refresh: bool = False) -> tuple:
     return _dma_status
 
 
-def _compiler_params(**kwargs):
-    """Mosaic compiler params across jax versions: the class was renamed
-    TPUCompilerParams -> CompilerParams and older ones lack
-    ``has_side_effects`` (safe to drop -- the exchange output is consumed
-    by the unpack gather, so the kernel is never dead code)."""
-    import dataclasses
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    fields = {f.name for f in dataclasses.fields(cls)}
-    return cls(**{k: v for k, v in kwargs.items() if k in fields})
-
-
 def _exchange_kernel(axis, use_barrier, gate_by_counts, scnt_ref, rcnt_ref,
                      sendbuf_ref, recvbuf_ref, send_sem, recv_sem):
     """Per-shard kernel: neighbourhood barrier, start every gated put
     (nbi-style, all in flight at once), then wait for sends and
     receives."""
     me = lax.axis_index(axis)
-    # static mesh size; lax.axis_size is missing on older runtimes, where
-    # psum of a Python scalar is the (statically folded) idiom
-    nparts = (lax.axis_size(axis) if hasattr(lax, "axis_size")
-              else lax.psum(1, axis))
+    nparts = lax.axis_size(axis)
 
     def want_send(q):
         if gate_by_counts:
@@ -245,9 +232,15 @@ def _exchange(sendbuf, send_counts, recv_counts, axis: str, interpret: bool,
         gate_by_counts = not interpret
     kernel = functools.partial(_exchange_kernel, axis, not interpret,
                                gate_by_counts)
-    return pl.pallas_call(
+    # one (1, width) plane per peer behind an UNTILED leading dim, width
+    # padded to the 128-lane tile: Mosaic refuses a DMA whose slice cuts
+    # a tile, and a row of a 2-D (nparts, maxcnt) HBM buffer is a 1-row
+    # slice of its (4, 128) tiling
+    width = maxcnt + (-maxcnt) % 128
+    planes = jnp.pad(sendbuf, ((0, 0), (0, width - maxcnt)))
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((nparts, maxcnt), sendbuf.dtype),
+        out_shape=jax.ShapeDtypeStruct((nparts, 1, width), sendbuf.dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # send_counts
             pl.BlockSpec(memory_space=pltpu.SMEM),   # recv_counts
@@ -258,10 +251,11 @@ def _exchange(sendbuf, send_counts, recv_counts, axis: str, interpret: bool,
             pltpu.SemaphoreType.DMA(()),             # send (shared)
             pltpu.SemaphoreType.DMA(()),             # recv (shared)
         ],
-        compiler_params=_compiler_params(has_side_effects=True,
-                                         collective_id=0),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True,
+                                             collective_id=0),
         interpret=interpret,
-    )(send_counts, recv_counts, sendbuf)
+    )(send_counts, recv_counts, planes.reshape(nparts, 1, width))
+    return out.reshape(nparts, width)[:, :maxcnt]
 
 
 def dma_exchange(sendbuf: jax.Array, send_counts: jax.Array,

@@ -60,3 +60,11 @@ def solve_mesh(nparts: int | None = None, devices=None) -> Mesh:
             f"controller processes; launch at most {nparts} controllers "
             f"(the MPI analog: fewer ranks than hosts)")
     return Mesh(np.array(chosen), (PARTS_AXIS,))
+
+
+def shard_devices(tree) -> list[int]:
+    """Sorted ids of the devices that hold a shard of the first array in
+    ``tree`` -- where the data actually lives, read from the array's
+    addressable shards rather than from the mesh it was meant for."""
+    leaf = jax.tree.leaves(tree)[0]
+    return sorted({int(sh.device.id) for sh in leaf.addressable_shards})

@@ -43,9 +43,7 @@ def initialize(coordinator: str | None = None,
     """
     import jax
 
-    from acg_tpu._platform import distributed_initialized
-
-    if distributed_initialized():
+    if jax.distributed.is_initialized():
         return
     kwargs = {}
     if coordinator is not None:

@@ -1008,7 +1008,6 @@ def build_dist_program(solver):
     import jax.numpy as _jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from acg_tpu._platform import shard_map as _shard_map
     from acg_tpu.parallel.dist import (make_dist_spmv,
                                        make_dist_spmv_overlapped)
     from acg_tpu.parallel.mesh import PARTS_AXIS
@@ -1139,9 +1138,10 @@ def build_dist_program(solver):
                               unbounded=unbounded,
                               needs_diff=needs_diff)
 
-        return _shard_map(
+        return jax.shard_map(
             smb,
             mesh=solver.mesh, in_specs=specs, out_specs=out_specs,
+            check_vma=False,
         )(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols, maxits,
           *extra)
 
@@ -1219,7 +1219,6 @@ def build_dist_segment_probes(solver, b_global, reps: int) -> list[tuple]:
     armed transport), and the psum reduction ladder."""
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from acg_tpu._platform import shard_map as _shard_map
     from acg_tpu.parallel.dist import (make_dist_spmv,
                                        make_dist_spmv_overlapped)
     from acg_tpu.parallel.halo import halo_exchange
@@ -1287,9 +1286,9 @@ def build_dist_segment_probes(solver, b_global, reps: int) -> list[tuple]:
             prog = jax.jit(lambda *a: shard(*a))
         else:
             pspec = P(axis)
-            prog = jax.jit(_shard_map(
+            prog = jax.jit(jax.shard_map(
                 shard, mesh=solver.mesh, in_specs=(pspec,) * 8,
-                out_specs=pspec))
+                out_specs=pspec, check_vma=False))
         return lambda: prog(la, ga, sidx, gsrc, gval, scnt, rcnt, b)
 
     def spmv_round(spmv, halo, pdot, pdotk, sdt):

@@ -1269,9 +1269,7 @@ def run_serve(args, argv: list) -> int:
     # the first trace; f32 requests keep their explicit dtype)
     import jax
 
-    from acg_tpu._platform import enable_compile_cache, \
-        honour_jax_platforms
-    honour_jax_platforms()
+    from acg_tpu._platform import enable_compile_cache
     jax.config.update("jax_enable_x64", True)
     enable_compile_cache()
     if args.chaos is not None:

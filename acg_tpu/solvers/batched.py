@@ -781,19 +781,17 @@ class BatchedCGSolver:
                 return out[0], out[1]
             return out, None
 
-        from acg_tpu._platform import block_until_ready_works, device_sync
-        block_until_ready_works()
         t_warm = time.perf_counter()
         with telemetry.annotate("compile"):
             for _ in range(max(warmup, 0)):
-                device_sync(run()[0].x)
+                jax.block_until_ready(run()[0].x)
         if warmup > 0:
             telemetry.add_timing(st, "compile",
                                  time.perf_counter() - t_warm)
         t0 = time.perf_counter()
         with telemetry.annotate("solve"):
             res, tbuf = run()
-            device_sync(res.x)
+            jax.block_until_ready(res.x)
         t_solve = time.perf_counter() - t0
         st.tsolve += t_solve
         telemetry.add_timing(st, "solve", t_solve)
@@ -901,7 +899,6 @@ class BatchedCGSolver:
         tolerances."""
         from acg_tpu import checkpoint as ckpt_mod
         from acg_tpu import metrics, observatory, telemetry
-        from acg_tpu._platform import block_until_ready_works, device_sync
         cfg = self.ckpt
         st = self.stats
         st.criteria = crit
@@ -944,7 +941,6 @@ class BatchedCGSolver:
                 st, "resume",
                 f"resumed batched solve ({nrhs} RHS) from snapshot at "
                 f"iteration {consumed}")
-        block_until_ready_works()
 
         def run(a, carry):
             out = _batched_cg_program(
@@ -975,7 +971,7 @@ class BatchedCGSolver:
                 else:
                     a = chunk_args(x_cur, abs_tol, 0.0, m)
                 res, tbuf, core = run(a, carry)
-                device_sync(res.x)
+                jax.block_until_ready(res.x)
                 k_chunk = int(res.k_total)
                 consumed += k_chunk
                 executed += k_chunk

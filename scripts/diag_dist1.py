@@ -37,11 +37,9 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
     from jax import lax
-    from acg_tpu._platform import shard_map as _shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from acg_tpu._platform import (device_sync, enable_compile_cache,
-                                   honour_jax_platforms)
+    from acg_tpu._platform import enable_compile_cache
     from acg_tpu.io.generators import poisson2d_coo, poisson_dia_device
     from acg_tpu.matrix import SymCsrMatrix
     from acg_tpu.ops.spmv import DiaMatrix, dia_mv, spmv
@@ -50,7 +48,6 @@ def main() -> int:
     from acg_tpu.partition import partition_rows
     from acg_tpu.solvers.stats import StoppingCriteria
 
-    honour_jax_platforms()  # JAX_PLATFORMS=cpu debug runs stay CPU
     enable_compile_cache()
     n = 2048
     planes, offsets, N = poisson_dia_device(n, 2, dtype=jnp.float32)
@@ -94,24 +91,24 @@ def main() -> int:
                 Ad = DiaMatrix(data=planes, offsets=offsets,
                                nrows=N, ncols_padded=N)
                 return cg_loop(lambda v: spmv(Ad, v), fdot, b, its)
-            return lambda its: device_sync(prog(A.data, b, its))
+            return lambda its: prog(A.data, b, its).block_until_ready()
         if variant == "single_dia":
             @functools.partial(jax.jit, static_argnames="its")
             def prog(planes, b, its):
                 return cg_loop(lambda v: dia_mv(planes, offsets, N, v),
                                fdot, b, its)
-            return lambda its: device_sync(prog(A.data, b, its))
+            return lambda its: prog(A.data, b, its).block_until_ready()
         if variant in ("smap_local", "smap_psum"):
             dot = fdot if variant == "smap_local" else pdot
 
             @functools.partial(jax.jit, static_argnames="its")
             def prog(planes, b, its):
-                return _shard_map(
+                return jax.shard_map(
                     lambda p_, b_: cg_loop(
                         lambda v: dia_mv(p_, offsets, N, v), dot, b_, its),
                     mesh=mesh, in_specs=(P(PARTS_AXIS), P(PARTS_AXIS)),
-                    out_specs=P(PARTS_AXIS))(planes, b)
-            return lambda its: device_sync(prog(planes_sh, b_sh, its))
+                    out_specs=P(PARTS_AXIS), check_vma=False)(planes, b)
+            return lambda its: prog(planes_sh, b_sh, its).block_until_ready()
         if variant == "smap_pad":
             def shard(p_, b_, its):
                 p_ = tuple(q[0] for q in p_)
@@ -121,11 +118,11 @@ def main() -> int:
 
             @functools.partial(jax.jit, static_argnames="its")
             def prog(planes, b, its):
-                return _shard_map(
+                return jax.shard_map(
                     functools.partial(shard, its=its),
                     mesh=mesh, in_specs=(P(PARTS_AXIS), P(PARTS_AXIS)),
-                    out_specs=P(PARTS_AXIS))(planes, b)
-            return lambda its: device_sync(prog(planes_st, b_st, its))
+                    out_specs=P(PARTS_AXIS), check_vma=False)(planes, b)
+            return lambda its: prog(planes_st, b_st, its).block_until_ready()
         if variant == "dist_fixed":
             rr, cc, vv, _ = poisson2d_coo(n)
             csr = SymCsrMatrix.from_coo(N, rr, cc, vv).to_csr()
@@ -136,7 +133,7 @@ def main() -> int:
             b_host = np.ones(N, np.float32)
 
             def run(its):
-                # solve() device_syncs its result internally
+                # solve() waits for its result internally
                 solver.solve(b_host,
                              criteria=StoppingCriteria(maxits=its),
                              host_result=False)

@@ -20,7 +20,6 @@ from acg_tpu.parallel.mesh import PARTS_AXIS, solve_mesh
 from acg_tpu.partition import partition_rows
 from acg_tpu.solvers import HostCGSolver, StoppingCriteria
 from jax.sharding import PartitionSpec as P
-from acg_tpu._platform import shard_map as _shard_map
 
 
 @pytest.fixture(scope="module")
@@ -59,11 +58,11 @@ def test_device_halo_exchange(problem2d, nparts):
         stacked[p, : s.nowned] = xg[s.global_ids[: s.nowned]]
 
     mesh = solve_mesh(nparts)
-    ghost = jax.jit(_shard_map(
+    ghost = jax.jit(jax.shard_map(
         lambda x, si, gs: halo_exchange(x[0], si[0], gs[0])[None],
         mesh=mesh,
         in_specs=(P(PARTS_AXIS),) * 3,
-        out_specs=P(PARTS_AXIS)))(
+        out_specs=P(PARTS_AXIS), check_vma=False))(
             jnp.asarray(stacked), halo.send_idx, halo.ghost_src)
     ghost = np.asarray(ghost)
     for p, s in enumerate(subs):

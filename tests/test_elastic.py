@@ -176,7 +176,7 @@ def test_cli_refuses_both_cadences():
 def test_fault_spec_str_roundtrips():
     for text in ("crash:exit@20", "sdc:flip@7:seed=99",
                  "spmv:nan@3:part=2", "peer:dead:proc=1",
-                 "solve:slow@10:secs=0.05", "backend:hang:secs=12"):
+                 "solve:slow@10:secs=0.05"):
         spec = faults.parse_fault_spec(text)
         assert faults.parse_fault_spec(str(spec)) == spec
 

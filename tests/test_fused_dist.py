@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from acg_tpu._platform import shard_map as _shard_map
 from acg_tpu.errors import AcgError
 from acg_tpu.io.generators import poisson2d_coo
 from acg_tpu.matrix import SymCsrMatrix
@@ -99,11 +98,11 @@ def test_split_spmv_bitwise_equals_unsplit(problem, comm):
         return split(x, la, ga, sidx, gsrc, gval, scnt, rcnt)[None]
 
     specs = (pspec,) * 8
-    yu = jax.jit(_shard_map(body_unsplit, mesh=s.mesh, in_specs=specs,
-                            out_specs=pspec))(
+    yu = jax.jit(jax.shard_map(body_unsplit, mesh=s.mesh, in_specs=specs,
+                               out_specs=pspec, check_vma=False))(
         la, ga3, sidx, gsrc, gval, scnt, rcnt, x)
-    ys = jax.jit(_shard_map(body_split, mesh=s.mesh, in_specs=specs,
-                            out_specs=pspec))(
+    ys = jax.jit(jax.shard_map(body_split, mesh=s.mesh, in_specs=specs,
+                               out_specs=pspec, check_vma=False))(
         la, ga4, sidx, gsrc, gval, scnt, rcnt, x)
     np.testing.assert_array_equal(np.asarray(ys), np.asarray(yu))
 

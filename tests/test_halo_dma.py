@@ -20,7 +20,6 @@ from acg_tpu.parallel.halo_dma import _exchange
 from acg_tpu.parallel.mesh import PARTS_AXIS, solve_mesh
 from acg_tpu.partition import partition_rows
 from acg_tpu.solvers.stats import StoppingCriteria
-from acg_tpu._platform import shard_map as _shard_map
 
 NDEV = len(jax.devices())
 
@@ -41,8 +40,8 @@ def test_exchange_routes_all_pairs():
     def body(sbuf, sc, rc):
         return _exchange(sbuf[0], sc[0], rc[0], PARTS_AXIS, True)[None]
 
-    f = jax.jit(_shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
-                              out_specs=pspec))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
+                                 out_specs=pspec, check_vma=False))
     out = np.asarray(f(jnp.asarray(sb), scnt, scnt))
     for p in range(nparts):
         for q in range(nparts):
@@ -73,8 +72,8 @@ def test_exchange_count_gating_ring():
         return _exchange(sbuf[0], sc[0], rc[0], PARTS_AXIS, True,
                          gate_by_counts=True)[None]
 
-    f = jax.jit(_shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
-                              out_specs=pspec))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
+                                 out_specs=pspec, check_vma=False))
     out = np.asarray(f(jnp.asarray(sb), jnp.asarray(scnt),
                        jnp.asarray(rcnt)))
     for p in range(nparts):
@@ -152,8 +151,8 @@ def test_exchange_count_gating_distance2():
         return _exchange(sbuf[0], sc[0], rc[0], PARTS_AXIS, True,
                          gate_by_counts=True)[None]
 
-    f = jax.jit(_shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
-                              out_specs=pspec))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
+                                 out_specs=pspec, check_vma=False))
     out = np.asarray(f(jnp.asarray(sb), jnp.asarray(scnt),
                        jnp.asarray(rcnt)))
     for p in range(nparts):
@@ -220,8 +219,8 @@ def test_halo_exchange_dma_parity_8part(small_problem):
         g_xla = jnp.where(gval, g_xla, 0)
         return g_dma[None], g_xla[None]
 
-    f = jax.jit(_shard_map(body, mesh=s.mesh, in_specs=(pspec,) * 6,
-                           out_specs=(pspec, pspec)))
+    f = jax.jit(jax.shard_map(body, mesh=s.mesh, in_specs=(pspec,) * 6,
+                              out_specs=(pspec, pspec), check_vma=False))
     g_dma, g_xla = f(sidx, gsrc, gval, scnt, rcnt, x)
     np.testing.assert_array_equal(np.asarray(g_dma), np.asarray(g_xla))
 
