@@ -23,6 +23,12 @@ they actually beat XLA fusion is *measured* (``scripts/bench_pallas.py``,
 BASELINE.md) -- the solvers select per measurement via
 ``kernels="pallas"``.
 
+Every ``pallas_call`` here passes a stable ``name=`` (``acg_dia_spmv``,
+``acg_dia_spmv_dot``, ``acg_dia_spmv_padded``, ``acg_fused_cg_a``/``_b``,
+``acg_fused_pipelined_update``, ``acg_stencil_spmv``): the compiled
+custom call, and so its event in a profiler capture, carries that name
+(``acg_dia_spmv.7``), whatever XLA numbers around it.
+
 The DISTRIBUTED fused tier (``kernels='fused'`` with ``--nparts``) does
 not use these single-device kernels: it is the recurrence builder's
 emission over the interior|border OVERLAPPED SpMV
@@ -385,6 +391,7 @@ def _dia_spmv_clustered(planes, offsets, x, central, far, Lpad, Rpad,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="acg_dia_spmv_dot" if with_dot else "acg_dia_spmv",
     )(x, *planes)
 
 
@@ -430,6 +437,7 @@ def _dia_spmv_padded(planes, offsets, x, L, R, interpret):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((npad,), x.dtype),
         interpret=interpret,
+        name="acg_dia_spmv_padded",
     )(xp, *planes)
     return y[:n]
 
@@ -589,6 +597,7 @@ def cg_phase_a(planes, offsets: tuple, r, p_old, gamma, gamma_prev,
                         pltpu.VMEM((win,), r.dtype),
                         pltpu.SemaphoreType.DMA((12,))],
         interpret=interpret,
+        name="acg_fused_cg_a",
     )(scal, r, p_old, *planes)
     return p, t, d[0]
 
@@ -636,6 +645,7 @@ def cg_phase_b(x, p, r, t, gamma, pdott, interpret: bool = False):
                    jax.ShapeDtypeStruct((n,), x.dtype),
                    jax.ShapeDtypeStruct((1,), jnp.float32)),
         interpret=interpret,
+        name="acg_fused_cg_b",
     )(scal, x, p, r, t)
     return xn, rn, g[0]
 
@@ -682,6 +692,7 @@ def fused_pipelined_update(x, r, w, p, t, z, q, alpha, beta,
         out_shape=tuple(jax.ShapeDtypeStruct((npad,), x.dtype)
                         for _ in range(6)),
         interpret=interpret,
+        name="acg_fused_pipelined_update",
     )(ab, *vecs)
     return tuple(o[:n] for o in outs)
 
@@ -764,6 +775,7 @@ def _stencil_poisson_call(x, n: int, dim: int, offsets: tuple,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((N,), x.dtype),
         interpret=interpret,
+        name="acg_stencil_spmv",
     )(x)
 
 

@@ -420,10 +420,11 @@ def matrix_index_bytes(A: DeviceMatrix) -> float:
 def spmv(A: DeviceMatrix, x: jax.Array) -> jax.Array:
     """y = A @ x for a device sparse matrix (jit-safe, differentiable).
 
-    Wrapped in a `jax.named_scope` so profiler traces show the SpMV as a
-    labelled range (the reference's NVTX tier, ``cgcuda.c:771-801``).
+    Wrapped in ``jax.named_scope("acg.spmv")`` so profiler traces show
+    the SpMV as a labelled range (the reference's NVTX tier,
+    ``cgcuda.c:771-801``).
     """
-    with jax.named_scope(f"spmv_{type(A).__name__}"):
+    with jax.named_scope("acg.spmv"):
         return _spmv(A, x)
 
 

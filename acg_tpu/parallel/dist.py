@@ -787,12 +787,14 @@ def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
 
     def dist_spmv(x_loc, la, ga, sidx, gsrc, gval, scnt, rcnt,
                   k=None, pidx=None):
-        if use_pallas:
-            y = dia_spmv(la, local_block.offsets, x_loc,
-                         interpret=pallas_interpret)
-        else:
-            y = local_block.shard_mv(la, x_loc)
+        with jax.named_scope("acg.spmv"):
+            if use_pallas:
+                y = dia_spmv(la, local_block.offsets, x_loc,
+                             interpret=pallas_interpret)
+            else:
+                y = local_block.shard_mv(la, x_loc)
         if halo.has_ghosts:
+            # both transports name their work acg.halo themselves
             if comm == "dma":
                 ghost = halo_exchange_dma(x_loc, sidx, gsrc, gval,
                                           scnt, rcnt,
@@ -801,7 +803,8 @@ def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
                 ghost = halo_exchange(x_loc, sidx, gsrc, axis)
             if fault is not None and k is not None:
                 ghost = fault.apply_halo(ghost, k, pidx)
-            y = y + ghost_block.shard_mv(ga, ghost)
+            with jax.named_scope("acg.spmv"):
+                y = y + ghost_block.shard_mv(ga, ghost)
         if fault is not None and k is not None:
             y = fault.apply_spmv(y, k, pidx)
         return y
@@ -923,11 +926,11 @@ def make_dist_spmv_overlapped(prob: "DistributedProblem", comm: str,
             else:
                 ghost = halo_exchange(x_loc, sidx, gsrc, axis)
         # 2. interior rows: zero ghost dependencies, free to overlap
-        with jax.named_scope("spmv_interior"):
+        with jax.named_scope("acg.spmv"):
             y_int = local_rows_mv(la, x_loc, irows)
         # 3+4. border finish: the border rows' local contribution plus
         #      the ghost contribution (which waits the recv side)
-        with jax.named_scope("spmv_border"):
+        with jax.named_scope("acg.spmv"):
             y_bor = local_rows_mv(la, x_loc, grows)
             y = jnp.zeros((nrows,), x_loc.dtype)
             y = y.at[irows].add(y_int, indices_are_sorted=True)
@@ -2692,11 +2695,11 @@ class DistCGSolver:
                                     scnt, rcnt))
             args = args + ((jnp.asarray(lam[0], sdt),
                             jnp.asarray(lam[1], sdt)),)
-        t_warm = time.perf_counter()
-        with telemetry.annotate("compile"):
-            for _ in range(max(warmup, 0)):
-                jax.block_until_ready(program(*args, **kwargs)[0])
         if warmup > 0:
+            t_warm = time.perf_counter()
+            with telemetry.annotate("compile"):
+                for _ in range(warmup):
+                    jax.block_until_ready(program(*args, **kwargs)[0])
             telemetry.add_timing(st, "compile",
                                  time.perf_counter() - t_warm)
 

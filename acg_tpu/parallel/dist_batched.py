@@ -100,7 +100,7 @@ def _halo_exchange_multi(X_loc, send_idx, ghost_src,
                          axis: str = PARTS_AXIS):
     """Multi-column halo exchange: the SAME single all_to_all as the
     single-RHS transport, its payload widened by the batch axis."""
-    with jax.named_scope("halo_exchange_multi"):
+    with jax.named_scope("acg.halo"):
         sendbuf = X_loc[send_idx]           # (nparts, maxcnt, B)
         recvbuf = lax.all_to_all(sendbuf, axis, split_axis=0,
                                  concat_axis=0, tiled=True)
@@ -549,12 +549,12 @@ class BatchedDistCGSolver:
                 jnp.asarray(crit.residual_atol, sdt),
                 jnp.asarray(crit.residual_rtol, sdt),
                 jnp.int32(crit.maxits))
-        t_warm = time.perf_counter()
-        with telemetry.annotate("compile"):
-            for _ in range(max(warmup, 0)):
-                jax.block_until_ready(program(*args,
-                                              unbounded=crit.unbounded)[0])
         if warmup > 0:
+            t_warm = time.perf_counter()
+            with telemetry.annotate("compile"):
+                for _ in range(warmup):
+                    jax.block_until_ready(
+                        program(*args, unbounded=crit.unbounded)[0])
             telemetry.add_timing(st, "compile",
                                  time.perf_counter() - t_warm)
         t0 = time.perf_counter()

@@ -110,7 +110,7 @@ def halo_exchange(x_loc: jax.Array, send_idx: jax.Array,
     Per shard: ``x_loc`` (nmax_owned,), ``send_idx`` (nparts, maxcnt),
     ``ghost_src`` (nmax_ghost,).  Returns the ghost vector (nmax_ghost,).
     """
-    with jax.named_scope("halo_exchange_xla"):
+    with jax.named_scope("acg.halo"):
         sendbuf = x_loc[send_idx]                   # pack: (nparts, maxcnt)
         recvbuf = lax.all_to_all(sendbuf, axis, split_axis=0, concat_axis=0,
                                  tiled=True)        # transport over ICI

@@ -254,6 +254,7 @@ def _exchange(sendbuf, send_counts, recv_counts, axis: str, interpret: bool,
         compiler_params=pltpu.CompilerParams(has_side_effects=True,
                                              collective_id=0),
         interpret=interpret,
+        name="acg_halo_dma",
     )(send_counts, recv_counts, planes.reshape(nparts, 1, width))
     return out.reshape(nparts, width)[:, :maxcnt]
 
@@ -292,8 +293,9 @@ def halo_exchange_dma(x_loc: jax.Array, send_idx: jax.Array,
     """
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    sendbuf = x_loc[send_idx]                    # pack (halo.cu:41-54)
-    recvbuf = _exchange(sendbuf, send_counts, recv_counts, axis,
-                        interpret)
-    ghost = recvbuf.reshape(-1)[ghost_src]       # unpack (halo.cu:94-107)
-    return jnp.where(ghost_valid, ghost, 0)
+    with jax.named_scope("acg.halo"):
+        sendbuf = x_loc[send_idx]                # pack (halo.cu:41-54)
+        recvbuf = _exchange(sendbuf, send_counts, recv_counts, axis,
+                            interpret)
+        ghost = recvbuf.reshape(-1)[ghost_src]   # unpack (halo.cu:94-107)
+        return jnp.where(ghost_valid, ghost, 0)

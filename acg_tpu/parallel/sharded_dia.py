@@ -101,7 +101,9 @@ class PallasRollSpmv:
     roll formulation's wraparound.
 
     Instances are used as the ``kernels`` static argument of the jitted
-    solve programs (identity-hashed: one compile per solver)."""
+    solve programs (identity-hashed: one compile per solver).  The
+    ppermutes and the window concatenation run under the device scope
+    ``acg.halo``, the kernel and the slice under ``acg.spmv``."""
 
     name = "pallas-roll"
 
@@ -123,19 +125,21 @@ class PallasRollSpmv:
         interpret = self.interpret
 
         def shard(planes, xl):
-            parts = []
-            if Lh:
-                # left halo = left neighbour's TAIL; shard 0 (no
-                # source pair) receives ppermute's zero fill
-                parts.append(jax.lax.ppermute(xl[nloc - Lh:], PARTS_AXIS,
-                                              self._fwd))
-            parts.append(xl)
-            if Rh:
-                parts.append(jax.lax.ppermute(xl[:Rh], PARTS_AXIS,
-                                              self._bwd))
-            xwin = jnp.concatenate(parts) if len(parts) > 1 else xl
-            y = dia_spmv(planes, offsets, xwin, interpret=interpret)
-            return jax.lax.slice(y, (Lh,), (Lh + nloc,))
+            with jax.named_scope("acg.halo"):
+                parts = []
+                if Lh:
+                    # left halo = left neighbour's TAIL; shard 0 (no
+                    # source pair) receives ppermute's zero fill
+                    parts.append(jax.lax.ppermute(xl[nloc - Lh:],
+                                                  PARTS_AXIS, self._fwd))
+                parts.append(xl)
+                if Rh:
+                    parts.append(jax.lax.ppermute(xl[:Rh], PARTS_AXIS,
+                                                  self._bwd))
+                xwin = jnp.concatenate(parts) if len(parts) > 1 else xl
+            with jax.named_scope("acg.spmv"):
+                y = dia_spmv(planes, offsets, xwin, interpret=interpret)
+                return jax.lax.slice(y, (Lh,), (Lh + nloc,))
 
         spec = P(PARTS_AXIS)
         return jax.shard_map(shard, mesh=self.mesh, in_specs=(spec, spec),

@@ -888,13 +888,17 @@ def classic_recurrence(ops: TierOps):
     def body(k, state):
         x, r, p, gamma = state
         t = ops.spmv(p, k)
-        pdott = ops.dot(p, t)
-        alpha = gamma / pdott
-        x = ops.store(x + alpha * p)
-        r = ops.store(r - alpha * t)
-        gamma_next = ops.dot(r, r)
-        beta = gamma_next / gamma
-        p_next = ops.store(r + beta * p)
+        with jax.named_scope("acg.dot"):
+            pdott = ops.dot(p, t)
+        with jax.named_scope("acg.update"):
+            alpha = gamma / pdott
+            x = ops.store(x + alpha * p)
+            r = ops.store(r - alpha * t)
+        with jax.named_scope("acg.dot"):
+            gamma_next = ops.dot(r, r)
+        with jax.named_scope("acg.update"):
+            beta = gamma_next / gamma
+            p_next = ops.store(r + beta * p)
         return (x, r, p_next, gamma_next)
     return body
 
@@ -906,17 +910,19 @@ def pipelined_recurrence(ops: TierOps, dot2):
     device, pdot2_fused on the mesh)."""
     def body(k, state):
         x, r, w, p, t, z, gamma_prev, alpha_prev = state
-        gamma, delta = dot2(r, r, w, r)
+        with jax.named_scope("acg.dot"):
+            gamma, delta = dot2(r, r, w, r)
         q = ops.spmv(w, k)
-        beta = gamma / gamma_prev
-        denom = delta - beta * (gamma / alpha_prev)
-        alpha = gamma / denom
-        z = ops.store(q + beta * z)
-        t = ops.store(w + beta * t)
-        p = ops.store(r + beta * p)
-        x = ops.store(x + alpha * p)
-        r = ops.store(r - alpha * t)
-        w = ops.store(w - alpha * z)
+        with jax.named_scope("acg.update"):
+            beta = gamma / gamma_prev
+            denom = delta - beta * (gamma / alpha_prev)
+            alpha = gamma / denom
+            z = ops.store(q + beta * z)
+            t = ops.store(w + beta * t)
+            p = ops.store(r + beta * p)
+            x = ops.store(x + alpha * p)
+            r = ops.store(r - alpha * t)
+            w = ops.store(w - alpha * z)
         return (x, r, w, p, t, z, gamma, alpha)
     return body
 
@@ -941,19 +947,20 @@ def _builder_cg_program(A, b, x0, res_atol, res_rtol, diff_atol,
     def dot2(a1, c1, a2, c2):
         return dot(a1, c1), dot(a2, c2)
 
-    bnrm2 = jnp.sqrt(dot(b, b))
-    x0nrm2 = jnp.sqrt(dot(x0, x0))
-    if pipelined:
-        r = b - ops.spmv(x0, None)
-        w = ops.spmv(r, None)
-        r0nrm2 = jnp.sqrt(dot(r, r))
-    else:
-        r = b - ops.spmv(x0, None)
-        p = r
-        gamma = dot(r, r)
-        r0nrm2 = jnp.sqrt(gamma)
-    res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-    diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
+    with jax.named_scope("acg.setup"):
+        bnrm2 = jnp.sqrt(dot(b, b))
+        x0nrm2 = jnp.sqrt(dot(x0, x0))
+        if pipelined:
+            r = b - ops.spmv(x0, None)
+            w = ops.spmv(r, None)
+            r0nrm2 = jnp.sqrt(dot(r, r))
+        else:
+            r = b - ops.spmv(x0, None)
+            p = r
+            gamma = dot(r, r)
+            r0nrm2 = jnp.sqrt(gamma)
+        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
+        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
     inf = jnp.asarray(jnp.inf, sdt)
     if pipelined:
         zeros = jnp.zeros_like(b)

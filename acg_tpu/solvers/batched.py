@@ -68,7 +68,7 @@ def spmv_multi(A: DeviceMatrix, X: jax.Array) -> jax.Array:
     tier's throughput lever.  Every device format is supported; the
     DIA path stays gather-free (statically-sliced 2-D views)."""
     adt = acc_dtype(X.dtype)
-    with jax.named_scope(f"spmv_multi_{type(A).__name__}"):
+    with jax.named_scope("acg.spmv"):
         if hasattr(A, "matfree_apply_multi"):
             # matrix-free operator tier (ops.operator): the batched
             # twin of the generated-plane apply -- the amortization is
@@ -781,11 +781,11 @@ class BatchedCGSolver:
                 return out[0], out[1]
             return out, None
 
-        t_warm = time.perf_counter()
-        with telemetry.annotate("compile"):
-            for _ in range(max(warmup, 0)):
-                jax.block_until_ready(run()[0].x)
         if warmup > 0:
+            t_warm = time.perf_counter()
+            with telemetry.annotate("compile"):
+                for _ in range(warmup):
+                    jax.block_until_ready(run()[0].x)
             telemetry.add_timing(st, "compile",
                                  time.perf_counter() - t_warm)
         t0 = time.perf_counter()

@@ -58,12 +58,15 @@ def _spmv_fn(kernels):
     jit cache entry."""
     if callable(kernels):
         return kernels
+    # every route names its work jax.named_scope("acg.spmv") (ops.spmv.
+    # spmv does so itself; a callable scopes its own halo and kernel)
     if kernels == "xla-roll":
         from acg_tpu.ops.spmv import dia_mv_roll
 
         def f(A, x):
             if isinstance(A, DiaMatrix) and A.ncols_padded == A.nrows:
-                return dia_mv_roll(A.data, A.offsets, x)
+                with jax.named_scope("acg.spmv"):
+                    return dia_mv_roll(A.data, A.offsets, x)
             return spmv(A, x)
 
         return f
@@ -74,14 +77,16 @@ def _spmv_fn(kernels):
 
         def f(A, x):
             if isinstance(A, DiaMatrix) and A.ncols_padded == A.nrows:
-                return dia_spmv(A.data, A.offsets, x, interpret=interp)
+                with jax.named_scope("acg.spmv"):
+                    return dia_spmv(A.data, A.offsets, x, interpret=interp)
             if getattr(A, "kind", None) == "poisson" \
                     and hasattr(A, "matfree_apply"):
                 # the matrix-free stencil's Pallas path: coefficient
                 # masks generated IN-KERNEL while x streams through
                 # VMEM once (falls back to the operator's XLA apply
                 # off the single-window route)
-                return stencil_spmv(A, x, interpret=interp)
+                with jax.named_scope("acg.spmv"):
+                    return stencil_spmv(A, x, interpret=interp)
             return spmv(A, x)
 
         return f
@@ -301,39 +306,45 @@ def _cg_program(A: DeviceMatrix, b, x0, res_atol, res_rtol, diff_atol,
     tier's audit/ABFT cadence stays phased to GLOBAL iteration
     numbers across chunk boundaries.  Disarmed programs never name
     any of the three and lower byte-identical code (pinned in
-    tests/test_checkpoint.py)."""
+    tests/test_checkpoint.py).
+
+    The ops carry the device-scope vocabulary in their ``op_name``:
+    ``acg.setup`` (r0 = b - A x0 and the pre-loop dots), ``acg.spmv``
+    (every operator application, set-up and loop), ``acg.dot`` and
+    ``acg.update`` (the loop's dot products and vector updates)."""
     dtype = b.dtype
     dot, sdt = _scalar_setup(dtype, precise)
     store = (lambda v: v.astype(dtype)) if sdt != dtype else (lambda v: v)
     spmv_ = _spmv_fn(kernels)
-    bnrm2 = jnp.sqrt(dot(b, b))
-    x0nrm2 = jnp.sqrt(dot(x0, x0))
-    if precond is not None:
-        from acg_tpu.precond import make_apply
-        papply = make_apply(precond, spmv_)
-    if carry is not None:
-        # resume: the provided carry IS the loop state; nothing is
-        # recomputed, so the Krylov recurrence continues exactly
+    with jax.named_scope("acg.setup"):
+        bnrm2 = jnp.sqrt(dot(b, b))
+        x0nrm2 = jnp.sqrt(dot(x0, x0))
         if precond is not None:
-            r, p, gamma, rr = carry
+            from acg_tpu.precond import make_apply
+            papply = make_apply(precond, spmv_)
+        if carry is not None:
+            # resume: the provided carry IS the loop state; nothing is
+            # recomputed, so the Krylov recurrence continues exactly
+            if precond is not None:
+                r, p, gamma, rr = carry
+                r0nrm2 = jnp.sqrt(rr)
+            else:
+                r, p, gamma = carry
+                r0nrm2 = jnp.sqrt(gamma)
+        elif precond is not None:
+            r = b - spmv_(A, x0)
+            z0 = papply(mstate, A, r)
+            p = store(z0)
+            gamma = dot(r, z0)
+            rr = dot(r, r)
             r0nrm2 = jnp.sqrt(rr)
         else:
-            r, p, gamma = carry
+            r = b - spmv_(A, x0)
+            p = r
+            gamma = dot(r, r)
             r0nrm2 = jnp.sqrt(gamma)
-    elif precond is not None:
-        r = b - spmv_(A, x0)
-        z0 = papply(mstate, A, r)
-        p = store(z0)
-        gamma = dot(r, z0)
-        rr = dot(r, r)
-        r0nrm2 = jnp.sqrt(rr)
-    else:
-        r = b - spmv_(A, x0)
-        p = r
-        gamma = dot(r, r)
-        r0nrm2 = jnp.sqrt(gamma)
-    res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-    diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
+        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
+        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
     inf = jnp.asarray(jnp.inf, sdt)
 
     if trace or progress:
@@ -371,35 +382,42 @@ def _cg_program(A: DeviceMatrix, b, x0, res_atol, res_rtol, diff_atol,
         t = spmv_(A, p)
         if fault is not None:
             t = fault.apply_spmv(t, k)
-        pdott = dot(p, t)
+        with jax.named_scope("acg.dot"):
+            pdott = dot(p, t)
         if fault is not None:
             pdott = fault.apply_dot(pdott, k)
-        if detect:
-            # breakdown BEFORE the updates: a non-finite t/pdott or an
-            # indefiniteness signal must not reach x
-            bad, alpha = _breakdown_guard(gamma, pdott)
-            x = store(jnp.where(bad, x, x + alpha * p))
-            r = store(jnp.where(bad, r, r - alpha * t))
-        else:
-            alpha = gamma / pdott
-            x = store(x + alpha * p)
-            r = store(r - alpha * t)
+        with jax.named_scope("acg.update"):
+            if detect:
+                # breakdown BEFORE the updates: a non-finite t/pdott or
+                # an indefiniteness signal must not reach x
+                bad, alpha = _breakdown_guard(gamma, pdott)
+                x = store(jnp.where(bad, x, x + alpha * p))
+                r = store(jnp.where(bad, r, r - alpha * t))
+            else:
+                alpha = gamma / pdott
+                x = store(x + alpha * p)
+                r = store(r - alpha * t)
         if precond is not None:
             z = papply(mstate, A, r)
             if fault is not None:
                 z = fault.apply_precond(z, k)
-            gamma_next = dot(r, z)
-            rr_next = dot(r, r)
-            beta = gamma_next / gamma
-            p_next = store(z + beta * p)
+            with jax.named_scope("acg.dot"):
+                gamma_next = dot(r, z)
+                rr_next = dot(r, r)
+            with jax.named_scope("acg.update"):
+                beta = gamma_next / gamma
+                p_next = store(z + beta * p)
             out = (x, r, p_next, gamma_next, rr_next)
         else:
-            gamma_next = dot(r, r)
-            beta = gamma_next / gamma
-            p_next = store(r + beta * p)
+            with jax.named_scope("acg.dot"):
+                gamma_next = dot(r, r)
+            with jax.named_scope("acg.update"):
+                beta = gamma_next / gamma
+                p_next = store(r + beta * p)
             out = (x, r, p_next, gamma_next)
         if needs_diff:
-            dx = alpha * alpha * dot(p, p)
+            with jax.named_scope("acg.dot"):
+                dx = alpha * alpha * dot(p, p)
             if detect:
                 # freeze dx too: a zeroed alpha would make the frozen
                 # iteration "satisfy" the diff criterion and launder the
@@ -784,37 +802,39 @@ def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
     dot, sdt = _scalar_setup(dtype, precise)
     store = (lambda v: v.astype(dtype)) if sdt != dtype else (lambda v: v)
     spmv_ = _spmv_fn(kernels)
-    bnrm2 = jnp.sqrt(dot(b, b))
-    x0nrm2 = jnp.sqrt(dot(x0, x0))
-    if precond is not None:
-        from acg_tpu.precond import make_apply
-        papply = make_apply(precond, spmv_)
-    # resume (the survivability tier): a provided carry re-enters the
-    # GV recurrence exactly -- x0 holds the snapshot iterate, and the
-    # carried vectors (incl. w = A-image and the z/t/q scratch whose
-    # recurrences the pipelined variant never rebuilds) replace the
-    # whole setup.  carry layout matches checkpoint.carry_names
-    c_in = None
-    if carry is not None:
-        c_in = carry
+    with jax.named_scope("acg.setup"):
+        bnrm2 = jnp.sqrt(dot(b, b))
+        x0nrm2 = jnp.sqrt(dot(x0, x0))
         if precond is not None:
-            r, rr0 = c_in[0], c_in[9]
+            from acg_tpu.precond import make_apply
+            papply = make_apply(precond, spmv_)
+        # resume (the survivability tier): a provided carry re-enters
+        # the GV recurrence exactly -- x0 holds the snapshot iterate,
+        # and the carried vectors (incl. w = A-image and the z/t/q
+        # scratch whose recurrences the pipelined variant never
+        # rebuilds) replace the whole setup.  carry layout matches
+        # checkpoint.carry_names
+        c_in = None
+        if carry is not None:
+            c_in = carry
+            if precond is not None:
+                r, rr0 = c_in[0], c_in[9]
+                r0nrm2 = jnp.sqrt(rr0)
+            else:
+                r = c_in[0]
+                r0nrm2 = jnp.sqrt(jnp.maximum(c_in[5], 0))
+        elif precond is not None:
+            r = b - spmv_(A, x0)
+            u0 = store(papply(mstate, A, r))
+            w = spmv_(A, u0)
+            rr0 = dot(r, r)
             r0nrm2 = jnp.sqrt(rr0)
         else:
-            r = c_in[0]
-            r0nrm2 = jnp.sqrt(jnp.maximum(c_in[5], 0))
-    elif precond is not None:
-        r = b - spmv_(A, x0)
-        u0 = store(papply(mstate, A, r))
-        w = spmv_(A, u0)
-        rr0 = dot(r, r)
-        r0nrm2 = jnp.sqrt(rr0)
-    else:
-        r = b - spmv_(A, x0)
-        w = spmv_(A, r)
-        r0nrm2 = jnp.sqrt(dot(r, r))
-    res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-    diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
+            r = b - spmv_(A, x0)
+            w = spmv_(A, r)
+            r0nrm2 = jnp.sqrt(dot(r, r))
+        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
+        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
     inf = jnp.asarray(jnp.inf, sdt)
     zeros = jnp.zeros_like(b)
     if trace or progress:
@@ -843,9 +863,10 @@ def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
         # the iteration's three reductions, fused (ONE allreduce on a
         # mesh): gamma/delta drive the recurrences, rr feeds the true-
         # residual convergence test (stale by one, like gamma)
-        gamma = dot(r, u)
-        delta = dot(w, u)
-        rr = dot(r, r)
+        with jax.named_scope("acg.dot"):
+            gamma = dot(r, u)
+            delta = dot(w, u)
+            rr = dot(r, r)
         if fault is not None:
             delta = fault.apply_dot(delta, k)
         # m = M^-1 w and n = A m overlap the reduction under XLA's
@@ -856,33 +877,35 @@ def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
         nvec = spmv_(A, m)
         if fault is not None:
             nvec = fault.apply_spmv(nvec, k)
-        beta = gamma / gamma_prev               # inf -> 0 on first iteration
-        denom = delta - beta * (gamma / alpha_prev)
-        if detect:
-            bad, alpha = _breakdown_guard(gamma, denom)
-            # a negative (r, u) is the non-SPD-M signal (precond: fault
-            # twin); the unpreconditioned guard cannot see it
-            bad = bad | (gamma < 0)
-            alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
-        else:
-            alpha = gamma / denom
-        z = store(nvec + beta * z)
-        q = store(m + beta * q)
-        s = store(w + beta * s)
-        p = store(u + beta * p)
-        if detect:
-            x = store(jnp.where(bad, x, x + alpha * p))
-            r = store(jnp.where(bad, r, r - alpha * s))
-            u = store(jnp.where(bad, u, u - alpha * q))
-            w = store(jnp.where(bad, w, w - alpha * z))
-        else:
-            x = store(x + alpha * p)
-            r = store(r - alpha * s)
-            u = store(u - alpha * q)
-            w = store(w - alpha * z)
+        with jax.named_scope("acg.update"):
+            beta = gamma / gamma_prev           # inf -> 0 on first iteration
+            denom = delta - beta * (gamma / alpha_prev)
+            if detect:
+                bad, alpha = _breakdown_guard(gamma, denom)
+                # a negative (r, u) is the non-SPD-M signal (precond:
+                # fault twin); the unpreconditioned guard cannot see it
+                bad = bad | (gamma < 0)
+                alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
+            else:
+                alpha = gamma / denom
+            z = store(nvec + beta * z)
+            q = store(m + beta * q)
+            s = store(w + beta * s)
+            p = store(u + beta * p)
+            if detect:
+                x = store(jnp.where(bad, x, x + alpha * p))
+                r = store(jnp.where(bad, r, r - alpha * s))
+                u = store(jnp.where(bad, u, u - alpha * q))
+                w = store(jnp.where(bad, w, w - alpha * z))
+            else:
+                x = store(x + alpha * p)
+                r = store(r - alpha * s)
+                u = store(u - alpha * q)
+                w = store(w - alpha * z)
         out = (x, r, u, w, p, s, q, z, gamma, alpha, rr)
         if needs_diff:
-            dx = alpha * alpha * dot(p, p)
+            with jax.named_scope("acg.dot"):
+                dx = alpha * alpha * dot(p, p)
             if detect:
                 dx = jnp.where(bad, state[11], dx)
             out = out + (dx,)
@@ -929,8 +952,9 @@ def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
             aud, state = state[-1], state[:-1]
         x, r, w, p, t, z, gamma_prev, alpha_prev = state[:8]
         # both reductions of the iteration, fused (one allreduce on a mesh)
-        gamma = dot(r, r)
-        delta = dot(w, r)
+        with jax.named_scope("acg.dot"):
+            gamma = dot(r, r)
+            delta = dot(w, r)
         if fault is not None:
             delta = fault.apply_dot(delta, k)
         # SpMV overlaps the allreduce in the reference (cgcuda.c:1750-1790);
@@ -941,39 +965,44 @@ def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
         # the SpMV input, before the 6-vector update rebinds w below
         # (the ABFT check verifies q against THIS vector)
         w_in = w
-        beta = gamma / gamma_prev               # inf -> 0 on first iteration
-        denom = delta - beta * (gamma / alpha_prev)
-        if detect:
-            # the alpha denominator plays the (p, Ap) role here; freeze
-            # x/r/w on breakdown (p/t/z are scratch once the loop exits)
-            bad, alpha = _breakdown_guard(gamma, denom)
-            if health is not None:
-                # sign anomaly: a negative computed (r, r) is
-                # arithmetic poison (the finite-value guard misses it)
-                bad = bad | (gamma < 0)
-                alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
-        else:
-            alpha = gamma / denom
-        # the 6-vector update stays in XLA even under kernels="pallas":
-        # the hand-written fused kernel (ops.pallas_kernels.
-        # fused_pipelined_update) wins in isolation (~1.35x) but inside
-        # the loop it is an opaque call that forfeits XLA's fusion of the
-        # *next* iteration's dots into these writes -- measured 894 vs
-        # 1818 iters/s on the flagship (BASELINE.md)
-        z = store(q + beta * z)
-        t = store(w + beta * t)
-        p = store(r + beta * p)
-        if detect:
-            x = store(jnp.where(bad, x, x + alpha * p))
-            r = store(jnp.where(bad, r, r - alpha * t))
-            w = store(jnp.where(bad, w, w - alpha * z))
-        else:
-            x = store(x + alpha * p)
-            r = store(r - alpha * t)
-            w = store(w - alpha * z)
+        with jax.named_scope("acg.update"):
+            beta = gamma / gamma_prev           # inf -> 0 on first iteration
+            denom = delta - beta * (gamma / alpha_prev)
+            if detect:
+                # the alpha denominator plays the (p, Ap) role here;
+                # freeze x/r/w on breakdown (p/t/z are scratch once the
+                # loop exits)
+                bad, alpha = _breakdown_guard(gamma, denom)
+                if health is not None:
+                    # sign anomaly: a negative computed (r, r) is
+                    # arithmetic poison (the finite-value guard misses
+                    # it)
+                    bad = bad | (gamma < 0)
+                    alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
+            else:
+                alpha = gamma / denom
+            # the 6-vector update stays in XLA even under
+            # kernels="pallas": the hand-written fused kernel (ops.
+            # pallas_kernels.fused_pipelined_update) wins in isolation
+            # (~1.35x) but inside the loop it is an opaque call that
+            # forfeits XLA's fusion of the *next* iteration's dots into
+            # these writes -- measured 894 vs 1818 iters/s on the
+            # flagship (BASELINE.md)
+            z = store(q + beta * z)
+            t = store(w + beta * t)
+            p = store(r + beta * p)
+            if detect:
+                x = store(jnp.where(bad, x, x + alpha * p))
+                r = store(jnp.where(bad, r, r - alpha * t))
+                w = store(jnp.where(bad, w, w - alpha * z))
+            else:
+                x = store(x + alpha * p)
+                r = store(r - alpha * t)
+                w = store(w - alpha * z)
         out = (x, r, w, p, t, z, gamma, alpha)
         if needs_diff:
-            dx = alpha * alpha * dot(p, p)
+            with jax.named_scope("acg.dot"):
+                dx = alpha * alpha * dot(p, p)
             if detect:
                 # freeze dx on breakdown (see _cg_program): alpha = 0
                 # must not fake the diff criterion
@@ -1728,40 +1757,45 @@ class JaxCGSolver:
                                     raise_on_divergence=raise_on_divergence,
                                     warmup=warmup,
                                     host_result=host_result)
-        crit = criteria or StoppingCriteria()
-        st = self.stats
-        st.criteria = crit
-        from acg_tpu import faults
-        fault = faults.device_fault()
-        self._fault_refusals(fault)
-        # detection arms with the recovery policy, an active injector
-        # (an injected fault must surface, never launder into x), or a
-        # tripping health spec; the detect=False programs stay
-        # byte-identical to the seed's
-        detect = self._detect(fault)
-        # dtype policy (vector_dtype override, f32 replacement outer)
-        # lives in _solve_dtype, shared with the lower_solve hook
-        dtype = self._solve_dtype()
         from acg_tpu import telemetry
-        if fault is not None:
-            # timestamped twin of the injector's stderr line for the
-            # structured sink (--stats-json)
-            telemetry.record_event(st, "fault-armed",
-                                   f"{fault.site}:{fault.mode}"
-                                   f"@{fault.iteration}")
-        t_xfer = time.perf_counter()
-        with telemetry.annotate("transfer"):
-            b = jnp.asarray(b, dtype=dtype)
-            x0 = (jnp.zeros_like(b) if x0 is None
-                  else jnp.asarray(x0, dtype=dtype))
-        telemetry.add_timing(st, "transfer",
-                             time.perf_counter() - t_xfer)
-        # scalar dtype for recovery's re-derived tolerances below; the
-        # program dispatch itself -- tolerances, static kwargs, the
-        # configuration refusals -- is shared with lower_solve
-        sdt = acc_dtype(dtype)
-        program, args, kwargs, tr = self._select_program(
-            b, x0, crit, detect=detect, fault=fault)
+        # host spans (telemetry.span, on the profiler's clock): prepare
+        # (transfer inside it), dispatch and wait inside the solve
+        # phase, then fetch, check and account -- each idle gap of the
+        # device in a capture is named by what the host was doing
+        with telemetry.span("prepare"):
+            crit = criteria or StoppingCriteria()
+            st = self.stats
+            st.criteria = crit
+            from acg_tpu import faults
+            fault = faults.device_fault()
+            self._fault_refusals(fault)
+            # detection arms with the recovery policy, an active
+            # injector (an injected fault must surface, never launder
+            # into x), or a tripping health spec; the detect=False
+            # programs stay byte-identical to the seed's
+            detect = self._detect(fault)
+            # dtype policy (vector_dtype override, f32 replacement
+            # outer) lives in _solve_dtype, shared with lower_solve
+            dtype = self._solve_dtype()
+            if fault is not None:
+                # timestamped twin of the injector's stderr line for the
+                # structured sink (--stats-json)
+                telemetry.record_event(st, "fault-armed",
+                                       f"{fault.site}:{fault.mode}"
+                                       f"@{fault.iteration}")
+            t_xfer = time.perf_counter()
+            with telemetry.annotate("transfer"):
+                b = jnp.asarray(b, dtype=dtype)
+                x0 = (jnp.zeros_like(b) if x0 is None
+                      else jnp.asarray(x0, dtype=dtype))
+            telemetry.add_timing(st, "transfer",
+                                 time.perf_counter() - t_xfer)
+            # scalar dtype for recovery's re-derived tolerances below;
+            # the program dispatch itself -- tolerances, static kwargs,
+            # the configuration refusals -- is shared with lower_solve
+            sdt = acc_dtype(dtype)
+            program, args, kwargs, tr = self._select_program(
+                b, x0, crit, detect=detect, fault=fault)
 
         hl = "health" in kwargs
 
@@ -1786,20 +1820,24 @@ class JaxCGSolver:
 
         # warmup solves outside the timed region (the reference warms up
         # each op class before timing, cgcuda.c:612-710)
-        t_warm = time.perf_counter()
-        with telemetry.annotate("compile"):
-            for _ in range(max(warmup, 0)):
-                jax.block_until_ready(run(*args, **kwargs)[0].x)
         if warmup > 0:
             # warmup absorbs the compile; with warmup=0 it lands in the
             # solve phase (documented in the README observability notes)
+            t_warm = time.perf_counter()
+            with telemetry.annotate("compile"):
+                for _ in range(warmup):
+                    jax.block_until_ready(run(*args, **kwargs)[0].x)
             telemetry.add_timing(st, "compile",
                                  time.perf_counter() - t_warm)
         t0 = time.perf_counter()
         with telemetry.annotate("solve"):
-            res, tbuf, aud = run(*args, **kwargs)
-            jax.block_until_ready(res.x)
-        niter = int(res.niterations)
+            with telemetry.span("dispatch"):
+                res, tbuf, aud = run(*args, **kwargs)
+            with telemetry.span("wait"):
+                jax.block_until_ready(res.x)
+        with telemetry.span("fetch"):
+            niter = int(res.niterations)
+            broke = detect and bool(res.breakdown)
         first_norms = None
         # the first note_audit of this solve resets the health summary;
         # later attempts MERGE (gap_max keeps the worst gap that
@@ -1809,7 +1847,7 @@ class JaxCGSolver:
         # instead of the generic arithmetic-breakdown diagnosis
         aud_fresh = True
         gap_tripped = False
-        if detect and bool(res.breakdown):
+        if broke:
             # host-side recovery (solvers.resilience): bounded restarts
             # from the last finite iterate -- the program's setup
             # recomputes the TRUE residual r = b - A x0, so each restart
@@ -1932,47 +1970,52 @@ class JaxCGSolver:
                         f"fallback available")
                 raise driver.give_up(niter, float(res.rnrm2))
         t_solve = time.perf_counter() - t0
-        st.tsolve += t_solve
-        telemetry.add_timing(st, "solve", t_solve)
-        if tr:
-            # the ONE extra host fetch of a traced solve (acceptance
-            # contract: zero additional transfers per iteration)
-            st.trace = self.last_trace = attempt_trace(res, tbuf)
-        st.nsolves += 1
-        st.niterations = niter
-        st.ntotaliterations += niter
-        st.bnrm2, st.x0nrm2, st.r0nrm2 = (
-            first_norms if first_norms is not None
-            else (float(res.bnrm2), float(res.x0nrm2), float(res.r0nrm2)))
-        st.rnrm2 = float(res.rnrm2)
-        st.dxnrm2 = float(res.dxnrm2)
-        st.converged = bool(res.converged) or crit.unbounded
-        if hl and aud is not None:
-            # the health: section's audit summary + the acg_health_*
-            # metrics + (threshold exceeded) the accuracy_degraded event
-            from acg_tpu import health as health_mod
-            health_mod.note_audit(st, aud, self.health_spec, "jax-cg",
-                                  fresh=aud_fresh)
-        # service-metrics tier: one completed solve (no-op disarmed;
-        # the sharded subclass reuses this solve, so its comm ledger
-        # rides through the same hook)
-        from acg_tpu import metrics
-        metrics.record_solve(t_solve, niter, st.converged,
-                             solver=self._solver_name())
-        metrics.observe_solver_comm(self, niter)
-        self._account_ops(st, niter, dtype)
+        with telemetry.span("fetch"):
+            if tr:
+                # the ONE extra host fetch of a traced solve (acceptance
+                # contract: zero additional transfers per iteration)
+                st.trace = self.last_trace = attempt_trace(res, tbuf)
+            st.bnrm2, st.x0nrm2, st.r0nrm2 = (
+                first_norms if first_norms is not None
+                else (float(res.bnrm2), float(res.x0nrm2),
+                      float(res.r0nrm2)))
+            st.rnrm2 = float(res.rnrm2)
+            st.dxnrm2 = float(res.dxnrm2)
+            st.converged = bool(res.converged) or crit.unbounded
+            x = np.asarray(res.x) if host_result else res.x
         if host_result:
-            x = np.asarray(res.x)
-            st.fexcept_arrays = [x]
+            fexcept = x
         else:
-            x = res.x
-            # device-side scans; only two bools cross the wire.  The
-            # sentinels reproduce the host report's NaN/Inf distinction
-            # (errors.fexcept_str).
-            has_nan = bool(jnp.isnan(res.x).any())
-            has_inf = bool(jnp.isinf(res.x).any())
-            st.fexcept_arrays = [np.asarray([np.nan if has_nan else 0.0,
-                                             np.inf if has_inf else 0.0])]
+            with telemetry.span("check"):
+                # device-side scans; only two bools cross the wire.  The
+                # sentinels reproduce the host report's NaN/Inf
+                # distinction (errors.fexcept_str).
+                has_nan = bool(jnp.isnan(res.x).any())
+                has_inf = bool(jnp.isinf(res.x).any())
+            fexcept = np.asarray([np.nan if has_nan else 0.0,
+                                  np.inf if has_inf else 0.0])
+        with telemetry.span("account"):
+            st.fexcept_arrays = [fexcept]
+            st.tsolve += t_solve
+            telemetry.add_timing(st, "solve", t_solve)
+            st.nsolves += 1
+            st.niterations = niter
+            st.ntotaliterations += niter
+            if hl and aud is not None:
+                # the health: section's audit summary + the acg_health_*
+                # metrics + (threshold exceeded) the accuracy_degraded
+                # event
+                from acg_tpu import health as health_mod
+                health_mod.note_audit(st, aud, self.health_spec, "jax-cg",
+                                      fresh=aud_fresh)
+            # service-metrics tier: one completed solve (no-op disarmed;
+            # the sharded subclass reuses this solve, so its comm ledger
+            # rides through the same hook)
+            from acg_tpu import metrics
+            metrics.record_solve(t_solve, niter, st.converged,
+                                 solver=self._solver_name())
+            metrics.observe_solver_comm(self, niter)
+            self._account_ops(st, niter, dtype)
         if not st.converged and raise_on_divergence:
             raise NotConvergedError(
                 f"{niter} iterations, residual {st.rnrm2:.3e}")

@@ -571,22 +571,34 @@ class PhaseTimer:
         return timings
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation("acg:<name>")`` bracket; a cheap
-    no-op when no trace is being collected, and tolerant of backends
-    without profiler support.  Also feeds the live-observatory status
-    document's current-phase field (no-op disarmed) -- every pipeline
-    phase passes through here."""
-    from acg_tpu import observatory
-    observatory.note_phase(name)
+def span(name: str):
+    """``jax.profiler.TraceAnnotation("acg:<name>")``: a host span on the
+    profiler's own clock, beside the device's op events; a cheap no-op
+    when no trace is being collected, and tolerant of backends without
+    profiler support.  Pipeline phases go through :func:`annotate`; a
+    solve's sub-steps (``prepare``, ``dispatch``, ``wait``, ``fetch``,
+    ``check``, ``account``) come here directly, so they name the host's
+    work in a capture without moving the status document's phase.
+
+    The device side has its own vocabulary: ``jax.named_scope("acg.<
+    layer>")`` (``setup``, ``spmv``, ``halo``, ``dot``, ``update``) in
+    the solve programs, which lands in each compiled op's ``op_name``."""
     try:
         import jax
 
-        cm = jax.profiler.TraceAnnotation(f"acg:{name}")
+        return jax.profiler.TraceAnnotation(f"acg:{name}")
     except Exception:  # noqa: BLE001 -- telemetry must never sink a solve
-        cm = contextlib.nullcontext()
-    with cm:
+        return contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def annotate(name: str):
+    """A pipeline phase: :func:`span` ``acg:<name>``, and the
+    live-observatory status document's current-phase field (no-op
+    disarmed) -- every pipeline phase passes through here."""
+    from acg_tpu import observatory
+    observatory.note_phase(name)
+    with span(name):
         yield
 
 

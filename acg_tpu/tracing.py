@@ -454,7 +454,7 @@ _HLO_PATTERNS: tuple[tuple[str, re.Pattern], ...] = (
 _KEYWORD_PATTERNS: tuple[tuple[str, re.Pattern], ...] = (
     ("gemv", re.compile(r"spmv|matvec|gemv", re.I)),
     ("allreduce", re.compile(r"\bpsum\b", re.I)),
-    ("halo", re.compile(r"ppermute|halo_exchange", re.I)),
+    ("halo", re.compile(r"ppermute|halo_exchange|acg\.halo", re.I)),
 )
 # collective KIND sub-classification (the commbench observatory's
 # per-kind confrontation: acg_tpu.commbench fits one alpha-beta model
