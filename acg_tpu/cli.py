@@ -1586,9 +1586,10 @@ def _report_errors(args, err0, err) -> None:
 def _solve_route(inner) -> dict:
     """What the solve actually ran, for the ``--stats-json`` manifest:
     the resolved kernel tier, the SpMV route (the Pallas DIA kernel's
-    route, or the storage format the XLA path multiplies), the halo
-    transport of an explicit mesh, and the ids of the devices that hold
-    a shard of the matrix."""
+    route, or the storage format the XLA path multiplies), on the
+    kernel's single-x-pass routes the HBM bytes of x one SpMV reads over
+    x's own (``spmv_x_reads``), the halo transport of an explicit mesh,
+    and the ids of the devices that hold a shard of the matrix."""
     import jax.numpy as jnp
 
     from acg_tpu.ops.spmv import DiaMatrix
@@ -1607,13 +1608,18 @@ def _solve_route(inner) -> dict:
         route = type(A).__name__.replace("Matrix", "").lower()
         if isinstance(A, DiaMatrix) and isinstance(tier, str) \
                 and tier.startswith("pallas"):
-            from acg_tpu.ops.pallas_kernels import dia_spmv_route
+            from acg_tpu.ops.pallas_kernels import (dia_spmv_route,
+                                                    dia_spmv_x_reads)
             n = (kern.nloc + kern.Lh + kern.Rh if tier == "pallas-roll"
                  else A.nrows)
             vdt = jnp.dtype(getattr(inner, "vector_dtype", None)
                             or A.dtype)
-            route = "dia-" + dia_spmv_route(A.offsets, n, vdt,
-                                            ndiags=len(A.offsets))[0]
+            kroute = dia_spmv_route(A.offsets, n, vdt,
+                                    ndiags=len(A.offsets))
+            route = "dia-" + kroute[0]
+            reads = dia_spmv_x_reads(kroute)
+            if reads is not None:
+                out["spmv_x_reads"] = reads
         out["spmv_route"] = route
         leaves = A.data if isinstance(A, DiaMatrix) else A
         try:

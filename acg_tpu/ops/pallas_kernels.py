@@ -9,10 +9,12 @@ a *specific* HBM-traffic bound the fusion cannot reach:
 * :func:`dia_spmv` -- DIA SpMV with a single pass over ``x``: the XLA
   formulation (``ops/spmv.py:dia_mv``) reads one shifted copy of ``x``
   per diagonal (D+1 vector reads + 1 write for D diagonals); this kernel
-  DMAs each x tile (plus band halo) into VMEM once and applies all D
-  statically-shifted multiplies from VMEM, for D/2+2-ish units of HBM
-  traffic -- the same traffic argument as the reference's merge-CSR
-  kernel, restated for a vector architecture.
+  DMAs each x tile into VMEM once and applies all D statically-shifted
+  multiplies from VMEM, for D + 2 vectors of HBM traffic a call: the D
+  planes and x read, y written (x's band halos re-read per window on
+  the "fast" route, ``dia_spmv_x_reads``) -- the same traffic argument
+  as the reference's merge-CSR kernel, restated for a vector
+  architecture.
 * :func:`fused_pipelined_update` -- the Ghysels-Vanroose 6-vector update
   (z,t,p,x,r,w) in one pass with alpha/beta in SMEM, the analog of
   ``acgsolvercuda_pipelined_update_kernel`` (``cg-kernels-cuda.cu:
@@ -81,15 +83,9 @@ def dia_spmv(planes, offsets: tuple, x, interpret: bool = False):
     """
     n = x.shape[0]
     route = dia_spmv_route(offsets, n, x.dtype, ndiags=len(planes))
-    if route[0] == "fast":
-        # the fast path IS the clustered kernel with no far windows
-        _, Lpad, Rpad, tile, align = route
-        return _dia_spmv_clustered(planes, offsets, x, tuple(offsets), (),
-                                   Lpad, Rpad, tile, align, interpret)
-    if route[0] == "clustered":
-        _, central, far, Lpad, Rpad, tile, align = route
-        return _dia_spmv_clustered(planes, offsets, x, central, far,
-                                   Lpad, Rpad, tile, align, interpret)
+    if route[0] in ("fast", "clustered"):
+        return _dia_spmv_clustered(planes, offsets, x,
+                                   *_kernel_args(route), interpret)
     if route[0] == "xla":
         from acg_tpu.ops.spmv import dia_mv
 
@@ -111,27 +107,28 @@ def dia_spmv_dot(planes, offsets: tuple, x, interpret: bool = False):
     """
     n = x.shape[0]
     route = dia_spmv_route(offsets, n, x.dtype, ndiags=len(planes))
-    if route[0] == "fast":
-        _, Lpad, Rpad, tile, align = route
-        y, d = _dia_spmv_clustered(planes, offsets, x, tuple(offsets), (),
-                                   Lpad, Rpad, tile, align, interpret,
-                                   with_dot=True)
-        return y, d[0].astype(x.dtype)
-    if route[0] == "clustered":
-        _, central, far, Lpad, Rpad, tile, align = route
-        y, d = _dia_spmv_clustered(planes, offsets, x, central, far,
-                                   Lpad, Rpad, tile, align, interpret,
-                                   with_dot=True)
+    if route[0] in ("fast", "clustered"):
+        y, d = _dia_spmv_clustered(planes, offsets, x, *_kernel_args(route),
+                                   interpret, with_dot=True)
         return y, d[0].astype(x.dtype)
     y = dia_spmv(planes, offsets, x, interpret=interpret)
     return y, jnp.dot(x, y)
+
+
+def _kernel_args(route) -> tuple:
+    """``(far, Lpad, Rpad, tile, align)`` of ``_dia_spmv_clustered`` for
+    a "fast" or "clustered" route: the fast path IS that kernel with no
+    far windows."""
+    if route[0] == "fast":
+        return ((),) + route[1:]
+    return route[2:]
 
 
 def dia_spmv_route(offsets: tuple, n: int, dtype, ndiags: int | None = None):
     """Which implementation :func:`dia_spmv` will take for this shape:
     ``("fast", Lpad, Rpad, tile, align)`` (single-window kernel),
     ``("clustered", central, far, Lpad, Rpad, tile, align)``
-    (multi-window kernel for clustered diagonals), ``("padded",)``, or
+    (ring kernel for clustered diagonals), ``("padded",)``, or
     ``("xla",)``.  Exposed so callers reporting a kernel tier (bench)
     can record what actually ran instead of what was requested."""
     ndiags = len(offsets) if ndiags is None else ndiags
@@ -173,14 +170,27 @@ def dia_spmv_route(offsets: tuple, n: int, dtype, ndiags: int | None = None):
     return ("padded",)
 
 
+def dia_spmv_x_reads(route) -> float | None:
+    """HBM bytes of x one :func:`dia_spmv` call reads over x's own, on a
+    ``route`` of :func:`dia_spmv_route`: 1.0 on the clustered route
+    (each tile enters the ring once; halos come from the ring), ``(tile
+    + Lpad + Rpad) / tile`` on the fast route (each window re-reads its
+    band halos); None on the fallbacks."""
+    if route[0] == "clustered":
+        return 1.0
+    if route[0] == "fast":
+        _, Lpad, Rpad, tile, _ = route
+        return (tile + Lpad + Rpad) / tile
+    return None
+
+
 def _cluster_route(offsets, n, itemsize, align, budget, ndiags):
-    """Multi-window variant for stencils whose diagonals CLUSTER (3D
-    Poisson: {-n^2}, {-n..n}, {+n^2}): one VMEM window per cluster
-    keeps the single-x-pass traffic argument even when the full band is
-    far too wide for one window.  Far clusters must be single offsets on
-    tile boundaries (their window is then exactly the x tile shifted by
-    whole tiles, so edge handling is a static in-range predicate);
-    the cluster containing 0 is handled like the fast path."""
+    """Route for stencils whose diagonals CLUSTER (3D Poisson: {-n^2},
+    {-n..n}, {+n^2}) in a band far too wide for one window: the kernel's
+    ring of x tiles (``_dia_spmv_clustered``) still reads x from HBM
+    once a call.  Far clusters must be single offsets on tile boundaries
+    (each then reads one whole tile of the ring); the cluster containing
+    0 reads its tile's slot with halos, like the fast path."""
     if n % TILE or n < TILE:
         return None
     sorted_offs = sorted(offsets)
@@ -193,9 +203,11 @@ def _cluster_route(offsets, n, itemsize, align, budget, ndiags):
     if len(clusters) < 2:
         return None
     central = min(clusters, key=lambda c: min(abs(o) for o in c))
-    far = [c for c in clusters if c is not central]
-    if any(len(c) != 1 or c[0] % TILE or abs(c[0]) >= n for c in far):
+    far_clusters = [c for c in clusters if c is not central]
+    if any(len(c) != 1 or c[0] % TILE or abs(c[0]) >= n
+           for c in far_clusters):
         return None
+    far = tuple(c[0] for c in far_clusters)
     L = max(0, -min(central))
     R = max(0, max(central))
     Lpad = L + (-L) % align
@@ -204,7 +216,11 @@ def _cluster_route(offsets, n, itemsize, align, budget, ndiags):
         return None
 
     def vmem(tile):
-        return (tile + Lpad + Rpad + len(far) * tile
+        # the kernel's x ring (one window-sized slot per tile a step
+        # reads, plus the tile in flight) and the double-buffered
+        # BlockSpec tiles (D planes + y)
+        lo, hi = _ring_span(far, Lpad, Rpad, tile)
+        return ((hi - lo + 2) * (tile + Lpad + Rpad)
                 + 2 * (ndiags + 1) * tile) * itemsize
 
     # grow the tile while the far offsets stay tile-multiples and VMEM
@@ -213,12 +229,11 @@ def _cluster_route(offsets, n, itemsize, align, budget, ndiags):
     # 512^3 with the base tile)
     tile = TILE
     while (n % (2 * tile) == 0 and vmem(2 * tile) <= budget
-           and all(c[0] % (2 * tile) == 0 for c in far)):
+           and all(o % (2 * tile) == 0 for o in far)):
         tile *= 2
     if vmem(tile) > budget:
         return None
-    return ("clustered", tuple(central), tuple(c[0] for c in far),
-            Lpad, Rpad, tile, align)
+    return ("clustered", tuple(central), far, Lpad, Rpad, tile, align)
 
 
 def _window_copies(hbm, wref, sems, s0: int, i, grid: int, tile: int,
@@ -280,13 +295,40 @@ def _window_copies(hbm, wref, sems, s0: int, i, grid: int, tile: int,
     return start, wait
 
 
-def _dia_spmv_clustered(planes, offsets, x, central, far, Lpad, Rpad,
-                        tile, align, interpret, with_dot=False):
-    """Multi-window single-x-pass SpMV (see ``_cluster_route``): the
-    central cluster reads body + left/right halos (the single-window
-    "fast" route is this kernel with ``far=()``); each far
-    offset reads exactly one whole x tile shifted by ``offset/tile``
-    tiles (zero-filled when that tile is off either end).
+def _ring_span(far, Lpad: int, Rpad: int, tile: int) -> tuple[int, int]:
+    """``(lo, hi)``: grid step ``i`` of the ring kernel reads x tiles
+    ``i+lo .. i+hi`` -- each far offset's whole-tile shift, the body,
+    and the neighbours whose edges are the central cluster's halos."""
+    shifts = [o // tile for o in far] + [0, -1 if Lpad else 0,
+                                         1 if Rpad else 0]
+    return min(shifts), max(shifts)
+
+
+def _dia_spmv_clustered(planes, offsets, x, far, Lpad, Rpad, tile, align,
+                        interpret, with_dot=False):
+    """Single-x-pass SpMV over VMEM windows of x.
+
+    ``far == ()`` (the single-window "fast" route): each grid step
+    fetches tile ``i`` plus its band halos (``_window_copies``) and
+    waits for it before computing.
+
+    Otherwise (the "clustered" route, see ``_cluster_route``): a RING of
+    x tiles carried across the grid steps, which TPU runs in order on
+    one core.  Step ``i`` reads tiles ``i+lo .. i+hi`` (``_ring_span``):
+    each far offset is exactly one whole tile ``i + offset/tile``, and
+    the central cluster reads tile ``i``'s slot, whose margins receive
+    the edges of tiles ``i-1`` and ``i+1`` by VMEM copies.  Tile ``t``
+    lives in slot ``t mod K`` (K = hi - lo + 2: the span plus the tile
+    in flight), enters it once by DMA one step ahead of its first use,
+    and tiles off either end are zero-filled slots (correctness-neutral:
+    those positions only multiply structural zeros).  So x is read from
+    HBM once per call, and its fetch overlaps the previous step's work.
+    The branch on ``i mod K`` makes every slot index static.
+
+    Both accumulate the planes in ``offsets`` order, one multiply-add
+    each in :func:`acc_dtype` (sub-f32 storage accumulates in f32: the
+    converts are free on the VPU, VMEM/HBM stay half-width), so the two
+    routes compute the same ``y`` bit for bit.
 
     ``with_dot=True`` additionally returns ``dot(x, y)`` accumulated in
     SMEM across the (sequential) grid -- the CG step's (p, Ap) scalar
@@ -294,84 +336,138 @@ def _dia_spmv_clustered(planes, offsets, x, central, far, Lpad, Rpad,
     n = x.shape[0]
     grid = n // tile
     win = tile + Lpad + Rpad
-    shifts = [o // tile for o in far]
-    # plane order: kernel args follow `planes`/`offsets` order; map each
-    # offset to (central?, window index)
-    central_set = set(central)
+    nplanes = len(planes)
+    kadt = acc_dtype(x.dtype)
 
-    def kernel(x_hbm, *plane_refs_and_out):
-        nout = 2 if with_dot else 1
-        plane_refs = plane_refs_and_out[:-nout]
-        y_ref = plane_refs_and_out[-nout]
-        dot_ref = plane_refs_and_out[-1] if with_dot else None
-        i = pl.program_id(0)
+    def accumulate(plane_refs, window_of):
+        acc = jnp.zeros((tile,), kadt)
+        for pr, off in zip(plane_refs, offsets):
+            acc = acc + (pr[:].astype(kadt) * window_of(off).astype(kadt))
+        return acc
 
-        def body(xwin, *fwins_and_sems):
-            fwins = fwins_and_sems[:-1]
-            sems = fwins_and_sems[-1]
-            # start every copy first, wait after: the DMAs overlap each
-            # other (and the zero-fills) instead of serialising the
-            # grid step on round-trips
-            start, wait = _window_copies(x_hbm, xwin, sems, 0, i, grid,
-                                         tile, Lpad, Rpad, align, x.dtype)
-            start()
-            for f, (fwin, s) in enumerate(zip(fwins, shifts)):
-                src = i + s  # whole-tile shift: static in-range test
+    def finish(i, acc, xbody, y_ref, dot_ref):
+        """Store y; with the dot, add (x, y) over the tile, ``xbody()``
+        giving x's tile."""
+        y_ref[:] = acc.astype(x.dtype)
+        if with_dot:
+            # TPU grids run sequentially, so accumulating the partial
+            # into the (1,)-SMEM output across steps is safe; products
+            # are widened to the accumulation dtype before the reduction
+            # so bf16 inputs don't collapse the scalar
+            partial = jnp.sum(acc * xbody().astype(kadt))
 
-                @pl.when((src >= 0) & (src < grid))
-                def _(fwin=fwin, src=src, f=f):
-                    pltpu.make_async_copy(
-                        x_hbm.at[pl.ds(
-                            pl.multiple_of(src * tile, align), tile)],
-                        fwin, sems.at[jnp.int32(3 + f)]).start()
+            @pl.when(i == 0)
+            def _():
+                dot_ref[0] = partial
 
-                @pl.when((src < 0) | (src >= grid))
-                def _(fwin=fwin):
-                    fwin[...] = jnp.zeros((tile,), x.dtype)
-            for f, (fwin, s) in enumerate(zip(fwins, shifts)):
-                src = i + s
+            @pl.when(i > 0)
+            def _():
+                dot_ref[0] += partial
 
-                @pl.when((src >= 0) & (src < grid))
-                def _(fwin=fwin, src=src, f=f):
-                    pltpu.make_async_copy(
-                        x_hbm.at[pl.ds(
-                            pl.multiple_of(src * tile, align), tile)],
-                        fwin, sems.at[jnp.int32(3 + f)]).wait()
-            wait()
-            # sub-f32 storage accumulates in f32: the converts are free
-            # on the VPU, VMEM/HBM stay half-width
-            kadt = acc_dtype(x.dtype)
-            acc = jnp.zeros((tile,), kadt)
-            far_idx = {o: f for f, o in enumerate(far)}
-            for pr, off in zip(plane_refs, offsets):
-                if off in central_set:
-                    acc = acc + (pr[:].astype(kadt)
-                                 * xwin[pl.ds(Lpad + off, tile)].astype(kadt))
-                else:
-                    acc = acc + (pr[:].astype(kadt)
-                                 * fwins[far_idx[off]][:].astype(kadt))
-            y_ref[:] = acc.astype(x.dtype)
-            if with_dot:
-                # TPU grids run sequentially, so accumulating the
-                # partial into the (1,)-SMEM output across steps is
-                # safe; products are widened to the accumulation dtype
-                # before the reduction so bf16 inputs don't collapse
-                # the scalar
-                adt = acc_dtype(x.dtype)
-                partial = jnp.sum(acc.astype(adt)
-                                  * xwin[pl.ds(Lpad, tile)].astype(adt))
+    def outputs(refs):
+        return refs[nplanes], (refs[nplanes + 1] if with_dot else None)
 
-                @pl.when(i == 0)
+    scratch, params = (), None
+    if not far:
+        def kernel(x_hbm, *refs):
+            plane_refs = refs[:nplanes]
+            y_ref, dot_ref = outputs(refs)
+            i = pl.program_id(0)
+
+            def body(xwin, sems):
+                start, wait = _window_copies(x_hbm, xwin, sems, 0, i, grid,
+                                             tile, Lpad, Rpad, align,
+                                             x.dtype)
+                start()
+                wait()
+                acc = accumulate(plane_refs,
+                                 lambda off: xwin[pl.ds(Lpad + off, tile)])
+                finish(i, acc, lambda: xwin[pl.ds(Lpad, tile)], y_ref,
+                       dot_ref)
+
+            pl.run_scoped(body, pltpu.VMEM((win,), x.dtype),
+                          pltpu.SemaphoreType.DMA((3,)))
+    else:
+        lo, hi = _ring_span(far, Lpad, Rpad, tile)
+        K = hi - lo + 2
+        scratch = ([pltpu.VMEM((win,), x.dtype) for _ in range(K)]
+                   + [pltpu.SemaphoreType.DMA((K,))])
+        # the ring is state carried from step to step: the grid must
+        # stay sequential
+        params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+        def kernel(x_hbm, *refs):
+            plane_refs = refs[:nplanes]
+            y_ref, dot_ref = outputs(refs)
+            ring, sems = refs[-K - 1:-1], refs[-1]
+            i = pl.program_id(0)
+
+            def fetch(t, slot):
+                # int32-explicit semaphore index: under jax_enable_x64 a
+                # Python int traces as an i64 constant
+                return pltpu.make_async_copy(
+                    x_hbm.at[pl.ds(pl.multiple_of(t * tile, align), tile)],
+                    ring[slot].at[pl.ds(Lpad, tile)],
+                    sems.at[jnp.int32(slot)])
+
+            def zero(slot):
+                ring[slot][pl.ds(Lpad, tile)] = jnp.zeros((tile,), x.dtype)
+
+            def step(r):
+                """Grid step ``i`` with ``i mod K == r``."""
+                def slot(d):  # the slot of tile i + d
+                    return (r + d) % K
+
+                if r == 0:
+                    @pl.when(i == 0)
+                    def _():
+                        for t in range(lo, hi + 1):
+                            if 0 <= t < grid:
+                                fetch(t, slot(t)).start()
+                            else:
+                                zero(slot(t))
+
+                # the tile step i+1 reads first: in flight behind this
+                # step's work, into the slot of tile i+lo-1, now unread
+                @pl.when(i + hi + 1 < grid)
                 def _():
-                    dot_ref[0] = partial
+                    fetch(i + hi + 1, slot(hi + 1)).start()
 
-                @pl.when(i > 0)
+                if r == 0:
+                    @pl.when(i == 0)
+                    def _():
+                        for t in range(0, min(hi, grid - 1) + 1):
+                            fetch(t, slot(t)).wait()
+
+                @pl.when((i > 0) & (i + hi < grid))
                 def _():
-                    dot_ref[0] += partial
+                    fetch(i + hi, slot(hi)).wait()
 
-        pl.run_scoped(body, pltpu.VMEM((win,), x.dtype),
-                      *[pltpu.VMEM((tile,), x.dtype) for _ in far],
-                      pltpu.SemaphoreType.DMA((3 + len(far),)))
+                @pl.when((i > 0) & (i + hi >= grid))
+                def _():
+                    zero(slot(hi))
+
+                body = ring[slot(0)]
+                if Lpad:
+                    body[pl.ds(0, Lpad)] = ring[slot(-1)][pl.ds(tile, Lpad)]
+                if Rpad:
+                    body[pl.ds(Lpad + tile, Rpad)] = \
+                        ring[slot(1)][pl.ds(Lpad, Rpad)]
+
+                def window_of(off):
+                    if off in far:
+                        return ring[slot(off // tile)][pl.ds(Lpad, tile)]
+                    return body[pl.ds(Lpad + off, tile)]
+
+                acc = accumulate(plane_refs, window_of)
+                finish(i, acc, lambda: body[pl.ds(Lpad, tile)], y_ref,
+                       dot_ref)
+
+            # int32-explicit modulo: under jax_enable_x64 a plain `i % K`
+            # promotes through int64, which Mosaic cannot lower
+            phase = jax.lax.rem(i, jnp.int32(K))
+            for r in range(K):
+                pl.when(phase == jnp.int32(r))(functools.partial(step, r))
 
     tile_spec = pl.BlockSpec((tile,), lambda i: (i,),
                              memory_space=pltpu.VMEM)
@@ -381,7 +477,7 @@ def _dia_spmv_clustered(planes, offsets, x, central, far, Lpad, Rpad,
         out_specs = (tile_spec,
                      pl.BlockSpec((1,), lambda i: (0,),
                                   memory_space=pltpu.SMEM))
-        out_shape = (out_shape, jax.ShapeDtypeStruct((1,), acc_dtype(x.dtype)))
+        out_shape = (out_shape, jax.ShapeDtypeStruct((1,), kadt))
     return pl.pallas_call(
         kernel,
         grid=(grid,),
@@ -390,6 +486,8 @@ def _dia_spmv_clustered(planes, offsets, x, central, far, Lpad, Rpad,
             for _ in planes],
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=params,
         interpret=interpret,
         name="acg_dia_spmv_dot" if with_dot else "acg_dia_spmv",
     )(x, *planes)

@@ -84,36 +84,115 @@ def test_solver_auto_kernels_off_tpu_is_xla():
     assert JaxCGSolver(dev, kernels="auto").kernels == "xla"
 
 
-def test_dia_spmv_clustered_route_and_numerics():
+def _plane_order_f32(planes, offsets, x):
+    """The kernel's arithmetic in numpy: ``acc = acc + plane * x[i+off]``
+    over the planes in ``offsets`` order, in f32, x zero off either end;
+    returns the f32 accumulator (the kernel stores it in x's dtype)."""
+    n = x.shape[0]
+    xs = np.asarray(x).astype(np.float32)
+    acc = np.zeros(n, np.float32)
+    for plane, off in zip(planes, offsets):
+        shifted = np.zeros(n, np.float32)
+        lo, hi = max(0, -off), min(n, n - off)
+        shifted[lo:hi] = xs[lo + off:hi + off]
+        acc = acc + np.asarray(plane).astype(np.float32) * shifted
+    return acc
+
+
+# (n, offsets, tile): far offsets one, two and four tiles away, grids of
+# 2s+1 tiles or fewer (every tile an edge tile of the ring) and longer
+# ones (the ring wraps), one-sided far clusters, a central cluster with
+# no left halo, and the grown tile.  ``tile=None`` takes the route's
+# tile through the public entry points; a shift of one tile is never
+# routed (a window of that band fits VMEM as the cheaper "fast" route),
+# so those cases give the kernel its tile
+@pytest.mark.parametrize("n,offsets,tile,shift", [
+    (3 * TILE, (-TILE, -3, 0, 3, TILE), TILE, 1),
+    (8 * TILE, (-TILE, -3, 0, 3, TILE), TILE, 1),
+    (3 * TILE, (-2 * TILE, 0, 5), None, 2),
+    (5 * TILE, (-2 * TILE, -3, 0, 3, 2 * TILE), None, 2),
+    (7 * TILE, (-3, 0, 3, 2 * TILE), None, 2),
+    (9 * TILE, (-4 * TILE, -3, 0, 3, 4 * TILE), None, 4),
+    (64 * TILE, (-32 * TILE, -3, 0, 3, 32 * TILE), None, None),
+], ids=["s1-grid3", "s1-grid8", "s2-grid3-noleft", "s2-grid5",
+        "s2-grid7-right", "s4-grid9", "grown"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("with_dot", [False, True], ids=["y", "dot"])
+def test_dia_spmv_clustered_route_and_numerics(n, offsets, tile, shift,
+                                               dtype, with_dot):
     """Clustered-offset stencils (3D Poisson shape: far +-n^2 diagonals)
-    take the multi-window kernel; numerics must match dia_mv exactly,
-    including the whole-tile-shift zero-fill at both edges."""
-    import numpy as np
+    take the ring kernel; ``y`` must equal the plane-order f32
+    evaluation bit for bit, including the zero-filled tiles off both
+    edges, and the fused dot the f64 dot."""
+    from acg_tpu.ops.pallas_kernels import (_dia_spmv_clustered,
+                                            dia_spmv_dot, dia_spmv_route)
 
-    from acg_tpu.ops.pallas_kernels import TILE, dia_spmv, dia_spmv_route
-    from acg_tpu.ops.spmv import dia_mv
-
-    # the real 512^3 shape routes clustered
-    r = dia_spmv_route((-262144, -512, -1, 0, 1, 512, 262144),
-                       512 ** 3, np.float32)
-    assert r[0] == "clustered"
-    assert r[1] == (-512, -1, 0, 1, 512) and r[2] == (-262144, 262144)
-
-    # band too wide for one VMEM window, far offsets on tile boundaries
-    n = 64 * TILE
-    offsets = (-32 * TILE, -3, 0, 3, 32 * TILE)
-    assert dia_spmv_route(offsets, n, np.float32)[0] == "clustered"
-    rng = np.random.default_rng(0)
-    planes = tuple(jnp.asarray(rng.random(n), jnp.float32)
-                   for _ in offsets)
-    x = jnp.asarray(rng.random(n), jnp.float32)
-    y = dia_spmv(planes, offsets, x, interpret=True)
-    yref = dia_mv(planes, offsets, n, x)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(yref), rtol=2e-6)
-
+    # the real 512^3 shape routes clustered, on one chip and as the
+    # four-chip shard window (a quarter of the rows plus +-n^2 halos)
+    for rows in (512 ** 3, 512 ** 3 // 4 + 2 * 512 ** 2):
+        r = dia_spmv_route((-262144, -512, -1, 0, 1, 512, 262144),
+                           rows, np.float32)
+        assert r == ("clustered", (-512, -1, 0, 1, 512),
+                     (-262144, 262144), 1024, 1024, 131072, 1024)
     # off-tile far offsets cannot cluster -> xla fallback
-    assert dia_spmv_route((-32 * TILE + 7, 0, 1), n,
+    assert dia_spmv_route((-32 * TILE + 7, 0, 1), 64 * TILE,
                           np.float32)[0] == "xla"
+
+    # values k/64 (k < 64): every product and partial sum is exact in
+    # f32, so the comparison is bitwise whether or not a backend
+    # contracts the multiply-adds (the CPU's does, shape by shape), and
+    # a wrong x position still shows
+    rng = np.random.default_rng(0)
+
+    def draw():
+        return jnp.asarray(rng.integers(1, 64, n) / 64, dtype)
+
+    planes = tuple(draw() for _ in offsets)
+    x = draw()
+    if tile is None:
+        route = dia_spmv_route(offsets, n, dtype)
+        assert route[0] == "clustered"
+        if shift is not None:
+            assert {abs(o) // route[5] for o in route[2]} == {shift}
+        if with_dot:
+            y, d = dia_spmv_dot(planes, offsets, x, interpret=True)
+        else:
+            y = dia_spmv(planes, offsets, x, interpret=True)
+    else:
+        align = 1024 if dtype == jnp.float32 else 2048
+        far = tuple(o for o in offsets if abs(o) >= TILE)
+        out = _dia_spmv_clustered(planes, offsets, x, far, align, align,
+                                  tile, align, True, with_dot=with_dot)
+        y, d = (out[0], out[1][0]) if with_dot else (out, None)
+    acc = _plane_order_f32(planes, offsets, x)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  acc.astype(np.asarray(x).dtype))
+    if with_dot:
+        # f64 ground truth, as in test_dia_spmv_dot_fused; the public
+        # entry returns the scalar in bf16 storage's own precision
+        dref = float(np.asarray(x, np.float64) @ acc.astype(np.float64))
+        rel = 2 ** -8 if tile is None and dtype == jnp.bfloat16 else 3e-4
+        assert float(d) == pytest.approx(dref, rel=rel)
+
+
+@pytest.mark.parametrize("n,offsets,route,reads", [
+    (64 * TILE, (-32 * TILE, -3, 0, 3, 32 * TILE), "clustered", 1.0),
+    (2 * TILE, (-2048, -1, 0, 1, 2048), "fast", (TILE + 2 * 2048) / TILE),
+], ids=["clustered", "fast"])
+def test_solve_route_reports_spmv_x_reads(n, offsets, route, reads):
+    """The solve's route report (the ``--stats-json`` manifest and the
+    benchmark's set-up log) gives the HBM reads of x per SpMV: once on
+    the clustered route's ring, the body plus its band halos on the
+    fast route (1.25 for the 2D n=2048 stencil)."""
+    from acg_tpu.cli import _solve_route
+    from acg_tpu.ops.spmv import DiaMatrix
+
+    planes = tuple(jnp.zeros((n,), jnp.float32) for _ in offsets)
+    A = DiaMatrix(data=planes, offsets=offsets, nrows=n, ncols_padded=n)
+    got = _solve_route(JaxCGSolver(A, kernels="pallas"))
+    assert got["spmv_route"] == "dia-" + route
+    assert got["spmv_x_reads"] == reads
 
 
 def test_dia_spmv_dot_fused():

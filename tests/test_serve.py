@@ -79,6 +79,18 @@ def _doc(**kw):
     return doc
 
 
+def _await_slow_lead(d, timeout: float = 5.0) -> None:
+    """Block until the worker is serving a request submitted from
+    another thread: the worker stamps its ``queue-wait`` stage as it
+    hands the batch to the solver.  An empty queue alone may only mean
+    the request is not submitted yet."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline and not any(
+            "queue-wait" in r["stages"]
+            for r in d.reqlog.snapshot()["inflight"]):
+        time.sleep(0.01)
+
+
 def _true_rel(x, b) -> float:
     r = b - _CSR @ np.asarray(x, dtype=np.float64)
     return float(np.linalg.norm(r) / np.linalg.norm(b))
@@ -229,9 +241,7 @@ def test_coalesced_batch_bitwise_equals_single():
             target=lambda: d.submit(_doc(fault="slow:0.6",
                                          b_seed=99)))]
         threads[0].start()
-        deadline = time.monotonic() + 5.0
-        while len(d.queue) > 0 and time.monotonic() < deadline:
-            time.sleep(0.01)
+        _await_slow_lead(d)
 
         def _go(seed):
             results[seed] = d.submit(_doc(b_seed=seed))
@@ -263,9 +273,7 @@ def test_queue_full_sheds_typed_429():
         t = threading.Thread(
             target=lambda: d.submit(_doc(fault="slow:0.8", b_seed=2)))
         t.start()
-        deadline = time.monotonic() + 5.0
-        while len(d.queue) > 0 and time.monotonic() < deadline:
-            time.sleep(0.01)  # the worker holds the slow lead
+        _await_slow_lead(d)  # the worker holds the slow lead
         filler = threading.Thread(
             target=lambda: d.submit(_doc(b_seed=3)))
         filler.start()
@@ -287,9 +295,7 @@ def test_expired_request_answers_typed_504():
         t = threading.Thread(
             target=lambda: d.submit(_doc(fault="slow:0.8", b_seed=2)))
         t.start()
-        deadline = time.monotonic() + 5.0
-        while len(d.queue) > 0 and time.monotonic() < deadline:
-            time.sleep(0.01)
+        _await_slow_lead(d)
         # queued behind 0.8s of service with a 0.2s budget: the worker
         # answers it typed the moment it pops -- never a hang
         status, body = d.submit(_doc(b_seed=3, timeout=0.2))
@@ -650,9 +656,7 @@ def test_concurrent_coalesced_requests_trace_one_solve(tmp_path):
                                              b_seed=99,
                                              request_id="slow-lead")))]
             threads[0].start()
-            deadline = time.monotonic() + 5.0
-            while len(d.queue) > 0 and time.monotonic() < deadline:
-                time.sleep(0.01)
+            _await_slow_lead(d)
             results = {}
 
             def _go(seed):

@@ -68,11 +68,21 @@ def _stencil_offsets(n: int, dim: int) -> tuple:
 
 
 @pytest.mark.parametrize("kernel", ["dia_spmv", "dia_spmv_dot"])
-@pytest.mark.parametrize("n,dim,route", [(2048, 2, "fast"),
-                                         (512, 3, "clustered")])
-def test_dia_spmv_compiles(topo, tpu_compile, kernel, n, dim, route):
-    N = n ** dim
+@pytest.mark.parametrize("n,dim,parts,route", [
+    pytest.param(2048, 2, 1, "fast", id="2048-2-fast"),
+    pytest.param(512, 3, 1, "clustered", id="512-3-clustered"),
+    pytest.param(512, 3, 4, "clustered", id="512-3-shard4-clustered"),
+])
+def test_dia_spmv_compiles(topo, tpu_compile, kernel, n, dim, parts, route):
+    """The kernel on one chip's whole vector, and on one shard's window
+    of the four-chip pallas-roll SpMV (a quarter of the rows plus its
+    halos: 34,078,720 at 512^3), under the kernel's VMEM budget."""
+    from acg_tpu.parallel.sharded_dia import _halo_sizes
+
     offsets = _stencil_offsets(n, dim)
+    N = n ** dim // parts
+    if parts > 1:
+        N += sum(_halo_sizes(offsets, N))
     assert pk.dia_spmv_route(offsets, N, jnp.float32)[0] == route
     one = SingleDeviceSharding(topo.devices[0])
     vec = jax.ShapeDtypeStruct((N,), jnp.float32, sharding=one)
