@@ -1588,8 +1588,11 @@ def _solve_route(inner) -> dict:
     the resolved kernel tier, the SpMV route (the Pallas DIA kernel's
     route, or the storage format the XLA path multiplies), on the
     kernel's single-x-pass routes the HBM bytes of x one SpMV reads over
-    x's own (``spmv_x_reads``), the halo transport of an explicit mesh,
-    and the ids of the devices that hold a shard of the matrix."""
+    x's own (``spmv_x_reads``), the halo transport of an explicit mesh
+    and its partition (``halo_entries``: the entries one halo exchange
+    moves, summed over the parts; ``rows_max``/``ghosts_max``: the
+    largest part's owned rows and ghosts), and the ids of the devices
+    that hold a shard of the matrix."""
     import jax.numpy as jnp
 
     from acg_tpu.ops.spmv import DiaMatrix
@@ -1604,6 +1607,9 @@ def _solve_route(inner) -> dict:
         out["spmv_route"] = prob.local.format
         out["transport"] = getattr(inner, "comm", None)
         out["devices"] = getattr(inner, "placement", None)
+        out["halo_entries"] = prob.halo_entries()
+        out["rows_max"] = int(prob.nmax_owned)
+        out["ghosts_max"] = int(prob.halo.nmax_ghost)
     elif A is not None:
         route = type(A).__name__.replace("Matrix", "").lower()
         if isinstance(A, DiaMatrix) and isinstance(tier, str) \

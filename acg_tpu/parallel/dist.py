@@ -756,6 +756,17 @@ class DistributedProblem:
             out[s.global_ids[: s.nowned]] = stacked[p, : s.nowned]
         return out
 
+    def halo_entries(self) -> int:
+        """Entries one halo exchange moves, summed over the parts.
+        Local-read problems carry the allgathered total
+        (``halo_send_total``): summing ``subs`` there would count only
+        this controller's parts."""
+        total = getattr(self, "halo_send_total", None)
+        if total is None:
+            total = sum(int(s.halo.total_send) for s in self.subs
+                        if s.halo is not None)
+        return int(total)
+
 
 def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
                    kernels: str = "xla", axis: str = PARTS_AXIS,
@@ -1432,45 +1443,48 @@ class DistCGSolver:
             def pdot3_fused(a1, c1, a2, c2, a3, c3):
                 return _pdotk((a1, c1), (a2, c2), (a3, c3))
 
-            bnrm2 = jnp.sqrt(pdot(b, b))
-            x0nrm2 = jnp.sqrt(pdot(x0, x0))
-            if precond_spec is not None:
-                # papply reuses the tier's halo'd SpMV closure: the
-                # cheby apply's communication is exactly K extra SpMVs
-                _papply = make_apply(precond_spec, lambda _A, x: spmv(x))
-
-                def papply(vec, k=None):
-                    z = _papply(mstate, None, vec)
-                    if fault is not None and k is not None:
-                        z = fault.apply_precond(z, k, pidx)
-                    return z
-
-            if carry is not None:
-                # resume (the survivability tier): the provided carry IS
-                # the loop state -- nothing is recomputed, the Krylov
-                # recurrence continues exactly where the snapshot left
-                # it (x0 holds the snapshot iterate).  The setup SpMV
-                # and its collectives are skipped on every shard alike
-                # (carry is a static python branch, mesh-uniform)
-                r = carry[0]
+            # r0 = b - A x0 and the pre-loop dots (jax_cg._cg_program's
+            # device-scope vocabulary; the loop bodies name theirs)
+            with jax.named_scope("acg.setup"):
+                bnrm2 = jnp.sqrt(pdot(b, b))
+                x0nrm2 = jnp.sqrt(pdot(x0, x0))
                 if precond_spec is not None:
-                    r0nrm2 = jnp.sqrt(carry[-1])
-                elif pipelined:
-                    r0nrm2 = jnp.sqrt(jnp.maximum(carry[-2], 0))
+                    # papply reuses the tier's halo'd SpMV closure: the
+                    # cheby apply's communication is exactly K extra SpMVs
+                    _papply = make_apply(precond_spec, lambda _A, x: spmv(x))
+
+                    def papply(vec, k=None):
+                        z = _papply(mstate, None, vec)
+                        if fault is not None and k is not None:
+                            z = fault.apply_precond(z, k, pidx)
+                        return z
+
+                if carry is not None:
+                    # resume (the survivability tier): the provided carry IS
+                    # the loop state -- nothing is recomputed, the Krylov
+                    # recurrence continues exactly where the snapshot left
+                    # it (x0 holds the snapshot iterate).  The setup SpMV
+                    # and its collectives are skipped on every shard alike
+                    # (carry is a static python branch, mesh-uniform)
+                    r = carry[0]
+                    if precond_spec is not None:
+                        r0nrm2 = jnp.sqrt(carry[-1])
+                    elif pipelined:
+                        r0nrm2 = jnp.sqrt(jnp.maximum(carry[-2], 0))
+                    else:
+                        r0nrm2 = jnp.sqrt(carry[-1])
+                elif precond_spec is not None:
+                    r = b - spmv(x0)
+                    u0 = store(papply(r))
+                    gamma0, rr0 = pdot2_fused(r, u0, r, r)
+                    gamma = rr0
+                    r0nrm2 = jnp.sqrt(rr0)
                 else:
-                    r0nrm2 = jnp.sqrt(carry[-1])
-            elif precond_spec is not None:
-                r = b - spmv(x0)
-                u0 = store(papply(r))
-                gamma0, rr0 = pdot2_fused(r, u0, r, r)
-                gamma = rr0
-                r0nrm2 = jnp.sqrt(rr0)
-            else:
-                r = b - spmv(x0)
-                gamma = pdot(r, r)
-                r0nrm2 = jnp.sqrt(gamma)
-            res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-            diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
+                    r = b - spmv(x0)
+                    gamma = pdot(r, r)
+                    r0nrm2 = jnp.sqrt(gamma)
+                res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
+                diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
             inf = jnp.asarray(jnp.inf, sdt)
             if health is not None and health.abft:
                 # the column checksum c = A^T 1 (= A 1: symmetric
@@ -1591,37 +1605,45 @@ class DistCGSolver:
                         aud, state = state[-1], state[:-1]
                     x, r, p, gamma = state[:4]
                     t = spmv(p, k)
-                    pdott = pdot(p, t)
+                    with jax.named_scope("acg.dot"):
+                        pdott = pdot(p, t)
                     if fault is not None:
                         pdott = fault.apply_dot(pdott, k)
-                    if detect:
-                        # breakdown detection mirrors jax_cg._cg_program
-                        # (shared predicate; the deferred gamma_next
-                        # term below too): every flagged scalar is
-                        # psum'd, so `bad` is identical on all shards
-                        # and the early exit is mesh-uniform
-                        bad, alpha = _breakdown_guard(gamma, pdott)
-                        x = store(jnp.where(bad, x, x + alpha * p))
-                        r = store(jnp.where(bad, r, r - alpha * t))
-                    else:
-                        alpha = gamma / pdott
-                        x = store(x + alpha * p)
-                        r = store(r - alpha * t)
+                    with jax.named_scope("acg.update"):
+                        if detect:
+                            # breakdown detection mirrors jax_cg.
+                            # _cg_program (shared predicate; the deferred
+                            # gamma_next term below too): every flagged
+                            # scalar is psum'd, so `bad` is identical on
+                            # all shards and the early exit is
+                            # mesh-uniform
+                            bad, alpha = _breakdown_guard(gamma, pdott)
+                            x = store(jnp.where(bad, x, x + alpha * p))
+                            r = store(jnp.where(bad, r, r - alpha * t))
+                        else:
+                            alpha = gamma / pdott
+                            x = store(x + alpha * p)
+                            r = store(r - alpha * t)
                     if precond_spec is not None:
                         z = papply(r, k)
                         # ONE fused psum for both scalars: the classic
                         # PCG loop keeps 2 allreduces per iteration
-                        gamma_next, rr_next = pdot2_fused(r, z, r, r)
-                        beta = gamma_next / gamma
-                        p_next = store(z + beta * p)
+                        with jax.named_scope("acg.dot"):
+                            gamma_next, rr_next = pdot2_fused(r, z, r, r)
+                        with jax.named_scope("acg.update"):
+                            beta = gamma_next / gamma
+                            p_next = store(z + beta * p)
                         out = (x, r, p_next, gamma_next, rr_next)
                     else:
-                        gamma_next = pdot(r, r)
-                        beta = gamma_next / gamma
-                        p_next = store(r + beta * p)
+                        with jax.named_scope("acg.dot"):
+                            gamma_next = pdot(r, r)
+                        with jax.named_scope("acg.update"):
+                            beta = gamma_next / gamma
+                            p_next = store(r + beta * p)
                         out = (x, r, p_next, gamma_next)
                     if needs_diff:
-                        dx = alpha * alpha * psum(ldot(p, p))
+                        with jax.named_scope("acg.dot"):
+                            dx = alpha * alpha * psum(ldot(p, p))
                         if detect:
                             # freeze dx on breakdown (jax_cg rationale):
                             # alpha = 0 must not fake the diff criterion
@@ -2573,128 +2595,132 @@ class DistCGSolver:
                                     raise_on_divergence=raise_on_divergence,
                                     warmup=warmup,
                                     host_result=host_result)
-        crit = criteria or StoppingCriteria()
-        st = self.stats
-        st.criteria = crit
-        prob = self.problem
-        dtype = np.dtype(prob.vdtype)
-        if self.replace_every and crit.needs_diff:
-            raise ValueError("replace_every supports residual criteria "
-                             "only")
-        if self.kernels == "fused" and crit.needs_diff:
-            raise ValueError("kernels='fused' supports residual "
-                             "criteria only (the builder base program "
-                             "carries no dx scalar)")
-
-        from acg_tpu import faults
-        self._crash_refusal()
-        fault = faults.device_fault()
-        if fault is not None and self.kernels == "fused":
-            # the fused base program carries no breakdown flag: an
-            # armed injector would poison the solve with nothing
-            # downstream ever noticing (the replace_every rationale)
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                "fault injection does not reach the fused "
-                "interior/border program (kernels='fused'); inject "
-                "into the classic/pipelined programs instead")
-        if (fault is not None and fault.site == "halo"
-                and not prob.halo.has_ghosts):
-            # this topology performs no halo exchange: the armed
-            # injector could never fire (the replace_every rationale)
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                "halo fault injection needs a topology with ghost "
-                "exchange; this problem has no halo (single part or "
-                "fully decoupled partition)")
-        if fault is not None and fault.part >= prob.nparts:
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                f"fault spec targets part {fault.part}, but this mesh "
-                f"has {prob.nparts} parts -- the fault could never "
-                f"fire")
-        if fault is not None and self.replace_every:
-            # the replacement segments call the dist SpMV without the
-            # global iteration index: an armed injector would silently
-            # never fire (jax_cg rationale) -- refuse instead
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                "fault injection does not reach the replacement-segment "
-                "program (replace_every); inject into the direct "
-                "classic/pipelined programs instead")
-        if (self.algo is not None and fault is not None
-                and self.algo.kind == "sstep"
-                and fault.site in ("spmv", "sdc", "halo")
-                and fault.iteration % self.algo.param != 0):
-            # the s-step basis products carry the BLOCK-START iteration
-            # index (jax_cg rationale): mid-block arming never fires
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                f"sstep:{self.algo.param} applies SpMV/halo faults at "
-                f"block boundaries; arm an iteration that is a "
-                f"multiple of {self.algo.param} (got "
-                f"{fault.iteration})")
-        if (self.algo is not None and fault is not None
-                and self.algo.kind == "pl" and fault.site == "dot"):
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                "dot fault injection has no site in the p(l) "
-                "recurrence (its reductions are fused window matvecs); "
-                "use spmv:, or the classic/pipelined/sstep programs")
-        if (fault is not None and fault.site == "precond"
-                and self.precond_spec is None):
-            # no preconditioner armed: the apply the fault poisons
-            # never runs (the replace_every rationale)
-            raise AcgError(
-                ErrorCode.INVALID_VALUE,
-                "precond fault injection needs an armed preconditioner "
-                "(--precond jacobi|bjacobi|cheby:K); this solve runs "
-                "unpreconditioned CG")
-        detect = self._detect(fault)
         from acg_tpu import telemetry
-        if self._comm_downgrade is not None:
-            # the capability-probe downgrade, recorded once as a
-            # structured event so stats/metrics consumers see WHY this
-            # solve ran the xla transport
-            telemetry.record_event(st, "transport-downgrade",
-                                   f"dma -> xla: {self._comm_downgrade}")
-            self._comm_downgrade = None
-        if fault is not None:
-            telemetry.record_event(st, "fault-armed",
-                                   f"{fault.site}:{fault.mode}"
-                                   f"@{fault.iteration}")
-        # an armed injector bakes into a solve-local program; the cached
-        # pristine program serves every clean solve
-        program = self._program_for(fault)
+        # host spans (telemetry.span, on the profiler's clock), as in
+        # JaxCGSolver.solve: prepare (transfer inside it), dispatch and
+        # wait inside the solve phase, then fetch, check and account
+        with telemetry.span("prepare"):
+            crit = criteria or StoppingCriteria()
+            st = self.stats
+            st.criteria = crit
+            prob = self.problem
+            dtype = np.dtype(prob.vdtype)
+            if self.replace_every and crit.needs_diff:
+                raise ValueError("replace_every supports residual criteria "
+                                 "only")
+            if self.kernels == "fused" and crit.needs_diff:
+                raise ValueError("kernels='fused' supports residual "
+                                 "criteria only (the fused base program "
+                                 "carries no dx scalar)")
 
-        t_xfer = time.perf_counter()
-        with telemetry.annotate("transfer"):
-            b, x0, la, ga, sidx, gsrc, gval, scnt, rcnt = \
-                self.device_args(b_global, x0)
-        telemetry.add_timing(st, "transfer", time.perf_counter() - t_xfer)
-        self.placement = shard_devices(la)
-        # tolerances in the scalar dtype (f32 for bf16 storage) so a 1e-9
-        # rtol is not pre-rounded to 8 mantissa bits
-        sdt = acc_dtype(dtype)
-        tols = jnp.asarray([crit.residual_atol, crit.residual_rtol,
-                            crit.diff_atol, crit.diff_rtol], dtype=sdt)
-        kwargs = dict(unbounded=crit.unbounded, needs_diff=crit.needs_diff,
-                      detect=detect)
-        if self.precond_spec is not None:
-            self._last_dev_args = (b, x0, la, ga, sidx, gsrc, gval,
-                                   scnt, rcnt)
-            kwargs["mstate"] = self._ensure_precond_state(
-                self._last_dev_args)
-        args = (la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols,
-                jnp.int32(crit.maxits))
-        if self.algo is not None:
-            if crit.needs_diff:
-                raise ValueError(f"{self.algo} supports residual "
-                                 f"criteria only")
-            lam = self._ensure_lam((b, x0, la, ga, sidx, gsrc, gval,
-                                    scnt, rcnt))
-            args = args + ((jnp.asarray(lam[0], sdt),
-                            jnp.asarray(lam[1], sdt)),)
+            from acg_tpu import faults
+            self._crash_refusal()
+            fault = faults.device_fault()
+            if fault is not None and self.kernels == "fused":
+                # the fused base program carries no breakdown flag: an
+                # armed injector would poison the solve with nothing
+                # downstream ever noticing (the replace_every rationale)
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    "fault injection does not reach the fused "
+                    "interior/border program (kernels='fused'); inject "
+                    "into the classic/pipelined programs instead")
+            if (fault is not None and fault.site == "halo"
+                    and not prob.halo.has_ghosts):
+                # this topology performs no halo exchange: the armed
+                # injector could never fire (the replace_every rationale)
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    "halo fault injection needs a topology with ghost "
+                    "exchange; this problem has no halo (single part or "
+                    "fully decoupled partition)")
+            if fault is not None and fault.part >= prob.nparts:
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    f"fault spec targets part {fault.part}, but this mesh "
+                    f"has {prob.nparts} parts -- the fault could never "
+                    f"fire")
+            if fault is not None and self.replace_every:
+                # the replacement segments call the dist SpMV without the
+                # global iteration index: an armed injector would silently
+                # never fire (jax_cg rationale) -- refuse instead
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    "fault injection does not reach the replacement-segment "
+                    "program (replace_every); inject into the direct "
+                    "classic/pipelined programs instead")
+            if (self.algo is not None and fault is not None
+                    and self.algo.kind == "sstep"
+                    and fault.site in ("spmv", "sdc", "halo")
+                    and fault.iteration % self.algo.param != 0):
+                # the s-step basis products carry the BLOCK-START iteration
+                # index (jax_cg rationale): mid-block arming never fires
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    f"sstep:{self.algo.param} applies SpMV/halo faults at "
+                    f"block boundaries; arm an iteration that is a "
+                    f"multiple of {self.algo.param} (got "
+                    f"{fault.iteration})")
+            if (self.algo is not None and fault is not None
+                    and self.algo.kind == "pl" and fault.site == "dot"):
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    "dot fault injection has no site in the p(l) "
+                    "recurrence (its reductions are fused window matvecs); "
+                    "use spmv:, or the classic/pipelined/sstep programs")
+            if (fault is not None and fault.site == "precond"
+                    and self.precond_spec is None):
+                # no preconditioner armed: the apply the fault poisons
+                # never runs (the replace_every rationale)
+                raise AcgError(
+                    ErrorCode.INVALID_VALUE,
+                    "precond fault injection needs an armed preconditioner "
+                    "(--precond jacobi|bjacobi|cheby:K); this solve runs "
+                    "unpreconditioned CG")
+            detect = self._detect(fault)
+            if self._comm_downgrade is not None:
+                # the capability-probe downgrade, recorded once as a
+                # structured event so stats/metrics consumers see WHY this
+                # solve ran the xla transport
+                telemetry.record_event(st, "transport-downgrade",
+                                       f"dma -> xla: {self._comm_downgrade}")
+                self._comm_downgrade = None
+            if fault is not None:
+                telemetry.record_event(st, "fault-armed",
+                                       f"{fault.site}:{fault.mode}"
+                                       f"@{fault.iteration}")
+            # an armed injector bakes into a solve-local program; the cached
+            # pristine program serves every clean solve
+            program = self._program_for(fault)
+
+            t_xfer = time.perf_counter()
+            with telemetry.annotate("transfer"):
+                b, x0, la, ga, sidx, gsrc, gval, scnt, rcnt = \
+                    self.device_args(b_global, x0)
+            telemetry.add_timing(st, "transfer", time.perf_counter() - t_xfer)
+            self.placement = shard_devices(la)
+            # tolerances in the scalar dtype (f32 for bf16 storage) so a 1e-9
+            # rtol is not pre-rounded to 8 mantissa bits
+            sdt = acc_dtype(dtype)
+            tols = jnp.asarray([crit.residual_atol, crit.residual_rtol,
+                                crit.diff_atol, crit.diff_rtol], dtype=sdt)
+            kwargs = dict(unbounded=crit.unbounded, needs_diff=crit.needs_diff,
+                          detect=detect)
+            if self.precond_spec is not None:
+                self._last_dev_args = (b, x0, la, ga, sidx, gsrc, gval,
+                                       scnt, rcnt)
+                kwargs["mstate"] = self._ensure_precond_state(
+                    self._last_dev_args)
+            args = (la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols,
+                    jnp.int32(crit.maxits))
+            if self.algo is not None:
+                if crit.needs_diff:
+                    raise ValueError(f"{self.algo} supports residual "
+                                     f"criteria only")
+                lam = self._ensure_lam((b, x0, la, ga, sidx, gsrc, gval,
+                                        scnt, rcnt))
+                args = args + ((jnp.asarray(lam[0], sdt),
+                                jnp.asarray(lam[1], sdt)),)
         if warmup > 0:
             t_warm = time.perf_counter()
             with telemetry.annotate("compile"):
@@ -2724,9 +2750,13 @@ class DistCGSolver:
 
         t0 = time.perf_counter()
         with telemetry.annotate("solve"):
-            out = program(*args, **kwargs)
-            jax.block_until_ready(out[0])
-        niter = int(out[1])
+            with telemetry.span("dispatch"):
+                out = program(*args, **kwargs)
+            with telemetry.span("wait"):
+                jax.block_until_ready(out[0])
+        with telemetry.span("fetch"):
+            niter = int(out[1])
+            broke = detect and bool(out[8])
         first_norms = None
         # first note_audit resets the summary, later attempts merge
         # (the jax_cg rationale: a recovered solve must still show the
@@ -2734,7 +2764,7 @@ class DistCGSolver:
         # attempt's exit as an accuracy gate for the raise below
         aud_fresh = True
         gap_tripped = False
-        if detect and bool(out[8]):
+        if broke:
             # the recovery ladder (solvers.resilience): bounded restarts
             # from the recomputed true residual; a recurring breakdown
             # under the dma transport retires it for the xla
@@ -2874,46 +2904,48 @@ class DistCGSolver:
                         f"fallback available")
                 raise driver.give_up(niter, float(out[2]))
         t_solve = time.perf_counter() - t0
-        st.tsolve += t_solve
-        telemetry.add_timing(st, "solve", t_solve)
-        if self.trace:
-            st.trace = self.last_trace = attempt_trace(out)
-
         x_st, k, rnrm2, r0nrm2, bnrm2, x0nrm2, dxnrm2, done = out[:8]
-        st.nsolves += 1
-        st.niterations = niter
-        st.ntotaliterations += niter
-        st.bnrm2, st.x0nrm2, st.r0nrm2 = (
-            first_norms if first_norms is not None
-            else (float(bnrm2), float(x0nrm2), float(r0nrm2)))
-        st.rnrm2 = float(rnrm2)
-        st.dxnrm2 = float(dxnrm2)
-        st.converged = bool(done) or crit.unbounded
-        if hl:
-            from acg_tpu import health as health_mod
-            health_mod.note_audit(st, attempt_aud(out),
-                                  self.health_spec, "dist-cg",
-                                  fresh=aud_fresh)
-        # service-metrics tier (no-op disarmed): one completed solve,
-        # plus this solve's halo/psum traffic folded out of the static
-        # comm ledger (comm_profile, the perfmodel tier's hook)
-        from acg_tpu import metrics
-        metrics.record_solve(t_solve, niter, st.converged,
-                             solver=self._solver_name())
-        metrics.observe_solver_comm(self, niter)
-        self._account_ops(st, niter)
-
-        if host_result:
-            x = prob.gather(get_global(x_st))
-            st.fexcept_arrays = [x]
-        else:
-            x = x_st
-            # device-side scans; only two bools cross the wire (the
-            # JaxCGSolver host_result=False convention)
-            has_nan = bool(jnp.isnan(x_st).any())
-            has_inf = bool(jnp.isinf(x_st).any())
-            st.fexcept_arrays = [np.asarray([np.nan if has_nan else 0.0,
-                                             np.inf if has_inf else 0.0])]
+        with telemetry.span("fetch"):
+            if self.trace:
+                st.trace = self.last_trace = attempt_trace(out)
+            st.bnrm2, st.x0nrm2, st.r0nrm2 = (
+                first_norms if first_norms is not None
+                else (float(bnrm2), float(x0nrm2), float(r0nrm2)))
+            st.rnrm2 = float(rnrm2)
+            st.dxnrm2 = float(dxnrm2)
+            st.converged = bool(done) or crit.unbounded
+        with telemetry.span("check"):
+            if host_result:
+                x = prob.gather(get_global(x_st))
+                st.fexcept_arrays = [x]
+            else:
+                x = x_st
+                # device-side scans; only two bools cross the wire (the
+                # JaxCGSolver host_result=False convention)
+                has_nan = bool(jnp.isnan(x_st).any())
+                has_inf = bool(jnp.isinf(x_st).any())
+                st.fexcept_arrays = [np.asarray(
+                    [np.nan if has_nan else 0.0,
+                     np.inf if has_inf else 0.0])]
+        with telemetry.span("account"):
+            st.tsolve += t_solve
+            telemetry.add_timing(st, "solve", t_solve)
+            st.nsolves += 1
+            st.niterations = niter
+            st.ntotaliterations += niter
+            if hl:
+                from acg_tpu import health as health_mod
+                health_mod.note_audit(st, attempt_aud(out),
+                                      self.health_spec, "dist-cg",
+                                      fresh=aud_fresh)
+            # service-metrics tier (no-op disarmed): one completed solve,
+            # plus this solve's halo/psum traffic folded out of the
+            # static comm ledger (comm_profile, the perfmodel tier's hook)
+            from acg_tpu import metrics
+            metrics.record_solve(t_solve, niter, st.converged,
+                                 solver=self._solver_name())
+            metrics.observe_solver_comm(self, niter)
+            self._account_ops(st, niter)
         if not st.converged and raise_on_divergence:
             raise NotConvergedError(
                 f"{niter} iterations, residual {st.rnrm2:.3e}")
@@ -2993,13 +3025,7 @@ class DistCGSolver:
             st.ops["allreduce"].add(
                 (1 if self.pipelined else 2) * niter, 0.0,
                 8 * (1 if self.pipelined else 2) * niter)
-        # local-read problems carry the allgathered total (summing subs
-        # here would count only this controller's parts)
-        halo_total = getattr(prob, "halo_send_total", None)
-        if halo_total is None:
-            halo_total = sum(int(s.halo.total_send) for s in prob.subs
-                             if s.halo is not None)
-        halo_bytes = halo_total * dbl
+        halo_bytes = prob.halo_entries() * dbl
         nhalo = int(niter * spmv_eq) + 1
         st.ops["halo"].add(nhalo, 0.0, halo_bytes * nhalo)
         if self.precond_spec is not None:

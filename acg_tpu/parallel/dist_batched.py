@@ -635,12 +635,8 @@ class BatchedDistCGSolver:
         nred = 1 if self.pipelined else 2
         st.ops["allreduce"].add(nred * k_total, 0.0,
                                 8 * nrhs * nred * k_total)
-        halo_total = getattr(prob, "halo_send_total", None)
-        if halo_total is None:
-            halo_total = sum(int(s.halo.total_send) for s in prob.subs
-                             if s.halo is not None)
         st.ops["halo"].add(k_total + 1, 0.0,
-                           halo_total * dbl * nrhs * (k_total + 1))
+                           prob.halo_entries() * dbl * nrhs * (k_total + 1))
 
     # -- survivability: chunked batched dist solve --------------------------
 

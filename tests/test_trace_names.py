@@ -3,8 +3,9 @@
 Device side: the solve programs put their ops under the scopes
 ``acg.setup``, ``acg.spmv``, ``acg.halo``, ``acg.dot`` and ``acg.update``
 (``jax.named_scope``), which land in each compiled op's ``op_name``
-metadata.  Host side: ``JaxCGSolver.solve`` brackets its steps in
-``acg:*`` spans (``telemetry.span``) on the profiler's clock."""
+metadata.  Host side: ``JaxCGSolver.solve`` and ``DistCGSolver.solve``
+bracket their steps in ``acg:*`` spans (``telemetry.span``) on the
+profiler's clock."""
 
 import re
 
@@ -173,6 +174,75 @@ def test_compile_span_only_with_warmup(tmp_path, warm_solver, warmup,
         b, criteria=crit, host_result=False, warmup=warmup))
     assert sum(n == "acg:compile" for n, _, _ in spans) == ncompile
     assert sum(n == "acg:solve" for n, _, _ in spans) == 1
+
+
+@pytest.fixture(scope="module")
+def mesh_solver():
+    """``DistCGSolver`` over four devices of the CPU mesh: a 16x16
+    Poisson grid, graph-partitioned (the built-in bisection), the
+    ``all_to_all`` halo; warmed so the captures hold no compile."""
+    from acg_tpu.graph import partition_matrix
+    from acg_tpu.parallel.dist import DistCGSolver, DistributedProblem
+    from acg_tpu.parallel.mesh import solve_mesh
+    from acg_tpu.partition import partition_rows
+
+    r, c, v, n = poisson2d_coo(16)
+    csr = SymCsrMatrix.from_coo(n, r, c, v).to_csr()
+    part = partition_rows(csr, 4, method="graph", use_metis="never")
+    prob = DistributedProblem.build(
+        csr, part, 4, dtype=jnp.float32,
+        subs=partition_matrix(csr, part, 4), vector_dtype=jnp.float32)
+    solver = DistCGSolver(prob, comm="xla", mesh=solve_mesh(4))
+    b = np.ones(n)
+    crit = StoppingCriteria(maxits=200, residual_rtol=1e-6)
+    solver.solve(b, criteria=crit, host_result=False)
+    return solver, b, crit
+
+
+def test_mesh_loop_ops_carry_acg_scopes(mesh_solver):
+    """The four-part classic program (shard_map, all_to_all halo): the
+    loop body's ops sit under acg.spmv, acg.halo, acg.dot and
+    acg.update, and the set-up residual under acg.setup."""
+    solver, b, crit = mesh_solver
+    names = op_names(solver.lower_solve(b, criteria=crit)
+                     .compile().as_text())
+    assert {"acg.spmv", "acg.halo", "acg.dot", "acg.update"} <= \
+        scopes_under(names, BODY)
+    assert {"acg.spmv", "acg.halo"} <= scopes_under(names, "acg.setup/")
+    # the pre-loop norms' all-reduces sit under acg.setup itself
+    assert any("acg.setup/" in n and BODY not in n and "psum" in n
+               for n in names), sorted(names)
+    halo = [n for n in names if BODY in n and "acg.halo" in n]
+    assert any(n.endswith("/all_to_all") for n in halo), halo
+
+
+@pytest.mark.parametrize("host_result", [False, True])
+def test_mesh_solve_host_spans_in_order(tmp_path, mesh_solver, host_result):
+    """``DistCGSolver.solve`` brackets its steps in the spans of
+    ``JaxCGSolver.solve``: prepare (holding transfer), dispatch and
+    wait inside solve, then fetch, check (the finiteness scans, or the
+    gather of x with ``host_result``) and account."""
+    solver, b, crit = mesh_solver
+    spans = capture_spans(tmp_path, lambda: solver.solve(
+        b, criteria=crit, host_result=host_result))
+    first = {}
+    for name, s, e in spans:
+        first.setdefault(name, (s, e))
+    order = ["acg:prepare", "acg:solve", "acg:fetch", "acg:check",
+             "acg:account"]
+    assert set(order) | {"acg:transfer", "acg:dispatch", "acg:wait"} == \
+        set(first), sorted(first)
+    starts = [first[n][0] for n in order]
+    assert starts == sorted(starts)
+
+    def inside(inner, outer):
+        return (first[outer][0] <= first[inner][0]
+                and first[inner][1] <= first[outer][1])
+
+    assert inside("acg:transfer", "acg:prepare")
+    assert inside("acg:dispatch", "acg:solve")
+    assert inside("acg:wait", "acg:solve")
+    assert first["acg:solve"][1] <= first["acg:fetch"][0]
 
 
 def test_spans_keep_the_status_phase(warm_solver):
