@@ -155,8 +155,8 @@ def make_parser() -> argparse.ArgumentParser:
                         "OVERLAPPED iteration (halo exchange in flight "
                         "behind the interior SpMV; classic + pipelined); "
                         "auto picks pallas on TPU hardware for DIA "
-                        "matrices and DIA local blocks of the multi-part "
-                        "path")
+                        "matrices and the DIA and row-tiled DIA local "
+                        "blocks of the multi-part path")
     p.add_argument("--spmv-format", default="auto",
                    choices=["auto", "dia", "ell", "coo"],
                    help="force the device sparse format for the "
@@ -1588,7 +1588,10 @@ def _solve_route(inner) -> dict:
     the resolved kernel tier, the SpMV route (the Pallas DIA kernel's
     route, or the storage format the XLA path multiplies), on the
     kernel's single-x-pass routes the HBM bytes of x one SpMV reads over
-    x's own (``spmv_x_reads``), the halo transport of an explicit mesh
+    x's own (``spmv_x_reads``), on row-tiled DIA local blocks the rows a
+    tile (``spmv_tile``), the segments a part (``spmv_segments_max``) and
+    the plane elements stored per local nonzero (``spmv_fill``), the
+    halo transport of an explicit mesh
     and its partition (``halo_entries``: the entries one halo exchange
     moves, summed over the parts; ``rows_max``/``ghosts_max``: the
     largest part's owned rows and ghosts), and the ids of the devices
@@ -1605,6 +1608,12 @@ def _solve_route(inner) -> dict:
     A = getattr(inner, "_A_program", None) or getattr(inner, "A", None)
     if prob is not None:
         out["spmv_route"] = prob.local.format
+        if prob.local.format == "tiledia":
+            planes = prob.local.arrays[0]
+            out["spmv_tile"] = int(prob.local.tile)
+            out["spmv_segments_max"] = int(planes.shape[1])
+            out["spmv_fill"] = planes.size / sum(
+                int(s.A_local.nnz) for s in prob.subs)
         out["transport"] = getattr(inner, "comm", None)
         out["devices"] = getattr(inner, "placement", None)
         out["halo_entries"] = prob.halo_entries()

@@ -15,19 +15,25 @@ a *specific* HBM-traffic bound the fusion cannot reach:
   the "fast" route, ``dia_spmv_x_reads``) -- the same traffic argument
   as the reference's merge-CSR kernel, restated for a vector
   architecture.
+* :func:`tiledia_spmv` -- the row-tiled DIA local block of a graph
+  partition with no gather of x: each (tile, offset) segment reads its x
+  window from a VMEM-resident x by unaligned row loads and lane rotates,
+  where the XLA formulation (``ops/spmv.py:tiledia_mv``) gathers every
+  window element.
 * :func:`fused_pipelined_update` -- the Ghysels-Vanroose 6-vector update
   (z,t,p,x,r,w) in one pass with alpha/beta in SMEM, the analog of
   ``acgsolvercuda_pipelined_update_kernel`` (``cg-kernels-cuda.cu:
   187-269``).
 
-Both run in interpret mode on CPU (tests) and compiled on TPU.  Whether
+All run in interpret mode on CPU (tests) and compiled on TPU.  Whether
 they actually beat XLA fusion is *measured* (``scripts/bench_pallas.py``,
 BASELINE.md) -- the solvers select per measurement via
 ``kernels="pallas"``.
 
 Every ``pallas_call`` here passes a stable ``name=`` (``acg_dia_spmv``,
-``acg_dia_spmv_dot``, ``acg_dia_spmv_padded``, ``acg_fused_cg_a``/``_b``,
-``acg_fused_pipelined_update``, ``acg_stencil_spmv``): the compiled
+``acg_dia_spmv_dot``, ``acg_dia_spmv_padded``, ``acg_tiledia_spmv``,
+``acg_fused_cg_a``/``_b``, ``acg_fused_pipelined_update``,
+``acg_stencil_spmv``): the compiled
 custom call, and so its event in a profiler capture, carries that name
 (``acg_dia_spmv.7``), whatever XLA numbers around it.
 
@@ -51,7 +57,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from acg_tpu.ops.spmv import acc_dtype
+from acg_tpu.ops.spmv import TILEDIA_TILE, acc_dtype, tiledia_mv
 
 # row-tile length for the SpMV kernel; multiple of the f32 (8,128) tile
 TILE = 16384
@@ -538,6 +544,131 @@ def _dia_spmv_padded(planes, offsets, x, L, R, interpret):
         name="acg_dia_spmv_padded",
     )(xp, *planes)
     return y[:n]
+
+
+# row-tiled DIA: one tile of rows (TILEDIA_TILE) is one (8, 128) f32
+# vreg; the kernel walks the segments in chunks of TILEDIA_CHUNK per grid
+# step (the SMEM blocks of per-segment scalars must match XLA's T(1024)
+# layout)
+TILEDIA_CHUNK = 1024
+# segments whose x windows are loaded and rotated before their products
+# join the tiles' running sums: loads issued ahead of the stores that
+# would otherwise order them (3.6x on the 2D n=2048 part, TPU v5e)
+TILEDIA_UNROLL = 8
+_TILEDIA_VMEM = 96 * 2 ** 20
+
+
+def _tiledia_layout(n: int, reach: tuple):
+    """``(ntiles, L, xlen, vmem)`` of the kernel for ``n`` rows: x is
+    held whole in VMEM as rows of 128 lanes, padded by ``L`` (the lowest
+    offset, rounded up to a tile) in front and enough behind that every
+    segment's window, the dummy tile's included, stays inside."""
+    T = TILEDIA_TILE
+    ntiles = -(-n // T)
+    L = -(-max(0, -reach[0]) // T) * T
+    R = -(-max(0, reach[1]) // T) * T
+    xlen = L + (ntiles + 2) * T + R
+    vmem = 4 * (xlen + (ntiles + 1) * T + 2 * TILEDIA_CHUNK * T)
+    return ntiles, L, xlen, vmem
+
+
+def tiledia_fits(n: int, tile: int, reach: tuple, dtype) -> bool:
+    """Whether :func:`tiledia_spmv` runs the kernel for this block (its
+    tile is the kernel's, the storage at most f32 wide, and x, y and two
+    plane chunks fit VMEM), rather than
+    :func:`acg_tpu.ops.spmv.tiledia_mv`."""
+    return (tile == TILEDIA_TILE and jnp.dtype(dtype).itemsize <= 4
+            and _tiledia_layout(n, reach)[3] <= _TILEDIA_VMEM)
+
+
+@functools.partial(jax.jit, static_argnames=("reach", "interpret"))
+def tiledia_spmv(planes, seg_off, seg_tile, x, reach: tuple,
+                 interpret: bool = False):
+    """y = A @ x for a row-tiled DIA block, gather-free: the same
+    operator and per-row summation order as
+    :func:`acg_tpu.ops.spmv.tiledia_mv`, in f32 (sub-f32 storage is
+    widened first, the :func:`acc_dtype` rule).
+
+    x sits whole in VMEM as rows of 128 lanes; y is one VMEM-resident
+    output, written back once.  The grid walks the segments in order,
+    ``TILEDIA_CHUNK`` a step, their planes streamed by BlockSpec and
+    their scalars (window start, output row, first-of-tile) in SMEM.  A
+    segment's x window ``[w, w + 1024)`` is the eight rows from row ``w
+    // 128`` and the eight from the next, each rotated left by ``w mod
+    128`` lanes, the first's lanes kept where they do not wrap.  Its
+    product adds to a running tile sum, reset at a tile's first segment
+    and stored after every segment, so a tile's sum is exact in segment
+    order across chunk boundaries."""
+    n = x.shape[0]
+    T = TILEDIA_TILE
+    if not tiledia_fits(n, planes.shape[-1], reach, x.dtype):
+        return tiledia_mv(planes, seg_off, seg_tile, reach, x)
+    ntiles, L, xlen, vmem = _tiledia_layout(n, reach)
+    S = planes.shape[0]
+    C = TILEDIA_CHUNK
+    spad = -(-S // C) * C
+    i32 = jnp.int32
+    first = jnp.concatenate([jnp.ones((1,), bool),
+                             seg_tile[1:] != seg_tile[:-1]])
+
+    def padded(v, fill):
+        return jnp.pad(v.astype(i32), (0, spad - S), constant_values=fill)
+
+    # padding segments: the dummy tile ntiles, each its own first, over
+    # a window inside x's padding
+    start = padded(L + seg_tile.astype(i32) * T + seg_off.astype(i32),
+                   L + ntiles * T)
+    trow = padded(seg_tile * 8, ntiles * 8)
+    first = padded(first, 1)
+    xp = jnp.pad(x.astype(jnp.float32), (L, xlen - L - n)).reshape(-1, LANE)
+    planes = planes.astype(jnp.float32).reshape(S, 8, LANE)
+
+    def kernel(start_ref, trow_ref, first_ref, x_ref, p_ref, y_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+        lane = jax.lax.broadcasted_iota(i32, (8, LANE), 1)
+
+        def window(j):
+            q = start_ref[j] >> 7
+            r = start_ref[j] & (LANE - 1)
+            sh = (LANE - r) & (LANE - 1)
+            return jnp.where(lane < LANE - r,
+                             pltpu.roll(x_ref[pl.ds(q, 8), :], sh, 1),
+                             pltpu.roll(x_ref[pl.ds(q + 1, 8), :], sh, 1))
+
+        def segments(u, acc):
+            js = [u * TILEDIA_UNROLL + j for j in range(TILEDIA_UNROLL)]
+            prods = [p_ref[j] * window(j) for j in js]
+            for j, prod in zip(js, prods):
+                acc = jnp.where(first_ref[j] != 0, 0.0, acc) + prod
+                y_ref[pl.ds(pl.multiple_of(trow_ref[j], 8), 8), :] = acc
+            return acc
+
+        acc0 = y_ref[pl.ds(pl.multiple_of(trow_ref[0], 8), 8), :]
+        jax.lax.fori_loop(0, C // TILEDIA_UNROLL, segments, acc0)
+
+    def smem():
+        return pl.BlockSpec((C,), lambda i: (i,), memory_space=pltpu.SMEM)
+
+    y = pl.pallas_call(
+        kernel,
+        grid=(spad // C,),
+        in_specs=[smem(), smem(), smem(),
+                  pl.BlockSpec(memory_space=pltpu.VMEM),
+                  pl.BlockSpec((C, 8, LANE), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct(((ntiles + 1) * 8, LANE),
+                                       jnp.float32),
+        # y and the running sum are carried from step to step
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem + 8 * 2 ** 20),
+        interpret=interpret,
+        name="acg_tiledia_spmv",
+    )(start, trow, first, xp, planes)
+    return y[: ntiles * 8].reshape(-1)[:n].astype(x.dtype)
 
 
 def fused_cg_route(offsets: tuple, n: int, dtype) -> tuple | None:

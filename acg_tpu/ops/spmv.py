@@ -21,6 +21,10 @@ Two formats are provided:
   ~1.2x off the DIA path on TPU by reading x through VMEM once instead of
   once per diagonal (solver flag ``kernels="pallas"``).
 
+The multi-part path adds row-tiled DIA for local blocks that are
+diagonal only within each tile of rows (:func:`tiledia_segments`,
+:func:`tiledia_mv`; graph partitions of stencils).
+
 Format choice is automatic in :func:`device_matrix_from_csr` from the
 sparsity structure (diagonal count, then row-length histogram), computed at
 load time (same decision the reference makes statically by choosing its
@@ -219,6 +223,62 @@ def dia_planes_fixed(csr, offsets, nrows_pad: int) -> np.ndarray:
     data = np.zeros((offsets.size, nrows_pad), dtype=np.float64)
     data[dmap, coo.row] = coo.data
     return data
+
+
+# rows per tile of a row-tiled DIA block: one (8, 128) f32 vreg, the
+# unit of the gather-free kernel (pallas_kernels.tiledia_spmv)
+TILEDIA_TILE = 1024
+
+
+def tiledia_segments(csr, tile: int):
+    """Host-side row-tiled DIA layout of a square CSR block: the rows are
+    cut into tiles of ``tile`` rows, and each tile keeps its own distinct
+    diagonal offsets (a *segment* per (tile, offset) pair).  Returns
+    ``(seg_tile, seg_off, seg_of_entry, rows)``: the segments sorted by
+    tile, then by offset (a row's column order), and for each stored
+    entry in CSR order its segment and row -- so the entry's value lands
+    at ``planes[seg_of_entry, rows % tile]``."""
+    n = int(csr.shape[0])
+    indptr = np.asarray(csr.indptr, dtype=np.int64)
+    rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(indptr))
+    off = np.asarray(csr.indices, dtype=np.int64) - rows
+    lo = int(off.min(initial=0))
+    span = int(off.max(initial=0)) - lo + 1
+    keys = (rows // tile) * span + (off - lo)
+    if -(-n // tile) * span <= 4 * keys.size:
+        # a narrow band: mark the (tile, offset) pairs in a dense table
+        # and number them in order, linear in the entries
+        used = np.zeros(-(-n // tile) * span, dtype=bool)
+        used[keys] = True
+        seg_of_entry = (np.cumsum(used) - 1)[keys]
+        keys = np.flatnonzero(used)
+    else:
+        keys, seg_of_entry = np.unique(keys, return_inverse=True)
+    return keys // span, keys % span + lo, seg_of_entry, rows
+
+
+def tiledia_mv(planes, seg_off, seg_tile, reach: tuple,
+               x: jax.Array) -> jax.Array:
+    """y = A @ x for a row-tiled DIA block (:func:`tiledia_segments`):
+    ``planes`` (S, T), segment ``s`` adds ``planes[s] * x[t*T + off :
+    t*T + off + T]`` to tile ``t = seg_tile[s]`` of y, ``off =
+    seg_off[s]``.  ``reach`` is the static (lowest, highest) offset;
+    padding segments name tile ``ceil(n / T)``, which is dropped.  Each
+    row sums its products in segment order, its column order, from
+    zero.  The XLA formulation: one element gather of the x windows;
+    :func:`acg_tpu.ops.pallas_kernels.tiledia_spmv` is the gather-free
+    kernel."""
+    n = x.shape[0]
+    T = planes.shape[-1]
+    ntiles = -(-n // T)
+    L = max(0, -reach[0])
+    xp = jnp.pad(x, (L, (ntiles + 1) * T - n + max(0, reach[1])))
+    idx = (L + seg_tile * T + seg_off)[:, None] + jnp.arange(T)
+    adt = acc_dtype(x.dtype)
+    prod = planes.astype(adt) * xp[idx].astype(adt)
+    y = jnp.zeros((ntiles + 1, T), adt).at[seg_tile].add(
+        prod, indices_are_sorted=True)
+    return y[:ntiles].reshape(-1)[:n].astype(x.dtype)
 
 
 def acc_dtype(dtype):

@@ -25,9 +25,10 @@ Data layout (host-built by :class:`DistributedProblem`):
   * the local block is stored as gather-free DIA planes whenever the
     partition keeps it banded (:class:`StackedLocalBlock`; owned rows are
     re-sorted into natural order for this -- ``graph.reorder_owned_
-    natural``), with ELL gather planes as the general fallback; the ghost
-    block is compressed to the coupled (border) rows only
-    (:class:`StackedGhostBlock`).
+    natural``), as row-tiled DIA planes where only each tile of rows is
+    (graph partitions of stencil and mesh matrices), with ELL gather
+    planes as the general fallback; the ghost block is compressed to the
+    coupled (border) rows only (:class:`StackedGhostBlock`).
 """
 
 from __future__ import annotations
@@ -47,8 +48,9 @@ from acg_tpu.errors import (AcgError, BreakdownError, ErrorCode,
                             NotConvergedError)
 from acg_tpu.graph import (Subdomain, partition_matrix, reorder_owned_natural,
                            scatter_vector)
-from acg_tpu.ops.spmv import (acc_dtype, csr_diag_offsets, dia_mv,
-                              dia_planes_fixed, ell_planes_from_csr)
+from acg_tpu.ops.spmv import (TILEDIA_TILE, acc_dtype, csr_diag_offsets,
+                              dia_mv, dia_planes_fixed, ell_planes_from_csr,
+                              tiledia_mv, tiledia_segments)
 from acg_tpu.parallel.halo import DeviceHaloPlan, build_device_halo, halo_exchange
 from acg_tpu.parallel.halo_dma import halo_exchange_dma
 from acg_tpu.parallel.mesh import PARTS_AXIS, shard_devices, solve_mesh
@@ -87,7 +89,13 @@ class StackedLocalBlock:
     offset; the union of all parts' offsets is stored so shapes are
     mesh-uniform).  Chosen when the partition keeps local blocks banded --
     contiguous parts of a banded matrix (``partition_rows_band``) with
-    owned rows in natural order.  ``"ell"``: row-padded gather planes
+    owned rows in natural order.  ``"tiledia"``: row-tiled DIA -- each
+    tile of ``tile`` rows keeps its own diagonal offsets, one plane per
+    (tile, offset) segment (:func:`acg_tpu.ops.spmv.tiledia_segments`);
+    chosen when DIA is refused and the planes store at most
+    ``TILEDIA_FILL_LIMIT`` times the local nonzeros: graph partitions of
+    stencil and mesh matrices, whose parts are diagonal within a grid row
+    but not across the part.  ``"ell"``: row-padded gather planes
     ``(data, cols)``, the general fallback (scattered partitions).
     ``"binnedell"``: the length-binned layout of
     :class:`acg_tpu.ops.spmv.BinnedEllMatrix` stacked per part
@@ -98,14 +106,18 @@ class StackedLocalBlock:
     goal, ``cg-kernels-cuda.cu:340-441``, round-4 verdict item 3).
     """
 
-    format: str      # "dia" | "ell" | "binnedell" | "matfree"
+    format: str      # "dia" | "tiledia" | "ell" | "binnedell" | "matfree"
     arrays: tuple    # dia: ndiags x (P, nrows); ell: (data (P,nrows,K), cols)
+    #                  tiledia: (planes (P,S,tile), seg_off (P,S),
+    #                            seg_tile (P,S))
     #                  binnedell: (bin_rows, bin_data, bin_cols tuples,
     #                              tail_rows, tail_cols, tail_vals)
     #                  matfree: (row0 (P,1), nowned (P,1), *tables (P,L))
-    offsets: tuple   # dia/matfree: static diagonal offsets, ascending
+    offsets: tuple   # dia/matfree: static diagonal offsets, ascending;
+    #                  tiledia: (lowest, highest) segment offset
     nrows: int
     bin_ks: tuple = ()   # binnedell only: static K_b per bin
+    tile: int = 0        # tiledia only: rows per tile
     # matfree only (acg_tpu.ops.operator / arm_matfree): the stencil
     # operator TEMPLATE -- static metadata (kind, grid, dtype) keying
     # the in-shard plane generation; its coefficient tables ride
@@ -138,6 +150,8 @@ class StackedLocalBlock:
                           self.nrows, x)
         if self.format == "dia":
             return dia_mv(arrays, self.offsets, self.nrows, x)
+        if self.format == "tiledia":
+            return tiledia_mv(*arrays, self.offsets, x)
         if self.format == "binnedell":
             bin_rows, bin_data, bin_cols, t_rows, t_cols, t_vals = arrays
             adt = acc_dtype(x.dtype)
@@ -374,6 +388,33 @@ def _stack_bell_blocks(blocks, nrows_pad: int, dtype,
         bin_ks=tuple(int(widths[b]) for b in live))
 
 
+# row-tiled DIA (StackedLocalBlock "tiledia"): the most plane elements
+# stored per local nonzero
+TILEDIA_FILL_LIMIT = 2.0
+
+
+def _stack_tiledia_blocks(blocks, segs, nmax_owned: int, dtype,
+                          tile: int) -> StackedLocalBlock:
+    """Stack per-part row-tiled DIA blocks (``segs[p]`` is
+    :func:`tiledia_segments` of ``blocks[p]``) with MESH-UNIFORM shapes:
+    every part holds the largest part's segment count, padding segments
+    naming the dummy tile ``ceil(nmax_owned / tile)`` with zero planes."""
+    P = len(blocks)
+    smax = max(st.size for st, _, _, _ in segs)
+    planes = np.zeros((P, smax, tile), dtype=np.dtype(dtype))
+    seg_off = np.zeros((P, smax), dtype=np.int32)
+    seg_tile = np.full((P, smax), -(-nmax_owned // tile), dtype=np.int32)
+    for p, (b, (st, so, entry_seg, rows)) in enumerate(zip(blocks, segs)):
+        planes[p][entry_seg, rows % tile] = b.data
+        seg_off[p, : so.size] = so
+        seg_tile[p, : st.size] = st
+    offs = np.concatenate([so for _, so, _, _ in segs])
+    return StackedLocalBlock(format="tiledia",
+                             arrays=(planes, seg_off, seg_tile),
+                             offsets=(int(offs.min()), int(offs.max())),
+                             nrows=nmax_owned, tile=tile)
+
+
 def _stack_local_blocks(subs, nmax_owned: int, dtype,
                         max_diags: int = 80,  # headroom over spmv.MAX_DIAGS:
                         # the union of per-part offset sets can exceed any
@@ -381,9 +422,18 @@ def _stack_local_blocks(subs, nmax_owned: int, dtype,
                         dia_waste_limit: float = 3.0,
                         ell_waste_limit: float = 3.0,
                         global_csr=None,
-                        uniform: UniformShapes | None = None
+                        uniform: UniformShapes | None = None,
+                        tile: int = TILEDIA_TILE,
+                        fill_limit: float = TILEDIA_FILL_LIMIT
                         ) -> StackedLocalBlock:
-    """Stacked arrays are HOST numpy (calloc-backed zeros, filled only
+    """The format follows from the blocks: DIA when the union of the
+    parts' diagonals is small, else row-tiled DIA (``tile`` rows a tile)
+    when its planes store at most ``fill_limit`` times the local
+    nonzeros, else ELL or binned ELL by the padding-waste rule.  The
+    local-read flow (``uniform``) and restricted builds (``global_csr``)
+    choose between DIA and ELL only.
+
+    Stacked arrays are HOST numpy (calloc-backed zeros, filled only
     for parts whose blocks exist): non-owned parts of a multi-controller
     build never touch their pages, so host RSS is O(owned/P); the device
     placement happens later through ``put_global``'s per-shard slicing
@@ -444,6 +494,12 @@ def _stack_local_blocks(subs, nmax_owned: int, dtype,
                                               for d in range(offs.size)),
                                  offsets=tuple(int(o) for o in offs),
                                  nrows=nmax_owned)
+    if uniform is None and global_csr is None and nnz:
+        segs = [tiledia_segments(b, tile) for b in blocks]
+        smax = max(st.size for st, _, _, _ in segs)
+        if len(blocks) * smax * tile <= fill_limit * nnz:
+            return _stack_tiledia_blocks(blocks, segs, nmax_owned, dtype,
+                                         tile)
     if (uniform is None and global_csr is None and nnz
             and Kl * nmax_owned * len(blocks) > ell_waste_limit * nnz):
         # the single-device auto histogram rule: plain-ELL padding waste
@@ -776,8 +832,9 @@ def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
     (``cgcuda.c:855-899``), scheduled by XLA instead of streams.
 
     ``kernels="pallas*"`` runs the hand-written single-x-pass DIA kernel
-    for the local block (the role of the reference's device SpMV inside
-    ``solvempi``, ``cgcuda.c:871``); non-DIA local blocks and the small
+    for a DIA local block, and the gather-free row-tiled DIA kernel for a
+    ``tiledia`` one (the role of the reference's device SpMV inside
+    ``solvempi``, ``cgcuda.c:871``); other local blocks and the small
     ghost block stay on the XLA path.
 
     Returns ``f(x_loc, la, ga, sidx, gsrc, gval, scnt, rcnt, k=None,
@@ -791,15 +848,19 @@ def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
     halo = prob.halo
     local_block = prob.local
     ghost_block = prob.ghost
-    use_pallas = kernels.startswith("pallas") and local_block.format == "dia"
+    use_pallas = (kernels.startswith("pallas")
+                  and local_block.format in ("dia", "tiledia"))
     pallas_interpret = kernels.endswith("interpret")
     if use_pallas:
-        from acg_tpu.ops.pallas_kernels import dia_spmv
+        from acg_tpu.ops.pallas_kernels import dia_spmv, tiledia_spmv
 
     def dist_spmv(x_loc, la, ga, sidx, gsrc, gval, scnt, rcnt,
                   k=None, pidx=None):
         with jax.named_scope("acg.spmv"):
-            if use_pallas:
+            if use_pallas and local_block.format == "tiledia":
+                y = tiledia_spmv(*la, x_loc, local_block.offsets,
+                                 interpret=pallas_interpret)
+            elif use_pallas:
                 y = dia_spmv(la, local_block.offsets, x_loc,
                              interpret=pallas_interpret)
             else:
@@ -878,8 +939,8 @@ def make_dist_spmv_overlapped(prob: "DistributedProblem", comm: str,
     interior_rows)`` -- the coupled-row list doubles as the border set,
     and :meth:`DistCGSolver.device_args` appends the interior list
     (:func:`interior_border_split`) when the fused tier is armed.
-    Supports the ``dia`` and ``ell`` stacked local formats (the two
-    with a per-row gather form); ``binnedell`` is refused at solver
+    Supports the ``dia``, ``tiledia`` and ``ell`` stacked local formats
+    (the ones with a per-row form); ``binnedell`` is refused at solver
     setup.  No fault hook: the fused tier refuses armed injectors at
     solve time (its base program carries no breakdown flag), so the
     signature keeps the ``k``/``pidx`` slots for call compatibility
@@ -887,9 +948,9 @@ def make_dist_spmv_overlapped(prob: "DistributedProblem", comm: str,
     halo = prob.halo
     local_block = prob.local
     ghost_block = prob.ghost
-    if local_block.format not in ("dia", "ell", "matfree"):
-        raise ValueError(f"overlapped SpMV needs DIA, ELL or matrix-"
-                         f"free local blocks (got "
+    if local_block.format not in ("dia", "tiledia", "ell", "matfree"):
+        raise ValueError(f"overlapped SpMV needs DIA, row-tiled DIA, ELL "
+                         f"or matrix-free local blocks (got "
                          f"{local_block.format!r})")
     nrows = local_block.nrows
     offs = local_block.offsets
@@ -900,10 +961,18 @@ def make_dist_spmv_overlapped(prob: "DistributedProblem", comm: str,
         drops).  Bit-identical per row to ``shard_mv``: the DIA form
         accumulates plane products in the same plane order over the
         same padded-x values (:func:`acg_tpu.ops.spmv.dia_mv`), the ELL
-        form is the same row-independent einsum reduction, and the
+        form is the same row-independent einsum reduction, the row-tiled
+        DIA form selects the rows of the whole block's product (a tile's
+        segments are shared by its rows, so the block is applied whole;
+        it reads no ghost, so it still overlaps the exchange; the kernel
+        where it fits, whose sums are the XLA formulation's), and the
         matrix-free form runs the DIA accumulation over GENERATED
         plane values (the interior/border split applied to the stencil
         apply -- the same split PR 13 gave the assembled SpMV)."""
+        if local_block.format == "tiledia":
+            from acg_tpu.ops.pallas_kernels import tiledia_spmv
+
+            return tiledia_spmv(*la, x, offs, interpret=interpret)[rows]
         adt = acc_dtype(x.dtype)
         if local_block.format in ("dia", "matfree"):
             planes = (local_block.gen_planes(la)
@@ -1110,13 +1179,15 @@ class DistCGSolver:
         self._sharding = NamedSharding(self.mesh, P(PARTS_AXIS))
         self._interpret = self.mesh.devices.flat[0].platform != "tpu"
         # kernel-tier resolution mirrors JaxCGSolver: pallas on TPU
-        # hardware for f32/bf16 DIA local blocks, interpret mode when
-        # explicitly requested off-TPU (tests), XLA otherwise
+        # hardware for f32/bf16 DIA and row-tiled DIA local blocks,
+        # interpret mode when explicitly requested off-TPU (tests), XLA
+        # otherwise
         itemsize = np.dtype(problem.dtype).itemsize
         if kernels == "auto":
             kernels = ("pallas" if not self._interpret
                        and itemsize in (2, 4)
-                       and problem.local.format == "dia" else "xla")
+                       and problem.local.format in ("dia", "tiledia")
+                       else "xla")
         elif kernels == "pallas" and self._interpret:
             kernels = "pallas-interpret"
         elif kernels.startswith("fused"):
@@ -1127,10 +1198,11 @@ class DistCGSolver:
             # gather form of the local block and the full-information
             # build (the split derives from every part's coupled-row
             # list)
-            if problem.local.format not in ("dia", "ell", "matfree"):
+            if problem.local.format not in ("dia", "tiledia", "ell",
+                                            "matfree"):
                 raise ValueError(
-                    "kernels='fused' needs DIA, ELL or matrix-free "
-                    f"local blocks (this problem stacked "
+                    "kernels='fused' needs DIA, row-tiled DIA, ELL or "
+                    f"matrix-free local blocks (this problem stacked "
                     f"{problem.local.format!r}, which has no per-row "
                     f"gather form); use kernels='auto'")
             if problem.owned_parts is not None:
@@ -2507,7 +2579,8 @@ class DistCGSolver:
                         < prob.nmax_owned).sum())
             mat_b = int(np.dtype(prob.dtype).itemsize)
             matfree = prob.local.format == "matfree"
-            idx_b = 0 if prob.local.format in ("dia", "matfree") else 4
+            idx_b = (0 if prob.local.format in ("dia", "tiledia", "matfree")
+                     else 4)
             nnz_int = 0
             for p, s in enumerate(prob.subs):
                 if s.A_local is None:
@@ -2991,7 +3064,8 @@ class DistCGSolver:
         # matrix bytes in the matrix dtype (differs from vectors under
         # mixed); DIA local blocks read no index arrays, ELL reads 4 B
         mat_dbl = np.dtype(prob.dtype).itemsize
-        idx_b = 0 if prob.local.format in ("dia", "matfree") else 4
+        idx_b = (0 if prob.local.format in ("dia", "tiledia", "matfree")
+                 else 4)
         # matrix-free local blocks read no planes at all -- the gemv
         # row's bytes are the generated-operand vector traffic plus
         # the O(grid-side) coefficient tables

@@ -46,9 +46,11 @@ from acg_tpu.solvers.stats import (SolverStats, StoppingCriteria,
 __all__ = ["BatchedDistCGSolver"]
 
 
-def _local_mv_multi(block, arrays, X):
+def _local_mv_multi(block, arrays, X, interpret: bool = True):
     """``Y = A_local @ X`` for one shard's local block, multi-column
-    ``X`` (nrows, B) -- one pass over the block for all columns."""
+    ``X`` (nrows, B) -- one pass over the block for all columns (a
+    row-tiled DIA block: one kernel call a column, compiled unless
+    ``interpret``)."""
     adt = acc_dtype(X.dtype)
     if block.format == "dia":
         # dia_mv generalises column-wise via a vmap over the batch
@@ -62,6 +64,12 @@ def _local_mv_multi(block, arrays, X):
             sl = lax.dynamic_slice_in_dim(Xp, L + off, block.nrows, 0)
             Y = Y + plane[:, None].astype(adt) * sl.astype(adt)
         return Y.astype(X.dtype)
+    if block.format == "tiledia":
+        from acg_tpu.ops.pallas_kernels import tiledia_spmv
+
+        return jnp.stack([tiledia_spmv(*arrays, X[:, c], block.offsets,
+                                       interpret=interpret)
+                          for c in range(X.shape[1])], axis=1)
     if block.format == "binnedell":
         bin_rows, bin_data, bin_cols, t_rows, t_cols, t_vals = arrays
         Y = jnp.zeros((block.nrows, X.shape[1]), dtype=adt)
@@ -193,6 +201,7 @@ class BatchedDistCGSolver:
         local_block = prob.local
         ghost_block = prob.ghost
         single_shard = self.mesh.devices.size == 1
+        interpret = self.mesh.devices.flat[0].platform != "tpu"
         if trace:
             from acg_tpu import telemetry
 
@@ -220,7 +229,7 @@ class BatchedDistCGSolver:
             res_atol, res_rtol = atols, rtol
 
             def spmv(X):
-                y = _local_mv_multi(local_block, la, X)
+                y = _local_mv_multi(local_block, la, X, interpret)
                 if halo.has_ghosts:
                     ghost = _halo_exchange_multi(X, sidx, gsrc, axis)
                     y = y + _ghost_mv_multi(ghost_block, ga, ghost)
@@ -623,7 +632,7 @@ class BatchedDistCGSolver:
                       + 3.0 * prob.nnz_total + 2.0 * n) * nrhs
         dbl = dtype.itemsize
         mat_dbl = np.dtype(prob.dtype).itemsize
-        idx_b = 0 if prob.local.format == "dia" else 4
+        idx_b = 0 if prob.local.format in ("dia", "tiledia") else 4
         st.ops["gemv"].add(k_total + 1, 0.0,
                            (prob.nnz_total * (mat_dbl + idx_b)
                             + 2 * n * dbl * nrhs) * (k_total + 1))

@@ -553,7 +553,8 @@ def _explain_tier(name, solver, b, csr, K, bw_gbs, dispatch_s, on_tpu,
         nnz, n = int(prob.nnz_total), int(prob.n)
         mat_b = int(np.dtype(prob.dtype).itemsize)
         vec_b = int(np.dtype(prob.vdtype).itemsize)
-        idx_b = 0.0 if prob.local.format in ("dia", "matfree") else 4.0
+        idx_b = (0.0 if prob.local.format in ("dia", "tiledia", "matfree")
+                 else 4.0)
         if getattr(prob, "operator", None) is not None:
             matfree_tables = int(prob.operator.table_bytes())
     else:

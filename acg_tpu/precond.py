@@ -468,6 +468,13 @@ def _np_local_block_triples(local, p: int):
             vs.append(np.asarray(plane[p], np.float64)[ok])
         return (np.concatenate(rs), np.concatenate(cs),
                 np.concatenate(vs))
+    if local.format == "tiledia":
+        planes, seg_off, seg_tile = (np.asarray(a[p]) for a in local.arrays)
+        rows = (seg_tile.astype(np.int64)[:, None] * local.tile
+                + np.arange(local.tile))
+        cols = rows + seg_off.astype(np.int64)[:, None]
+        ok = planes != 0
+        return rows[ok], cols[ok], planes[ok].astype(np.float64)
     if local.format == "ell":
         data, cols = local.arrays
         n, K = data.shape[1], data.shape[2]

@@ -106,6 +106,24 @@ def test_cg_phase_a_compiles(topo, tpu_compile):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_tiledia_spmv_compiles(topo, tpu_compile, dtype):
+    """The row-tiled DIA kernel on the largest local block of the 2D
+    n=2048 file graph-partitioned into four: 1,048,577 rows, 9,209
+    segments of 1,024 rows, offsets within +-2,046."""
+    n, S, reach = 1048577, 9209, (-2046, 2046)
+    assert pk.tiledia_fits(n, pk.TILEDIA_TILE, reach, dtype)
+    one = SingleDeviceSharding(topo.devices[0])
+    text = tpu_compile(
+        lambda planes, off, tile, x: pk.tiledia_spmv(planes, off, tile, x,
+                                                     reach),
+        jax.ShapeDtypeStruct((S, pk.TILEDIA_TILE), dtype, sharding=one),
+        jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((n,), dtype, sharding=one))
+    assert "tpu_custom_call" in text
+
+
 def test_fused_pipelined_update_compiles(topo, tpu_compile):
     N = 2048 * 2048
     one = SingleDeviceSharding(topo.devices[0])
