@@ -845,8 +845,8 @@ def _buildinfo(out) -> int:
          "2/iter -> 1/S-block; Chebyshev basis at S>=4) | pipelined:L "
          "(p(l)-CG: ONE fused allreduce/iter consumed L iterations "
          "later; restarted on sqrt breakdown); single-device, sharded "
-         "gen-direct and dist tiers; builder classic/pipelined "
-         "emission pinned byte-identical (acg_tpu.recurrence)"),
+         "gen-direct and dist tiers; classic/pipelined share the "
+         "same builder (acg_tpu.recurrence)"),
         ("persistent fused iteration", "--kernels fused on the mesh "
          "(--nparts): interior/border OVERLAPPED SpMV -- one-sided "
          "halo DMA (--comm dma) or all_to_all issued first, interior "

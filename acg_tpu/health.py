@@ -273,13 +273,13 @@ def abft_default_threshold(sdt, n: int) -> float:
     return 64.0 * math.sqrt(max(float(n), 1.0)) * eps
 
 
-def abft_update(aud, spec: HealthSpec, k, y, x, cvec, dot3, sdt,
+def abft_update(aud, spec: HealthSpec, k, y, x, cvec, dotk, sdt,
                 n: int):
     """The in-loop Huang-Abraham checksum verification of ``y = A x``:
     at the audit cadence, compare ``sum(y)`` against ``(c, x)`` where
     ``c = A^T 1`` (precomputed through the tier's own SpMV; equal to
-    ``A 1`` for the symmetric systems this suite solves).  ``dot3`` is
-    the tier's FUSED 3-dot closure (one psum of 3 scalars on the mesh
+    ``A 1`` for the symmetric systems this suite solves).  ``dotk`` is
+    the tier's FUSED k-dot over pairs (one psum of 3 scalars on the mesh
     tiers, so the armed delta is exactly +1 all_reduce and ZERO extra
     SpMVs/halo exchanges -- the checksum test is what makes SDC
     detection affordable every few iterations).
@@ -301,7 +301,7 @@ def abft_update(aud, spec: HealthSpec, k, y, x, cvec, dot3, sdt,
     def do(a):
         ys = y.astype(sdt)
         xs = x.astype(sdt)
-        st, cp, tt = dot3(ys, jnp.ones_like(ys), cvec, xs, ys, ys)
+        st, cp, tt = dotk((ys, jnp.ones_like(ys)), (cvec, xs), (ys, ys))
         denom = (jnp.sqrt(jnp.maximum(tt, 0) * jnp.asarray(n, sdt))
                  + jnp.abs(st) + jnp.abs(cp)
                  + jnp.asarray(jnp.finfo(sdt).tiny, sdt))

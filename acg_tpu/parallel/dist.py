@@ -56,7 +56,6 @@ from acg_tpu.parallel.halo_dma import halo_exchange_dma
 from acg_tpu.parallel.mesh import PARTS_AXIS, shard_devices, solve_mesh
 from acg_tpu.parallel.reductions import make_pdot, make_pdotk
 from acg_tpu.parallel.multihost import get_global, put_global
-from acg_tpu.solvers.jax_cg import _breakdown_guard, _iterate
 from acg_tpu.solvers.stats import (SolverStats, StoppingCriteria,
                                    cg_flops_per_iteration)
 
@@ -1092,6 +1091,96 @@ def arm_matfree(prob: "DistributedProblem", op) -> "DistributedProblem":
     return prob
 
 
+def _replaced_segments(spmv, pdot, b, x0, tols, maxits, unbounded: bool,
+                       sdt, replace_every: int, replace_restart: bool):
+    """The sound-bf16 contract, distributed: inner bf16 CG segments over
+    the mesh with a per-segment f32 true-residual replacement
+    (mixed-precision dist SpMV -- bf16 blocks x f32 vector).  Mirrors
+    jax_cg._cg_replaced_program; b/x0 arrive in f32 (solve scatters them
+    wide), and every psum'd scalar is f32, so the convergence test per
+    segment is grounded in the true residual on every shard.  Returns
+    the shard body's output tuple."""
+    res_atol, res_rtol = tols[0], tols[1]
+    with jax.named_scope("acg.setup"):
+        bnrm2 = jnp.sqrt(pdot(b, b))
+        x0nrm2 = jnp.sqrt(pdot(x0, x0))
+        r = b - spmv(x0)
+        gamma = pdot(r, r)
+        r0nrm2 = jnp.sqrt(gamma)
+        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
+    inf = jnp.asarray(jnp.inf, sdt)
+    vdt = jnp.bfloat16
+
+    def segment(x32, r32, p, its):
+        r16 = r32.astype(vdt)
+        seg_gamma = pdot(r16, r16)
+        if replace_restart:
+            p = r16
+        else:
+            pn = pdot(p, p)
+            bad = ((~jnp.isfinite(pn))
+                   | (pn > jnp.asarray(1e24, sdt) * seg_gamma))
+            p = jnp.where(bad, r16, p)
+        nin = jnp.minimum(jnp.int32(replace_every), maxits - its)
+
+        def ibody(j, st):
+            d, rr, pp, g = st
+            live = j < nin
+            t = spmv(pp)
+            pdott = pdot(pp, t)
+            num = g if replace_restart else pdot(rr, pp)
+            alpha = jnp.where(live & (pdott > 0), num / pdott,
+                              jnp.zeros_like(g))
+            d = (d.astype(sdt)
+                 + alpha * pp.astype(sdt)).astype(vdt)
+            r_new = (rr.astype(sdt)
+                     - alpha * t.astype(sdt)).astype(vdt)
+            g_next = pdot(r_new, r_new)
+            beta = jnp.where(g > 0, g_next / g,
+                             jnp.zeros_like(g))
+            pp = jnp.where(live,
+                           (r_new.astype(sdt)
+                            + beta * pp.astype(sdt)).astype(vdt),
+                           pp)
+            return (d, r_new, pp, g_next)
+
+        d, _, p, _ = jax.lax.fori_loop(
+            0, replace_every, ibody,
+            (jnp.zeros_like(r16), r16, p, seg_gamma))
+        x32 = x32 + d.astype(sdt)
+        r32 = b - spmv(x32)
+        return x32, r32, p, its + nin, pdot(r32, r32)
+
+    p0 = r.astype(vdt)
+    if unbounded:
+        nouter = ((maxits + jnp.int32(replace_every) - 1)
+                  // jnp.int32(replace_every))
+
+        def obody(_, carry):
+            x32, r32, p, its, _ = carry
+            return segment(x32, r32, p, its)
+
+        x32, _, _, k, gamma_f = jax.lax.fori_loop(
+            0, nouter, obody,
+            (x0, r, p0, jnp.int32(0), gamma))
+        done = jnp.isfinite(gamma_f)
+    else:
+        def wcond(c):
+            # NaN >= x is False: a non-finite recomputed residual exits
+            # here -- the segment boundary doubles as the breakdown
+            # detector for free
+            return (c[4] >= res_tol * res_tol) & (c[3] < maxits)
+
+        def wbody(c):
+            return segment(*c[:4])
+
+        x32, _, _, k, gamma_f = jax.lax.while_loop(
+            wcond, wbody, (x0, r, p0, jnp.int32(0), gamma))
+        done = gamma_f < res_tol * res_tol
+    return (x32[None], k, jnp.sqrt(gamma_f), r0nrm2, bnrm2,
+            x0nrm2, inf, done, ~jnp.isfinite(gamma_f))
+
+
 class DistCGSolver:
     """Whole-solve SPMD CG program over a 1-D mesh of ``nparts`` devices.
 
@@ -1164,10 +1253,9 @@ class DistCGSolver:
         self.precise_dots = precise_dots
         self.comm = comm
         # recurrence selection (acg_tpu.recurrence): classic/pipelined
-        # stay on the hand-built shard_body (builder emission pinned
-        # byte-identical in tests/test_hlo_structure.py); sstep:S /
-        # pipelined:L compose the builder recurrences with this tier's
-        # halo'd SpMV + fused psum machinery (_compile_ca)
+        # run in _compile, sstep:S / pipelined:L in _compile_ca -- both
+        # compose the builder's recurrences with this tier's halo'd
+        # SpMV + fused psum machinery
         from acg_tpu.recurrence import parse_algorithm
         self.algo = parse_algorithm(algorithm)
         if self.algo is not None and not self.algo.communication_avoiding:
@@ -1336,34 +1424,26 @@ class DistCGSolver:
                 "the direct classic/pipelined programs")
         self.last_trace = None
         if self.kernels == "fused":
-            # the fused tier dispatches the BUILDER base program
-            # (recurrence.build_dist_program over the overlapped SpMV):
-            # every cross-cutting feature it does not thread refuses
-            # here rather than silently dropping (the could-never-fire
-            # discipline, mirroring the single-device fused tier)
+            # the fused tier runs _compile's loop over the overlapped
+            # SpMV, proven there with every hook disarmed only
+            # (tests/test_fused_dist.py): an armed feature refuses here
+            # rather than run unproven.  The overlapped SpMV has no
+            # fault site, so recovery could not be fault-tested either
             for on, what in (
                     (self.replace_every,
                      "replace_every (the replacement segments "
                      "restructure the loop)"),
-                    (self.precise_dots,
-                     "precise_dots (the fused tier accumulates its "
-                     "dots in the plain scalar dtype)"),
-                    (self.precond_spec is not None,
-                     "precond (no preconditioner hook in the fused "
-                     "base program)"),
-                    (self.health_spec is not None,
-                     "the health audit (no audit hook in the fused "
-                     "base program)"),
-                    (self.ckpt is not None,
-                     "checkpointing (the fused base program exposes "
-                     "no loop carry)"),
+                    (self.precise_dots, "precise_dots"),
+                    (self.precond_spec is not None, "precond"),
+                    (self.health_spec is not None, "the health audit"),
+                    (self.ckpt is not None, "checkpointing"),
                     (self.algo is not None,
                      f"--algorithm {self.algo} (the CA recurrences "
                      f"keep the unsplit SpMV; fused covers "
                      f"classic/pipelined)"),
                     (self.recovery is not None,
-                     "recovery (the fused base program carries no "
-                     "breakdown flag, so a policy could never fire)"),
+                     "recovery (the overlapped SpMV has no fault "
+                     "site)"),
                     (bool(self.trace or self.progress),
                      "convergence telemetry (trace/progress)")):
                 if on:
@@ -1386,7 +1466,13 @@ class DistCGSolver:
     # -- program construction ---------------------------------------------
 
     def _compile(self, fault=None, state_io: bool = False):
-        """Build the whole-solve program.  ``fault`` (a static
+        """Build the whole-solve program: the mesh frame around the
+        builder's classic/pipelined loop (acg_tpu.recurrence.run_cg) --
+        this tier contributes its halo'd SpMV (the interior|border
+        OVERLAPPED one under ``kernels='fused'``), its psum'd dot and
+        fused k-dot, the preconditioner apply through that SpMV, the
+        part index a fault targets, and the part that speaks for the
+        mesh in the heartbeat.  ``fault`` (a static
         acg_tpu.faults.FaultSpec) bakes the injector into the loop --
         the armed program is a solve-local temporary, never cached on
         ``self``, so clean solves keep the pristine compilation.
@@ -1404,46 +1490,37 @@ class DistCGSolver:
             # (recurrence.run_sstep_loop / run_pl_loop) composed with
             # this tier's machinery
             return self._compile_ca(fault=fault)
-        if self.kernels == "fused":
-            # the distributed fused-iteration tier: the recurrence
-            # builder's base emission (classic_recurrence /
-            # pipelined_recurrence over TierOps) composed with the
-            # interior|border OVERLAPPED SpMV -- no hand-built loop
-            # (the PR 12 one-recurrence-per-feature discipline).
-            # Faults/state_io never reach here: both are refused at
-            # setup/solve for this tier
-            from acg_tpu.recurrence import build_dist_program
-            return build_dist_program(self)
+        from acg_tpu.recurrence import TierOps, run_cg
         prob = self.problem
         pipelined = self.pipelined
         replace_every = self.replace_every
         replace_restart = self.replace_restart
         axis = PARTS_AXIS
-
-        comm = self.comm
-        interpret = self._interpret
         precise = self.precise_dots
-        trace = self.trace
-        progress = self.progress
         precond_spec = self.precond_spec
-        health = self.health_spec
-        if trace or progress:
-            from acg_tpu import telemetry
+        hooks = dict(pipelined=pipelined, fault=fault, trace=self.trace,
+                     progress=self.progress, health=self.health_spec,
+                     state_io=state_io)
         if precond_spec is not None:
             from acg_tpu.precond import make_apply
-        if health is not None:
-            from acg_tpu import health as _health
 
-        dist_spmv = make_dist_spmv(prob, comm, interpret,
-                                   kernels=self.kernels, fault=fault)
+        if self.kernels == "fused":
+            # the distributed fused-iteration tier: the halo exchange is
+            # issued first and the interior rows computed while it is in
+            # flight (faults are refused at setup/solve for this tier)
+            dist_spmv = make_dist_spmv_overlapped(prob, self.comm,
+                                                  self._interpret)
+        else:
+            dist_spmv = make_dist_spmv(prob, self.comm, self._interpret,
+                                       kernels=self.kernels, fault=fault)
 
         # commsize==1 parity (the reference's explicit special case,
         # ``cgcuda.c:403``): on a 1-shard mesh every psum is an identity
-        # -- but XLA does NOT elide a 1-device all-reduce, and on this
-        # runtime each one costs a fixed per-op launch overhead INSIDE
-        # the iteration loop (measured round 5: 2 all-reduces/iteration
-        # made the nparts=1 program 27x slower than the single-chip
-        # solver, the `cg_dist1` ladder row's collapse).  The whole
+        # -- but XLA does NOT elide a 1-device all-reduce, and each one
+        # costs a fixed per-op launch overhead INSIDE the iteration loop
+        # (on the CPU mesh of the project's early rounds, before the
+        # chip ledger existed, 2 all-reduces/iteration made the nparts=1
+        # program 27x slower than the single-chip solver).  The whole
         # shard_map wrapper is bypassed below for the same reason.
         single_shard = self.mesh.devices.size == 1
 
@@ -1456,9 +1533,6 @@ class DistCGSolver:
         # shard_map specs, and the chunk driver's snapshot writer
         from acg_tpu.checkpoint import SCALAR_LEAVES, carry_names
         c_names = carry_names(pipelined, precond_spec is not None)[1:]
-        # the GLOBAL unknown count (the ABFT mismatch scale; local
-        # shapes would understate the rounding headroom)
-        nglobal = int(prob.n)
 
         def shard_body(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0,
                        tols, maxits, mstate=None, unbounded=False,
@@ -1482,7 +1556,7 @@ class DistCGSolver:
             sdt = acc_dtype(dtype)
             store = ((lambda v: v.astype(dtype)) if sdt != dtype
                      else (lambda v: v))
-            res_atol, res_rtol, diff_atol, diff_rtol = tols
+            tols = tuple(tols)
             # the part index a vector fault targets; only derivable from
             # the mesh axis inside shard_map (the plain-jit bypass below
             # is single-part by construction)
@@ -1502,575 +1576,49 @@ class DistCGSolver:
             # psum carries k scalars -- compensated mode psums hi/lo
             # pairs so local summation error stays out of the global
             # scalar, and the pipelined/PCG single-allreduce property
-            # (cgcuda.c:1730-1737) is the k=2/k=3 member.  The builders
-            # emit exactly the op sequence the hand-written ladders
-            # traced, so these programs lower byte-identically to the
-            # pre-refactor ones (pinned in tests/test_hlo_structure.py)
+            # (cgcuda.c:1730-1737) is the k=2/k=3 member
             pdot = make_pdot(psum, ldot, sdt, precise)
-            _pdotk = make_pdotk(psum, ldot, sdt, precise)
+            if replace_every:
+                return _replaced_segments(
+                    spmv, pdot, b, x0, tols, maxits, unbounded, sdt,
+                    replace_every, replace_restart)
+            papply = None
+            if precond_spec is not None:
+                # the tier's halo'd SpMV closure: the cheby apply's
+                # communication is exactly K extra SpMVs
+                apply = make_apply(precond_spec, lambda _A, x: spmv(x))
 
-            def pdot2_fused(a1, c1, a2, c2):
-                return _pdotk((a1, c1), (a2, c2))
-
-            def pdot3_fused(a1, c1, a2, c2, a3, c3):
-                return _pdotk((a1, c1), (a2, c2), (a3, c3))
-
-            # r0 = b - A x0 and the pre-loop dots (jax_cg._cg_program's
-            # device-scope vocabulary; the loop bodies name theirs)
-            with jax.named_scope("acg.setup"):
-                bnrm2 = jnp.sqrt(pdot(b, b))
-                x0nrm2 = jnp.sqrt(pdot(x0, x0))
-                if precond_spec is not None:
-                    # papply reuses the tier's halo'd SpMV closure: the
-                    # cheby apply's communication is exactly K extra SpMVs
-                    _papply = make_apply(precond_spec, lambda _A, x: spmv(x))
-
-                    def papply(vec, k=None):
-                        z = _papply(mstate, None, vec)
-                        if fault is not None and k is not None:
-                            z = fault.apply_precond(z, k, pidx)
-                        return z
-
-                if carry is not None:
-                    # resume (the survivability tier): the provided carry IS
-                    # the loop state -- nothing is recomputed, the Krylov
-                    # recurrence continues exactly where the snapshot left
-                    # it (x0 holds the snapshot iterate).  The setup SpMV
-                    # and its collectives are skipped on every shard alike
-                    # (carry is a static python branch, mesh-uniform)
-                    r = carry[0]
-                    if precond_spec is not None:
-                        r0nrm2 = jnp.sqrt(carry[-1])
-                    elif pipelined:
-                        r0nrm2 = jnp.sqrt(jnp.maximum(carry[-2], 0))
-                    else:
-                        r0nrm2 = jnp.sqrt(carry[-1])
-                elif precond_spec is not None:
-                    r = b - spmv(x0)
-                    u0 = store(papply(r))
-                    gamma0, rr0 = pdot2_fused(r, u0, r, r)
-                    gamma = rr0
-                    r0nrm2 = jnp.sqrt(rr0)
-                else:
-                    r = b - spmv(x0)
-                    gamma = pdot(r, r)
-                    r0nrm2 = jnp.sqrt(gamma)
-                res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-                diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
-            inf = jnp.asarray(jnp.inf, sdt)
-            if health is not None and health.abft:
-                # the column checksum c = A^T 1 (= A 1: symmetric
-                # systems) through the tier's own halo'd SpMV -- one
-                # extra exchange per solve.  The in-loop test rides the
-                # FUSED 3-dot psum (pdot3_fused), so the armed delta is
-                # exactly +1 all_reduce per audit and ZERO extra SpMVs
-                cvec = spmv(jnp.ones_like(b)).astype(sdt)
-
-            # Loop structure and convergence logic shared with the
-            # single-device solver (jax_cg._iterate / _converged): gamma is
-            # psum'd, so `done` is identical on every shard and the while
-            # predicates agree across the mesh.
-            def run_iter(iter_body, init_state, gamma_of, dx_of,
-                         init_gamma=None, bad_of=None):
-                return _iterate(iter_body, init_state, gamma_of, maxits,
-                                res_tol, diff_tol, dx_of, unbounded,
-                                init_gamma=init_gamma, bad_of=bad_of)
-
-            if replace_every and not pipelined:
-                # the sound-bf16 contract, distributed: inner bf16 CG
-                # segments over the mesh with a per-segment f32
-                # true-residual replacement (mixed-precision dist SpMV
-                # -- bf16 blocks x f32 vector).  Mirrors
-                # jax_cg._cg_replaced_program; b/x0 arrive in f32
-                # (solve scatters them wide), and every psum'd scalar
-                # is f32, so the convergence test per segment is
-                # grounded in the true residual on every shard.
-                vdt = jnp.bfloat16
-
-                def segment(x32, r32, p, its):
-                    r16 = r32.astype(vdt)
-                    seg_gamma = pdot(r16, r16)
-                    if replace_restart:
-                        p = r16
-                    else:
-                        pn = pdot(p, p)
-                        bad = ((~jnp.isfinite(pn))
-                               | (pn > jnp.asarray(1e24, sdt) * seg_gamma))
-                        p = jnp.where(bad, r16, p)
-                    nin = jnp.minimum(jnp.int32(replace_every), maxits - its)
-
-                    def ibody(j, st):
-                        d, rr, pp, g = st
-                        live = j < nin
-                        t = spmv(pp)
-                        pdott = pdot(pp, t)
-                        num = g if replace_restart else pdot(rr, pp)
-                        alpha = jnp.where(live & (pdott > 0), num / pdott,
-                                          jnp.zeros_like(g))
-                        d = (d.astype(sdt)
-                             + alpha * pp.astype(sdt)).astype(vdt)
-                        r_new = (rr.astype(sdt)
-                                 - alpha * t.astype(sdt)).astype(vdt)
-                        g_next = pdot(r_new, r_new)
-                        beta = jnp.where(g > 0, g_next / g,
-                                         jnp.zeros_like(g))
-                        pp = jnp.where(live,
-                                       (r_new.astype(sdt)
-                                        + beta * pp.astype(sdt)).astype(vdt),
-                                       pp)
-                        return (d, r_new, pp, g_next)
-
-                    d, _, p, _ = jax.lax.fori_loop(
-                        0, replace_every, ibody,
-                        (jnp.zeros_like(r16), r16, p, seg_gamma))
-                    x32 = x32 + d.astype(sdt)
-                    r32 = b - spmv(x32)
-                    return x32, r32, p, its + nin, pdot(r32, r32)
-
-                p0 = r.astype(vdt)
-                if unbounded:
-                    nouter = ((maxits + jnp.int32(replace_every) - 1)
-                              // jnp.int32(replace_every))
-
-                    def obody(_, carry):
-                        x32, r32, p, its, _ = carry
-                        return segment(x32, r32, p, its)
-
-                    x32, _, _, k, gamma_f = jax.lax.fori_loop(
-                        0, nouter, obody,
-                        (x0, r, p0, jnp.int32(0), gamma))
-                    done = jnp.isfinite(gamma_f)
-                else:
-                    def wcond(c):
-                        # NaN >= x is False: a non-finite recomputed
-                        # residual exits here -- the segment boundary
-                        # doubles as the breakdown detector for free
-                        return (c[4] >= res_tol * res_tol) & (c[3] < maxits)
-
-                    def wbody(c):
-                        return segment(*c[:4])
-
-                    x32, _, _, k, gamma_f = jax.lax.while_loop(
-                        wcond, wbody, (x0, r, p0, jnp.int32(0), gamma))
-                    done = gamma_f < res_tol * res_tol
-                return (x32[None], k, jnp.sqrt(gamma_f), r0nrm2, bnrm2,
-                        x0nrm2, inf, done, ~jnp.isfinite(gamma_f))
+                def papply(v):
+                    return apply(mstate, None, v)
 
             # heartbeat emits from part 0 only: every recorded scalar is
             # psum'd (mesh-uniform), so one part speaks for the mesh
             leader = None
-            if progress and not single_shard:
+            if self.progress and not single_shard:
                 leader = lax.axis_index(axis) == jnp.int32(0)
-
-            if not pipelined:
-                # carry layout mirrors jax_cg._cg_program: rr (the true
-                # residual the convergence test reads) joins only under
-                # precond, dx only under a diff criterion
-                dx_i = 5 if precond_spec is not None else 4
-
-                # dxsqr joins the carry only under a diff criterion (extra
-                # loop-carried scalars measurably slow the TPU loop)
-                def body(k, state):
-                    if trace:
-                        buf, state = state[-1], state[:-1]
-                    if health is not None:
-                        aud, state = state[-1], state[:-1]
-                    x, r, p, gamma = state[:4]
-                    t = spmv(p, k)
-                    with jax.named_scope("acg.dot"):
-                        pdott = pdot(p, t)
-                    if fault is not None:
-                        pdott = fault.apply_dot(pdott, k)
-                    with jax.named_scope("acg.update"):
-                        if detect:
-                            # breakdown detection mirrors jax_cg.
-                            # _cg_program (shared predicate; the deferred
-                            # gamma_next term below too): every flagged
-                            # scalar is psum'd, so `bad` is identical on
-                            # all shards and the early exit is
-                            # mesh-uniform
-                            bad, alpha = _breakdown_guard(gamma, pdott)
-                            x = store(jnp.where(bad, x, x + alpha * p))
-                            r = store(jnp.where(bad, r, r - alpha * t))
-                        else:
-                            alpha = gamma / pdott
-                            x = store(x + alpha * p)
-                            r = store(r - alpha * t)
-                    if precond_spec is not None:
-                        z = papply(r, k)
-                        # ONE fused psum for both scalars: the classic
-                        # PCG loop keeps 2 allreduces per iteration
-                        with jax.named_scope("acg.dot"):
-                            gamma_next, rr_next = pdot2_fused(r, z, r, r)
-                        with jax.named_scope("acg.update"):
-                            beta = gamma_next / gamma
-                            p_next = store(z + beta * p)
-                        out = (x, r, p_next, gamma_next, rr_next)
-                    else:
-                        with jax.named_scope("acg.dot"):
-                            gamma_next = pdot(r, r)
-                        with jax.named_scope("acg.update"):
-                            beta = gamma_next / gamma
-                            p_next = store(r + beta * p)
-                        out = (x, r, p_next, gamma_next)
-                    if needs_diff:
-                        with jax.named_scope("acg.dot"):
-                            dx = alpha * alpha * psum(ldot(p, p))
-                        if detect:
-                            # freeze dx on breakdown (jax_cg rationale):
-                            # alpha = 0 must not fake the diff criterion
-                            dx = jnp.where(bad, state[dx_i], dx)
-                        out = out + (dx,)
-                    fire = None
-                    if health is not None:
-                        # cadence phased to TRAJECTORY iterations: the
-                        # checkpoint chunk driver passes the chunk's
-                        # starting iteration (mesh-uniform, like k)
-                        kk = k if k_offset is None else k + k_offset
-
-                        # in-loop audit through the SAME halo'd SpMV:
-                        # the cond predicate (k) is mesh-uniform, so
-                        # the conditional collectives fire on every
-                        # shard together; the psum'd gap replicates
-                        def compute_gap():
-                            return _health.relative_gap(b - spmv(x), r,
-                                                                                pdot, bnrm2, sdt)
-
-                        aud, fire = _health.audit_update(
-                            aud, health, kk, compute_gap)
-                        prog_now = (out[4] if precond_spec is not None
-                                    else gamma_next)
-                        prog_prev = (state[4] if precond_spec is not None
-                                     else gamma)
-                        aud = _health.stall_update(aud, health,
-                                                   prog_now < prog_prev)
-                        if health.abft:
-                            # Huang-Abraham checksum test of this
-                            # iteration's t = A p: sum(t) vs (c, p),
-                            # all three scalars in ONE fused psum
-                            aud = _health.abft_update(
-                                aud, health, kk, t, p, cvec,
-                                pdot3_fused, sdt, nglobal)
-                    if detect:
-                        deferred = bad | (~jnp.isfinite(gamma_next))
-                        if precond_spec is not None:
-                            # negative (r, z): the non-SPD-M signal
-                            deferred = deferred | (gamma_next < 0)
-                        if health is not None:
-                            if precond_spec is None:
-                                # sign anomaly (jax_cg rationale)
-                                deferred = deferred | (gamma_next < 0)
-                            deferred = deferred | _health.trip(aud,
-                                                               health)
-                        out = out + (deferred,)
-                    if health is not None:
-                        out = out + (aud,)
-                    if trace:
-                        # psum'd scalars: the ring is replicated, one
-                        # rank-independent fetch per solve (gamma IS the
-                        # preconditioned residual norm^2 under precond)
-                        audit_col = (_health.ring_gap(aud, fire, sdt)
-                                     if health is not None else None)
-                        out = out + (telemetry.ring_record(
-                            buf, k, gamma_next, alpha, beta, pdott,
-                            audit=audit_col),)
-                    if progress:
-                        telemetry.heartbeat(k, gamma_next, progress,
-                                            leader=leader, what="dist-cg")
-                    return out
-
-                if carry is not None:
-                    init_state = (x0,) + tuple(carry)
-                elif precond_spec is not None:
-                    init_state = (x0, r, u0, gamma0, rr0)
-                else:
-                    init_state = (x0, r, r, gamma)
-                init_state = init_state + ((inf,) if needs_diff else ())
-                if detect:
-                    init_state = init_state + (jnp.asarray(False),)
-                if health is not None:
-                    init_state = init_state + (_health.audit_init(sdt,
-                                                                  health),)
-                if trace:
-                    init_state = init_state + (telemetry.ring_init(
-                        trace, sdt, audit=health is not None),)
-                bad_i = -1 - (1 if trace else 0) - (
-                    1 if health is not None else 0)
-                conv_i = 4 if precond_spec is not None else 3
-                k, state, done = run_iter(
-                    body, init_state, lambda s: s[conv_i],
-                    (lambda s: s[dx_i]) if needs_diff else (lambda s: inf),
-                    bad_of=(lambda s: s[bad_i]) if detect else None)
-                x, r_fin, gamma_fin = state[0], state[1], state[conv_i]
-                dxsqr = state[dx_i] if needs_diff else inf
-                breakdown = state[bad_i] if detect else jnp.asarray(False)
-                tbuf = state[-1] if trace else None
-                aud_out = (state[-2] if trace else state[-1]) \
-                    if health is not None else None
-                rnrm2 = jnp.sqrt(gamma_fin)
-            elif precond_spec is not None:
-                # preconditioned Ghysels-Vanroose (jax_cg pbody, psum'd):
-                # ONE fused 3-scalar allreduce per iteration, the
-                # preconditioner apply + its SpMV overlapping it
-                if carry is None:
-                    w = spmv(u0)
-                zeros = jnp.zeros_like(b)
-
-                def pbody(k, state):
-                    if trace:
-                        buf, state = state[-1], state[:-1]
-                    if health is not None:
-                        aud, state = state[-1], state[:-1]
-                    x, r, u, w, p, s, q, z, gamma_prev, alpha_prev = \
-                        state[:10]
-                    rr_prev = state[10]
-                    gamma, delta, rr = pdot3_fused(r, u, w, u, r, r)
-                    if fault is not None:
-                        delta = fault.apply_dot(delta, k)
-                    m = papply(w, k)
-                    nvec = spmv(m, k)
-                    beta = gamma / gamma_prev
-                    denom = delta - beta * (gamma / alpha_prev)
-                    if detect:
-                        bad, alpha = _breakdown_guard(gamma, denom)
-                        bad = bad | (gamma < 0)
-                        alpha = jnp.where(bad, jnp.zeros_like(alpha),
-                                          alpha)
-                    else:
-                        alpha = gamma / denom
-                    z = store(nvec + beta * z)
-                    q = store(m + beta * q)
-                    s = store(w + beta * s)
-                    p = store(u + beta * p)
-                    if detect:
-                        x = store(jnp.where(bad, x, x + alpha * p))
-                        r = store(jnp.where(bad, r, r - alpha * s))
-                        u = store(jnp.where(bad, u, u - alpha * q))
-                        w = store(jnp.where(bad, w, w - alpha * z))
-                    else:
-                        x = store(x + alpha * p)
-                        r = store(r - alpha * s)
-                        u = store(u - alpha * q)
-                        w = store(w - alpha * z)
-                    out = (x, r, u, w, p, s, q, z, gamma, alpha, rr)
-                    if needs_diff:
-                        dx = alpha * alpha * psum(ldot(p, p))
-                        if detect:
-                            dx = jnp.where(bad, state[11], dx)
-                        out = out + (dx,)
-                    fire = None
-                    if health is not None:
-                        kk = k if k_offset is None else k + k_offset
-
-                        def compute_gap():
-                            return _health.relative_gap(b - spmv(x), r,
-                                                                                pdot, bnrm2, sdt)
-
-                        aud, fire = _health.audit_update(
-                            aud, health, kk, compute_gap)
-                        aud = _health.stall_update(aud, health,
-                                                   rr < rr_prev)
-                        if health.abft:
-                            # checksum test of this iteration's n = A m
-                            aud = _health.abft_update(
-                                aud, health, kk, nvec, m, cvec,
-                                pdot3_fused, sdt, nglobal)
-                    if detect:
-                        flag = bad
-                        if health is not None:
-                            flag = flag | _health.trip(aud, health)
-                        out = out + (flag,)
-                    if health is not None:
-                        out = out + (aud,)
-                    if trace:
-                        audit_col = (_health.ring_gap(aud, fire, sdt)
-                                     if health is not None else None)
-                        out = out + (telemetry.ring_record(
-                            buf, k, gamma, alpha, beta, denom,
-                            audit=audit_col),)
-                    if progress:
-                        telemetry.heartbeat(k, gamma, progress,
-                                            leader=leader,
-                                            what="dist-cg")
-                    return out
-
-                if carry is not None:
-                    init_state = (x0,) + tuple(carry)
-                    rr0 = carry[9]
-                else:
-                    init_state = (x0, r, u0, w, zeros, zeros, zeros,
-                                  zeros, inf, inf, rr0)
-                init_state = init_state + ((inf,) if needs_diff else ())
-                if detect:
-                    init_state = init_state + (jnp.asarray(False),)
-                if health is not None:
-                    init_state = init_state + (_health.audit_init(sdt,
-                                                                  health),)
-                if trace:
-                    init_state = init_state + (telemetry.ring_init(
-                        trace, sdt, audit=health is not None),)
-                bad_i = -1 - (1 if trace else 0) - (
-                    1 if health is not None else 0)
-                k, state, done = run_iter(
-                    pbody, init_state, lambda s: s[10],
-                    (lambda s: s[11]) if needs_diff else (lambda s: inf),
-                    init_gamma=rr0,
-                    bad_of=(lambda s: s[bad_i]) if detect else None)
-                x, r_fin = state[0], state[1]
-                dxsqr = state[11] if needs_diff else inf
-                breakdown = state[bad_i] if detect else jnp.asarray(False)
-                tbuf = state[-1] if trace else None
-                aud_out = (state[-2] if trace else state[-1]) \
-                    if health is not None else None
-                rnrm2 = jnp.sqrt(pdot(r_fin, r_fin))
-                # stale-test consistency: see jax_cg._cg_pipelined_program
-                done = jnp.logical_or(done, rnrm2 <= res_tol)
-            else:
-                if carry is None:
-                    w = spmv(r)
-                zeros = jnp.zeros_like(b)
-
-                def body(k, state):
-                    if trace:
-                        buf, state = state[-1], state[:-1]
-                    if health is not None:
-                        aud, state = state[-1], state[:-1]
-                    x, r, w, p, t, z, gamma_prev, alpha_prev = state[:8]
-                    # the pipelined variant's single fused allreduce:
-                    # both scalars in one psum (cgcuda.c:1730-1737)
-                    # single fused allreduce of both scalars
-                    gamma, delta = pdot2_fused(r, r, w, r)
-                    if fault is not None:
-                        delta = fault.apply_dot(delta, k)
-                    q = spmv(w, k)  # overlaps the psum under XLA's scheduler
-                    # the SpMV input, before the update rebinds w (the
-                    # ABFT check verifies q against THIS vector)
-                    w_in = w
-                    beta = gamma / gamma_prev
-                    denom = delta - beta * (gamma / alpha_prev)
-                    if detect:
-                        # jax_cg._cg_pipelined_program's guard: the
-                        # flag is NOT gamma_next-deferred here (the
-                        # pipelined poison surfaces in the next
-                        # iteration's (w, r) reduction instead)
-                        bad, alpha = _breakdown_guard(gamma, denom)
-                        if health is not None:
-                            # sign anomaly (jax_cg rationale)
-                            bad = bad | (gamma < 0)
-                            alpha = jnp.where(bad, jnp.zeros_like(alpha),
-                                              alpha)
-                    else:
-                        alpha = gamma / denom
-                    z = store(q + beta * z)
-                    t = store(w + beta * t)
-                    p = store(r + beta * p)
-                    if detect:
-                        x = store(jnp.where(bad, x, x + alpha * p))
-                        r = store(jnp.where(bad, r, r - alpha * t))
-                        w = store(jnp.where(bad, w, w - alpha * z))
-                    else:
-                        x = store(x + alpha * p)
-                        r = store(r - alpha * t)
-                        w = store(w - alpha * z)
-                    out = (x, r, w, p, t, z, gamma, alpha)
-                    if needs_diff:
-                        dx = alpha * alpha * psum(ldot(p, p))
-                        if detect:
-                            dx = jnp.where(bad, state[8], dx)
-                        out = out + (dx,)
-                    fire = None
-                    if health is not None:
-                        kk = k if k_offset is None else k + k_offset
-
-                        def compute_gap():
-                            return _health.relative_gap(b - spmv(x), r,
-                                                                                pdot, bnrm2, sdt)
-
-                        aud, fire = _health.audit_update(
-                            aud, health, kk, compute_gap)
-                        aud = _health.stall_update(aud, health,
-                                                   gamma < gamma_prev)
-                        if health.abft:
-                            # checksum test of this iteration's q = A w
-                            # (w_in: the pre-update input)
-                            aud = _health.abft_update(
-                                aud, health, kk, q, w_in, cvec,
-                                pdot3_fused, sdt, nglobal)
-                    if detect:
-                        flag = bad
-                        if health is not None:
-                            flag = flag | _health.trip(aud, health)
-                        out = out + (flag,)
-                    if health is not None:
-                        out = out + (aud,)
-                    if trace:
-                        # carried gamma (stale by one, like the
-                        # convergence test); alpha denominator in the
-                        # pAp slot (jax_cg._cg_pipelined_program)
-                        audit_col = (_health.ring_gap(aud, fire, sdt)
-                                     if health is not None else None)
-                        out = out + (telemetry.ring_record(
-                            buf, k, gamma, alpha, beta, denom,
-                            audit=audit_col),)
-                    if progress:
-                        telemetry.heartbeat(k, gamma, progress,
-                                            leader=leader, what="dist-cg")
-                    return out
-
-                # stale-gamma convergence test (see jax_cg): s[6] is the
-                # psum'd ||r||^2 from before the update
-                if carry is not None:
-                    init_state = (x0,) + tuple(carry)
-                    init_gamma = carry[5]
-                else:
-                    init_state = (x0, r, w, zeros, zeros, zeros, inf, inf)
-                    init_gamma = gamma
-                init_state = init_state + ((inf,) if needs_diff else ())
-                if detect:
-                    init_state = init_state + (jnp.asarray(False),)
-                if health is not None:
-                    init_state = init_state + (_health.audit_init(sdt,
-                                                                  health),)
-                if trace:
-                    init_state = init_state + (telemetry.ring_init(
-                        trace, sdt, audit=health is not None),)
-                bad_i = -1 - (1 if trace else 0) - (
-                    1 if health is not None else 0)
-                k, state, done = run_iter(
-                    body, init_state, lambda s: s[6],
-                    (lambda s: s[8]) if needs_diff else (lambda s: inf),
-                    init_gamma=init_gamma,
-                    bad_of=(lambda s: s[bad_i]) if detect else None)
-                x, r_fin = state[0], state[1]
-                dxsqr = state[8] if needs_diff else inf
-                breakdown = state[bad_i] if detect else jnp.asarray(False)
-                tbuf = state[-1] if trace else None
-                aud_out = (state[-2] if trace else state[-1]) \
-                    if health is not None else None
-                rnrm2 = jnp.sqrt(pdot(r_fin, r_fin))
-                # stale-test consistency: see jax_cg._cg_pipelined_program
-                done = jnp.logical_or(done, rnrm2 <= res_tol)
-
-            # breakdown-at-the-floor consistency (jax_cg rationale): a
-            # flagged exit whose residual already meets tolerance is
-            # convergence, not breakdown
-            breakdown = breakdown & ~done
-            dxnrm2 = jnp.sqrt(dxsqr)
-            out = (x[None], k, rnrm2, r0nrm2, bnrm2, x0nrm2, dxnrm2,
-                   done, breakdown)
-            out = out + ((tbuf,) if trace else ())
-            # the audit vector rides after the ring so the existing
-            # out[9] = ring fetch in solve() is untouched
-            out = out + ((aud_out,) if health is not None else ())
+            # n is the GLOBAL unknown count (the ABFT mismatch scale;
+            # local shapes would understate the rounding headroom)
+            ops = TierOps(spmv=spmv, dot=pdot, psum_stack=psum,
+                          store=store, sdt=sdt,
+                          dotk=make_pdotk(psum, ldot, sdt, precise),
+                          precond=papply, pidx=pidx, n=int(prob.n),
+                          leader=leader, what="dist-cg")
+            # every scalar the loop tests is psum'd, so `done` and the
+            # breakdown flag are identical on every shard and the while
+            # predicates agree across the mesh
+            res, extras = run_cg(ops, b, x0, tols, maxits,
+                                 unbounded=unbounded,
+                                 needs_diff=needs_diff, detect=detect,
+                                 carry=carry, k_offset=k_offset, **hooks)
+            out = (res.x[None], res.niterations, res.rnrm2, res.r0nrm2,
+                   res.bnrm2, res.x0nrm2, res.dxnrm2, res.converged,
+                   res.breakdown)
             if state_io:
-                # the final loop carry, strictly last (checkpoint.
-                # carry_names order minus x, which rides the result):
-                # vector leaves re-stack the parts axis, psum'd scalars
-                # stay replicated
-                core = state[1:1 + len(c_names)]
-                out = out + tuple(v[None] if v.ndim else v
-                                  for v in core)
-            return out
+                # the final loop carry, strictly last: vector leaves
+                # re-stack the parts axis, psum'd scalars stay replicated
+                extras = extras[:-1] + tuple(v[None] if v.ndim else v
+                                             for v in extras[-1])
+            return out + extras
 
         with_precond = precond_spec is not None
         if single_shard and not prob.halo.has_ghosts:
@@ -2108,7 +1656,7 @@ class DistCGSolver:
         # the telemetry ring (psum'd scalars) and the audit vector
         # (psum'd gap) are replicated
         out_specs = (pspec,) + (rspec,) * (
-            8 + (1 if trace else 0)
+            8 + (1 if self.trace else 0)
             + (1 if self.health_spec is not None else 0))
         # the state_io carry: vector leaves shard like x, psum'd
         # scalars replicate (checkpoint.carry_names order)
@@ -2161,8 +1709,8 @@ class DistCGSolver:
         """Communication-avoiding recurrence programs: s-step CG (one
         Gram allreduce per s-iteration block) and deep-pipelined
         p(l)-CG (one fused 2l+2-scalar window allreduce per iteration),
-        shard_map'd over the SAME halo'd SpMV / psum plumbing as the
-        hand-built programs.  The recurrence math itself -- basis
+        shard_map'd over the SAME halo'd SpMV / psum plumbing as
+        :meth:`_compile`.  The recurrence math itself -- basis
         construction, coefficient updates, the stream-Cholesky window
         bookkeeping -- is the same code the single-device tier runs
         (recurrence.run_sstep_loop / run_pl_loop): a recurrence lands

@@ -21,12 +21,12 @@ tier's SpMV (halo'd or not), its global dot / fused k-dot family
 Recurrences:
 
 ``classic`` / ``pipelined``
-    The existing hand-built programs stay dispatched (zero risk), but
-    the builder can emit both, and tests/test_hlo_structure.py pins the
-    builder emission BYTE-IDENTICAL (StableHLO) to the hand-built
-    programs on the single-device and dist tiers -- the proof that this
-    refactor is a no-op for current users and that new features can
-    land in the builder instead of per-copy.
+    Classic CG and Ghysels-Vanroose pipelined CG (:func:`run_cg`), with
+    every hook the solvers arm -- breakdown detection, fault sites,
+    preconditioner, diff criterion, health audit, telemetry ring,
+    checkpoint carry.  The single-device programs (jax_cg._cg_program /
+    _cg_pipelined_program) and the mesh program (DistCGSolver._compile)
+    are frames around it: they build their TierOps and call it.
 
 ``sstep:S`` -- communication-avoiding s-step CG (arXiv:2501.03743
     lineage; Chronopoulos-Gear / Carson formulation).  Per outer block:
@@ -73,12 +73,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from acg_tpu import health as _health, telemetry
+from acg_tpu.checkpoint import carry_names
 from acg_tpu.ops.spmv import acc_dtype
 
-# the classic/pipelined body + loop driver live in jax_cg; imported
-# lazily inside functions to avoid a circular import at module load
-# (jax_cg does not import recurrence at module level either -- the
-# solver imports it inside _select_program)
+# jax_cg imports this module; what this module needs of jax_cg
+# (CGResult, _scalar_setup, _spmv_fn) is imported inside functions
 
 POWER_ITERS = 24          # lambda_max power iteration length (setup)
 LAM_SAFETY = 1.05         # spectral headroom on the estimated lambda_max
@@ -210,16 +210,25 @@ def reduction_schedule(spec: RecurrenceSpec | None, pipelined: bool,
 @dataclasses.dataclass
 class TierOps:
     """What a tier contributes to the builder: its SpMV (halo machinery
-    included), its global dot, its stacked-payload reduction (the ONE
-    collective of the communication-avoiding recurrences), and its
-    storage rounding.  ``spmv(v, k)`` takes the iteration index so the
-    deterministic fault injector can key on it."""
+    included), its global dot and fused k-dot, its stacked-payload
+    reduction (the ONE collective of the communication-avoiding
+    recurrences), its storage rounding, its preconditioner apply, and
+    what the fault injector, the ABFT check and the heartbeat need to
+    know of the tier.  ``spmv(v, k)`` takes the iteration index so the
+    tier's SpMV (and halo) fault sites can key on it; ``k`` None
+    (set-up, audits) never injects."""
 
     spmv: callable
     dot: callable            # (a, c) -> global scalar in sdt
     psum_stack: callable     # stacked local payload -> reduced payload
     store: callable
     sdt: object
+    dotk: callable = None    # ((a1, c1), ...) -> k global scalars, fused
+    precond: callable = None  # v -> M^-1 v (None: unpreconditioned)
+    pidx: object = None      # the part index a vector fault targets
+    n: int = 0               # global unknown count (the ABFT scale)
+    leader: object = None    # traced bool: the part that speaks for all
+    what: str = "cg"         # the heartbeat's label
 
     def gram(self, V):
         """Global Gram matrix of the stacked basis V ((m, n) rows):
@@ -236,9 +245,13 @@ class TierOps:
         return self.psum_stack(local)
 
 
-def single_ops(A, kernels, dot, sdt, store, fault=None):
+def single_ops(A, kernels, dot, sdt, store, fault=None, precond=None,
+               mstate=None, n: int = 0):
     """TierOps for the single-device tier (and the sharded-DIA tier,
-    whose mesh-aware SpMV arrives as a callable ``kernels``).
+    whose mesh-aware SpMV arrives as a callable ``kernels``): the fused
+    k-dot is k plain dots in order, and ``precond`` (a static
+    acg_tpu.precond.PrecondSpec) applies M^-1 from ``mstate`` through
+    this tier's SpMV.
 
     ``A`` may be any DeviceMatrix OR a matrix-free operator
     (acg_tpu.ops.operator): ``_spmv_fn`` routes through the ops.spmv
@@ -256,8 +269,507 @@ def single_ops(A, kernels, dot, sdt, store, fault=None):
             y = fault.apply_spmv(y, k)
         return y
 
+    papply = None
+    if precond is not None:
+        from acg_tpu.precond import make_apply
+        apply = make_apply(precond, spmv_)
+
+        def papply(v):
+            return apply(mstate, A, v)
+
     return TierOps(spmv=spmv, dot=dot, psum_stack=lambda s: s,
-                   store=store, sdt=sdt)
+                   store=store, sdt=sdt,
+                   dotk=lambda *pairs: tuple(dot(a, c) for a, c in pairs),
+                   precond=papply, n=n)
+
+
+# -- classic and pipelined CG: the loop every tier runs --------------------
+#
+# The ONE place the classic and the Ghysels-Vanroose pipelined recurrence
+# are written, every cross-cutting hook included: the breakdown guard, the
+# fault sites, the preconditioner, the diff criterion, the health audit
+# (true-residual gap, stall, ABFT checksum), the telemetry ring and
+# heartbeat, and the checkpoint carry.  The single-device programs
+# (solvers/jax_cg.py) and the mesh program (parallel/dist.py) are frames
+# that build their TierOps and call run_cg; a hook lands here once and
+# reaches both tiers.
+
+def _converged(rnrm2sqr, dxnrm2sqr, res_tol, diff_tol):
+    """The stopping test on squared norms; a zero tolerance disables
+    its criterion (cf. cg.c:844-848)."""
+    ok = jnp.asarray(False)
+    ok = ok | jnp.where(res_tol > 0, rnrm2sqr < res_tol * res_tol, False)
+    ok = ok | jnp.where(diff_tol > 0, dxnrm2sqr < diff_tol * diff_tol, False)
+    return ok
+
+
+def _breakdown_guard(gamma, denom):
+    """``(bad, alpha)``: the ONE breakdown predicate every detecting
+    loop shares -- non-finite gamma/denominator, or a non-positive
+    denominator while progress remains -- and the guarded step size
+    (a jnp.where select, NOT a zeroed multiply: 0 * inf is NaN, so a
+    multiplied-out alpha would still poison the frozen vectors)."""
+    bad = ((~jnp.isfinite(denom)) | (~jnp.isfinite(gamma))
+           | ((denom <= 0) & (gamma > 0)))
+    return bad, jnp.where(bad, jnp.zeros_like(gamma), gamma / denom)
+
+
+def _iterate(iter_body, init_state, gamma_of, maxits, res_tol,
+             diff_tol, dx_of, unbounded: bool, init_gamma=None,
+             bad_of=None):
+    """Run the CG iteration to maxits (traced scalar) or convergence.
+
+    ``iter_body(k, state)`` receives the 0-based iteration index -- the
+    hook the deterministic fault injector (acg_tpu.faults) keys on.
+
+    Loop-structure choice, measured on TPU v5e (poisson2d n=2048, f32)
+    in the project's early rounds, before the benchmark's chip ledger
+    existed (the ledger's `iter_ms` now holds the per-iteration times):
+      * `fori_loop` with a *traced* bound and a minimal carry ran at the
+        same speed as a static bound -- so a dynamic maxits costs
+        nothing and one compile serves every iteration cap;
+      * a `while_loop` whose predicate reads a data-dependent scalar
+        cost ~+0.2 ms/iter (the carry dependence defeats loop
+        pipelining), and testing only every K-th iteration in an outer
+        while was *worse* (a drain at each chunk boundary).
+    Hence: tolerance-free solves (benchmark mode) take the pure fori path
+    with no convergence predicate at all -- the analog of the reference
+    always running with a deferred, one-iteration-stale test
+    (``cgcuda.c:980-1052``) -- and tolerance-driven solves pay for the
+    per-iteration device-side test exactly like the reference's
+    device-initiated variant (``cg-kernels-cuda.cu:948-957``).
+
+    ``bad_of`` (breakdown detection, ``detect=True`` programs) reads the
+    carried breakdown flag; a flagged state exits the loop early so the
+    host recovery policy can act.  Detection forces the while path even
+    for unbounded solves -- the predicate's cost is the price of early
+    exit, paid only when recovery is requested.
+    """
+    if unbounded and bad_of is None:
+        state = jax.lax.fori_loop(0, maxits, iter_body, init_state)
+        return maxits, state, jnp.asarray(True)
+
+    def body(carry):
+        k, state, _ = carry
+        state = iter_body(k, state)
+        done = (jnp.asarray(False) if unbounded else
+                _converged(gamma_of(state), dx_of(state), res_tol, diff_tol))
+        return (k + 1, state, done)
+
+    def cond(carry):
+        go = (~carry[2]) & (carry[0] < maxits)
+        if bad_of is not None:
+            go = go & (~bad_of(carry[1]))
+        return go
+
+    # init_gamma overrides the carried value for the entry test: the
+    # pipelined recurrence carries gamma_prev = inf at entry, but an
+    # already-converged start (r0 = 0) must return x0 in 0 iterations,
+    # not divide 0/0 in the first update.
+    init_done = (jnp.asarray(False) if unbounded else _converged(
+        gamma_of(init_state) if init_gamma is None else init_gamma,
+        dx_of(init_state), res_tol, diff_tol))
+    k, state, done = jax.lax.while_loop(cond, body,
+                                        (jnp.int32(0), init_state,
+                                         init_done))
+    if unbounded:
+        # unbounded semantics: "converged" = ran the full budget without
+        # a detected breakdown (the only early exit on this path)
+        done = ~bad_of(state)
+    return k, state, done
+
+
+def _split_tail(state, trace: int, health):
+    """``(ring, audit, core)`` of a loop carry: the audit vector and the
+    ring buffer ride LAST (in that order), so every core index -- dx,
+    the deferred-bad flag -- stays where it is."""
+    buf = aud = None
+    if trace:
+        buf, state = state[-1], state[:-1]
+    if health is not None:
+        aud, state = state[-1], state[:-1]
+    return buf, aud, state
+
+
+def _close_tail(ops: TierOps, out, k, aud, fire, buf, ring, trace: int,
+                progress: int, health):
+    """Append the audit vector and this iteration's ring record to a
+    body's output, and emit the heartbeat.  ``ring`` holds the four
+    RAW recorded scalars (a poisoned one stays visible in the window
+    the recovery log quotes); the first is the residual norm^2 the
+    heartbeat prints.  Every recorded scalar is a global reduction, so
+    on a mesh the ring is replicated and one part speaks for all."""
+    if health is not None:
+        out = out + (aud,)
+    if trace:
+        audit_col = (_health.ring_gap(aud, fire, ops.sdt)
+                     if health is not None else None)
+        out = out + (telemetry.ring_record(buf, k, *ring,
+                                           audit=audit_col),)
+    if progress:
+        telemetry.heartbeat(k, ring[0], progress, leader=ops.leader,
+                            what=ops.what)
+    return out
+
+
+def _apply_precond(ops: TierOps, fault, v, k):
+    """``M^-1 v`` through the tier's apply, with the precond fault site."""
+    z = ops.precond(v)
+    if fault is not None:
+        z = fault.apply_precond(z, k, ops.pidx)
+    return z
+
+
+def classic_recurrence(ops: TierOps, *, needs_diff: bool = False,
+                       detect: bool = False, fault=None, trace: int = 0,
+                       progress: int = 0, health=None, audit=None):
+    """Classic CG as a loop body; preconditioned CG when ``ops.precond``
+    is set.
+
+    Carry ``(x, r, p, gamma [, rr] [, dx] [, bad] [, aud] [, ring])``:
+    rr -- the true residual the convergence test reads -- joins only
+    under precond (gamma is then ``(r, z)``), dx only under a diff
+    criterion (every extra loop-carried scalar slows the TPU loop), the
+    deferred-bad flag under ``detect``.  Two global reductions per
+    iteration: ``(p, t)``, then ``(r, r)`` -- or ``(r, z)`` and
+    ``(r, r)`` fused into one."""
+    precond = ops.precond is not None
+    store = ops.store
+    dx_i = len(carry_names(False, precond))
+
+    def body(k, state):
+        buf, aud, state = _split_tail(state, trace, health)
+        x, r, p, gamma = state[:4]
+        # NOT the fused dia_spmv_dot: an in-kernel (p, t) scalar was
+        # measured ~15% slower in-loop in the project's early rounds,
+        # before the chip ledger existed (the opaque kernel boundary
+        # forfeits XLA's fusion of the updates, the same verdict as the
+        # fused 6-vector update, BASELINE.md); not re-measured since
+        t = ops.spmv(p, k)
+        with jax.named_scope("acg.dot"):
+            pdott = ops.dot(p, t)
+        if fault is not None:
+            pdott = fault.apply_dot(pdott, k)
+        with jax.named_scope("acg.update"):
+            if detect:
+                # breakdown BEFORE the updates: a non-finite t/pdott or
+                # an indefiniteness signal must not reach x
+                bad, alpha = _breakdown_guard(gamma, pdott)
+                x = store(jnp.where(bad, x, x + alpha * p))
+                r = store(jnp.where(bad, r, r - alpha * t))
+            else:
+                alpha = gamma / pdott
+                x = store(x + alpha * p)
+                r = store(r - alpha * t)
+        if precond:
+            z = _apply_precond(ops, fault, r, k)
+            # ONE fused reduction for both scalars
+            with jax.named_scope("acg.dot"):
+                gamma_next, rr_next = ops.dotk((r, z), (r, r))
+            with jax.named_scope("acg.update"):
+                beta = gamma_next / gamma
+                p_next = store(z + beta * p)
+            out = (x, r, p_next, gamma_next, rr_next)
+        else:
+            with jax.named_scope("acg.dot"):
+                gamma_next = ops.dot(r, r)
+            with jax.named_scope("acg.update"):
+                beta = gamma_next / gamma
+                p_next = store(r + beta * p)
+            out = (x, r, p_next, gamma_next)
+        if needs_diff:
+            with jax.named_scope("acg.dot"):
+                dx = alpha * alpha * ops.dot(p, p)
+            if detect:
+                # freeze dx too: a zeroed alpha would make the frozen
+                # iteration "satisfy" the diff criterion and launder the
+                # breakdown into a converged exit
+                dx = jnp.where(bad, state[dx_i], dx)
+            out = out + (dx,)
+        fire = None
+        if health is not None:
+            # residual non-decrease on the scalar the convergence test
+            # reads (preconditioned: the carried rr); ABFT checks t = A p
+            aud, fire = audit(aud, k, x, r,
+                              rr_next if precond else gamma_next,
+                              state[4] if precond else gamma, t, p)
+        if detect:
+            # a poison that slipped past pdott (e.g. a NaN row of t with
+            # a finite dot) lands in r: flag it one iteration deferred.
+            # A NEGATIVE (r, z) is the non-SPD-M signal (the precond:
+            # fault site's deterministic twin); under the health tier a
+            # negative (r, r) is arithmetic poison the finite-value
+            # guard misses
+            deferred = bad | (~jnp.isfinite(gamma_next))
+            if precond or health is not None:
+                deferred = deferred | (gamma_next < 0)
+            if health is not None:
+                deferred = deferred | _health.trip(aud, health)
+            out = out + (deferred,)
+        return _close_tail(ops, out, k, aud, fire, buf,
+                           (gamma_next, alpha, beta, pdott), trace,
+                           progress, health)
+
+    return body
+
+
+def pipelined_recurrence(ops: TierOps, *, needs_diff: bool = False,
+                         detect: bool = False, fault=None, trace: int = 0,
+                         progress: int = 0, health=None, audit=None):
+    """Ghysels-Vanroose pipelined CG as a loop body, with the fused
+    6-vector update of the reference's cooperative kernel
+    (``cg-kernels-cuda.cu:187-269``).  ONE fused reduction per
+    iteration, which the SpMV overlaps (``cgcuda.c:1750-1790``; under
+    XLA the scheduler owns that overlap).
+
+    Carry ``(x, r, w, p, t, z, gamma_prev, alpha_prev)``; with
+    ``ops.precond`` the preconditioned GV form (the method
+    arXiv:1801.04728 / 1905.06850 actually pipeline), carry ``(x, r, u,
+    w, p, s, q, z, gamma_prev, alpha_prev, rr)`` -- ``u = M^-1 r``, s
+    the A-direction (the unpreconditioned t), q the M^-1 A-direction, z
+    the A M^-1 A-direction -- one preconditioner apply ``m = M^-1 w``
+    and one SpMV ``n = A m`` per iteration, and THREE scalars in the
+    fused reduction (gamma = (r, u), delta = (w, u), rr = (r, r)).
+    Then the dx / bad / audit / ring tail as in classic_recurrence.
+
+    gamma is the carried ||r||^2 (or rr) from BEFORE the update: the
+    convergence test, the detection and the telemetry record are all
+    one iteration stale, the reference's deferred test
+    (``cgcuda.c:1798-1810``).  The ring records the alpha denominator
+    in the pAp slot -- the recurrence scalars whose drift the
+    deep-pipelining literature plots (arXiv:1801.04728)."""
+    precond = ops.precond is not None
+    store = ops.store
+    dx_i = len(carry_names(True, precond))
+
+    def body(k, state):
+        buf, aud, state = _split_tail(state, trace, health)
+        with jax.named_scope("acg.dot"):
+            if precond:
+                x, r, u, w, p, s, q, z, gamma_prev, alpha_prev, rr_prev = \
+                    state[:11]
+                gamma, delta, rr = ops.dotk((r, u), (w, u), (r, r))
+            else:
+                x, r, w, p, s, z, gamma_prev, alpha_prev = state[:8]
+                u = r
+                gamma, delta = ops.dotk((r, r), (w, r))
+        if fault is not None:
+            delta = fault.apply_dot(delta, k)
+        # m is the SpMV input (w itself unpreconditioned); the ABFT test
+        # verifies n = A m against it after the update rebinds w
+        m = _apply_precond(ops, fault, w, k) if precond else w
+        n = ops.spmv(m, k)
+        with jax.named_scope("acg.update"):
+            beta = gamma / gamma_prev           # inf -> 0 on first iteration
+            denom = delta - beta * (gamma / alpha_prev)
+            if detect:
+                # the alpha denominator plays the (p, Ap) role; freeze
+                # the iterate on breakdown (p/s/z are scratch once the
+                # loop exits).  A negative gamma is the non-SPD-M signal
+                # under precond and arithmetic poison under the health
+                # tier -- the finite-value guard cannot see either
+                bad, alpha = _breakdown_guard(gamma, denom)
+                if precond or health is not None:
+                    bad = bad | (gamma < 0)
+                    alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
+            else:
+                alpha = gamma / denom
+            # the 6-vector update stays in XLA even under
+            # kernels="pallas": the hand-written fused kernel (ops.
+            # pallas_kernels.fused_pipelined_update) won in isolation but
+            # inside the loop it is an opaque call that forfeits XLA's
+            # fusion of the *next* iteration's dots into these writes --
+            # measured about 2x slower in the project's early rounds,
+            # before the chip ledger existed (BASELINE.md)
+            z = store(n + beta * z)
+            if precond:
+                q = store(m + beta * q)
+            s = store(w + beta * s)
+            p = store(u + beta * p)
+            if detect:
+                x = store(jnp.where(bad, x, x + alpha * p))
+                r = store(jnp.where(bad, r, r - alpha * s))
+                if precond:
+                    u = store(jnp.where(bad, u, u - alpha * q))
+                w = store(jnp.where(bad, w, w - alpha * z))
+            else:
+                x = store(x + alpha * p)
+                r = store(r - alpha * s)
+                if precond:
+                    u = store(u - alpha * q)
+                w = store(w - alpha * z)
+        if precond:
+            out = (x, r, u, w, p, s, q, z, gamma, alpha, rr)
+        else:
+            out = (x, r, w, p, s, z, gamma, alpha)
+        if needs_diff:
+            with jax.named_scope("acg.dot"):
+                dx = alpha * alpha * ops.dot(p, p)
+            if detect:
+                dx = jnp.where(bad, state[dx_i], dx)
+            out = out + (dx,)
+        fire = None
+        if health is not None:
+            # progress on the fused residual scalar (stale by one)
+            aud, fire = audit(aud, k, x, r, rr if precond else gamma,
+                              rr_prev if precond else gamma_prev, n, m)
+        if detect:
+            flag = bad
+            if health is not None:
+                flag = flag | _health.trip(aud, health)
+            out = out + (flag,)
+        return _close_tail(ops, out, k, aud, fire, buf,
+                           (gamma, alpha, beta, denom), trace, progress,
+                           health)
+
+    return body
+
+
+def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
+           unbounded: bool, needs_diff: bool, detect: bool = False,
+           fault=None, trace: int = 0, progress: int = 0, health=None,
+           state_io: bool = False, carry=None, k_offset=None):
+    """A whole classic or pipelined CG solve over the tier's ``ops``:
+    the set-up, the loop and the result.  Returns ``(CGResult,
+    extras)`` with extras = (ring?, audit?, carry?) in that order.
+
+    ``tols`` = ``(res_atol, res_rtol, diff_atol, diff_rtol)``.  The
+    static switches arm the hooks (each None/False/0 traces none of its
+    ops): ``detect`` carries the breakdown flag and exits on it;
+    ``fault`` (acg_tpu.faults.FaultSpec) arms the injector's SpMV
+    (through ``ops.spmv(v, k)``), dot and precond sites; ``trace`` /
+    ``progress`` the telemetry ring and heartbeat; ``health``
+    (acg_tpu.health.HealthSpec) the audit, phased to trajectory
+    iterations by ``k_offset`` (the chunk's starting iteration).
+
+    ``state_io`` returns the final loop carry (checkpoint.carry_names
+    order minus x, which rides the result); a ``carry`` of that shape
+    re-enters the recurrence exactly where a chunk left it (``x0`` then
+    holds the snapshot iterate and the set-up SpMV is skipped), so a
+    chunked trajectory is iteration-identical to an uninterrupted one.
+
+    The ops carry the device-scope vocabulary in their ``op_name``:
+    ``acg.setup`` (r0 = b - A x0 and the pre-loop dots), ``acg.spmv``
+    (every operator application, named by the tier's SpMV),
+    ``acg.dot`` and ``acg.update`` (the loop's dots and updates)."""
+    from acg_tpu.solvers.jax_cg import CGResult
+
+    sdt, store, dot = ops.sdt, ops.store, ops.dot
+    precond = ops.precond is not None
+    res_atol, res_rtol, diff_atol, diff_rtol = tols
+    # the carry's true-residual scalar: gamma (classic), rr (precond),
+    # the carried stale gamma (GV pipelined); dx rides right after
+    ncore = len(carry_names(pipelined, precond))
+    conv_i = ncore - 2 if pipelined and not precond else ncore - 1
+    with jax.named_scope("acg.setup"):
+        bnrm2 = jnp.sqrt(dot(b, b))
+        x0nrm2 = jnp.sqrt(dot(x0, x0))
+        if carry is not None:
+            # resume: the carry IS the loop state; nothing is recomputed
+            init = (x0,) + tuple(carry)
+            g0 = carry[conv_i - 1]
+            r0nrm2 = jnp.sqrt(jnp.maximum(g0, 0)
+                              if pipelined and not precond else g0)
+        else:
+            r = b - ops.spmv(x0)
+            if not pipelined:
+                if precond:
+                    z0 = ops.precond(r)
+                    p = store(z0)
+                    gamma, g0 = ops.dotk((r, z0), (r, r))
+                    init = (x0, r, p, gamma, g0)
+                else:
+                    g0 = dot(r, r)
+                    init = (x0, r, r, g0)
+                r0nrm2 = jnp.sqrt(g0)
+            elif precond:
+                u0 = store(ops.precond(r))
+                w = ops.spmv(u0)
+                g0 = dot(r, r)
+                r0nrm2 = jnp.sqrt(g0)
+            else:
+                w = ops.spmv(r)
+                r0nrm2 = jnp.sqrt(dot(r, r))
+        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
+        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
+    inf = jnp.asarray(jnp.inf, sdt)
+    if pipelined and carry is None:
+        zeros = jnp.zeros_like(b)
+        init = ((x0, r, u0, w) + (zeros,) * 4 + (inf, inf, g0) if precond
+                else (x0, r, w) + (zeros,) * 3 + (inf, inf))
+    cvec = None
+    if health is not None and health.abft:
+        # the column checksum c = A^T 1 (= A 1: symmetric systems)
+        # through the tier's own SpMV -- one extra SpMV per solve, and
+        # the in-loop test rides the fused 3-scalar reduction
+        cvec = ops.spmv(jnp.ones_like(b)).astype(sdt)
+    init_gamma = None
+    if pipelined:
+        init_gamma = (r0nrm2 * r0nrm2 if carry is None and not precond
+                      else g0)
+    init = init + ((inf,) if needs_diff else ())
+    if detect:
+        init = init + (jnp.asarray(False),)
+    if health is not None:
+        init = init + (_health.audit_init(sdt, health),)
+    if trace:
+        init = init + (telemetry.ring_init(trace, sdt,
+                                           audit=health is not None),)
+
+    def audit(aud, k, x, r, now, prev, y, v):
+        """The in-loop audit: the true-residual gap through the tier's
+        own SpMV (lax.cond-guarded: off-cadence iterations pay only the
+        predicate; the mesh's conditional collectives fire on every part
+        together), the stall counter, the ABFT check of ``y = A v``."""
+        kk = k if k_offset is None else k + k_offset
+
+        def compute_gap():
+            return _health.relative_gap(b - ops.spmv(x), r, dot, bnrm2,
+                                        sdt)
+
+        aud, fire = _health.audit_update(aud, health, kk, compute_gap)
+        aud = _health.stall_update(aud, health, now < prev)
+        if health.abft:
+            aud = _health.abft_update(aud, health, kk, y, v, cvec,
+                                      ops.dotk, sdt, ops.n)
+        return aud, fire
+
+    make_body = pipelined_recurrence if pipelined else classic_recurrence
+    body = make_body(ops, needs_diff=needs_diff, detect=detect,
+                     fault=fault, trace=trace, progress=progress,
+                     health=health, audit=audit)
+    bad_i = -1 - (1 if trace else 0) - (1 if health is not None else 0)
+    k, state, done = _iterate(
+        body, init, lambda s: s[conv_i], maxits, res_tol, diff_tol,
+        (lambda s: s[ncore]) if needs_diff else (lambda s: inf),
+        unbounded, init_gamma=init_gamma,
+        bad_of=(lambda s: s[bad_i]) if detect else None)
+    x = state[0]
+    dxsqr = state[ncore] if needs_diff else inf
+    breakdown = state[bad_i] if detect else jnp.asarray(False)
+    if pipelined:
+        rnrm2 = jnp.sqrt(dot(state[1], state[1]))
+        # the in-loop test is one iteration stale; at the maxits
+        # boundary a solve whose final *fresh* residual meets tolerance
+        # must not report converged=False with a below-tolerance rnrm2
+        done = jnp.logical_or(done, rnrm2 <= res_tol)
+    # a breakdown flagged on the iteration the tolerance was met is
+    # convergence: at the f32 floor the (p, Ap) scalar / the pipelined
+    # denominator legitimately rounds <= 0 once progress is exhausted
+    breakdown = breakdown & ~done
+    if not pipelined:
+        rnrm2 = jnp.sqrt(state[conv_i])
+    res = CGResult(x=x, niterations=k, rnrm2=rnrm2, r0nrm2=r0nrm2,
+                   bnrm2=bnrm2, x0nrm2=x0nrm2, dxnrm2=jnp.sqrt(dxsqr),
+                   converged=done, breakdown=breakdown)
+    extras = ()
+    if trace:
+        extras = extras + (state[-1],)
+    if health is not None:
+        extras = extras + (state[-2] if trace else state[-1],)
+    if state_io:
+        extras = extras + (tuple(state[1:ncore]),)
+    return res, extras
 
 
 # -- s-step CG -------------------------------------------------------------
@@ -338,10 +850,6 @@ def make_sstep_block(ops: TierOps, s: int, basis: str, lam, res_tol,
     tol2 = res_tol * res_tol
     Bmat = sstep_combined_bmat(s, basis, lam, sdt)
     w = 2 * s + 1
-    if trace or progress:
-        from acg_tpu import telemetry
-    if health is not None:
-        from acg_tpu import health as _health
 
     def body(state):
         if trace:
@@ -492,8 +1000,6 @@ def make_pl_step(ops: TierOps, l: int, sigma, res_tol, maxits,
     sdt = ops.sdt
     tol2 = res_tol * res_tol
     W = 2 * l + 2
-    if trace or progress:
-        from acg_tpu import telemetry
 
     def safe(x):
         return jnp.where(x == 0, jnp.asarray(1.0, sdt), x)
@@ -644,8 +1150,6 @@ def run_sstep_loop(ops: TierOps, s: int, basis: str, lam, b, x0, r,
     ``state_io`` appends the final ``(r, p, gamma)`` to the return."""
     sdt = ops.sdt
     tol2 = res_tol * res_tol
-    if health is not None:
-        from acg_tpu import health as _health
     body, ntails = make_sstep_block(
         ops, s, basis, lam, res_tol, maxits, fault=fault, trace=trace,
         progress=progress, health=health, what=what, leader=leader,
@@ -656,7 +1160,6 @@ def run_sstep_loop(ops: TierOps, s: int, basis: str, lam, b, x0, r,
     if health is not None:
         init = init + (_health.audit_init(sdt, health),)
     if trace:
-        from acg_tpu import telemetry
         init = init + (telemetry.ring_init(trace, sdt),)
 
     def cond(state):
@@ -710,7 +1213,6 @@ def run_pl_loop(ops: TierOps, l: int, lam, x0, z0, eta, eta2, res_tol,
                 x0.astype(sdt), q, dprev, ptilde, Z, V, zzq, gb,
                 gammas, deltas, jnp.asarray(False), jnp.asarray(False))
     if trace:
-        from acg_tpu import telemetry
         init = init + (telemetry.ring_init(trace, sdt),)
     jcap = maxits + jnp.int32(2 * l + 2)
 
@@ -871,296 +1373,12 @@ def estimate_lam(A, n: int, dtype, kernels: str = "xla"):
     return (0.0, lmax)
 
 
-# -- builder emission of the existing recurrences (byte-identity) ----------
-#
-# The classic and Ghysels-Vanroose recurrences as BUILDER bodies over
-# TierOps -- the same carry layout / update / reduction schedule the
-# hand-built programs in solvers/jax_cg.py and parallel/dist.py trace.
-# tests/test_hlo_structure.py pins the builder emission byte-identical
-# (StableHLO) to the hand-built programs on both tiers: the proof that
-# the builder is a faithful home for the recurrence matrix, and that
-# flipping the dispatch (or landing a new cross-cutting feature in the
-# builder instead of per-copy) is a no-op for current users.
-
-def classic_recurrence(ops: TierOps):
-    """Classic CG as a builder body: carry ``(x, r, p, gamma)``, two
-    global dots per iteration ((p, t) and (r, r))."""
-    def body(k, state):
-        x, r, p, gamma = state
-        t = ops.spmv(p, k)
-        with jax.named_scope("acg.dot"):
-            pdott = ops.dot(p, t)
-        with jax.named_scope("acg.update"):
-            alpha = gamma / pdott
-            x = ops.store(x + alpha * p)
-            r = ops.store(r - alpha * t)
-        with jax.named_scope("acg.dot"):
-            gamma_next = ops.dot(r, r)
-        with jax.named_scope("acg.update"):
-            beta = gamma_next / gamma
-            p_next = ops.store(r + beta * p)
-        return (x, r, p_next, gamma_next)
-    return body
-
-
-def pipelined_recurrence(ops: TierOps, dot2):
-    """Ghysels-Vanroose pipelined CG as a builder body: carry
-    ``(x, r, w, p, t, z, gamma_prev, alpha_prev)``, ONE fused 2-scalar
-    reduction per iteration (``dot2`` -- two plain dots on a single
-    device, pdot2_fused on the mesh)."""
-    def body(k, state):
-        x, r, w, p, t, z, gamma_prev, alpha_prev = state
-        with jax.named_scope("acg.dot"):
-            gamma, delta = dot2(r, r, w, r)
-        q = ops.spmv(w, k)
-        with jax.named_scope("acg.update"):
-            beta = gamma / gamma_prev
-            denom = delta - beta * (gamma / alpha_prev)
-            alpha = gamma / denom
-            z = ops.store(q + beta * z)
-            t = ops.store(w + beta * t)
-            p = ops.store(r + beta * p)
-            x = ops.store(x + alpha * p)
-            r = ops.store(r - alpha * t)
-            w = ops.store(w - alpha * z)
-        return (x, r, w, p, t, z, gamma, alpha)
-    return body
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("unbounded", "needs_diff",
-                                    "pipelined", "kernels"))
-def _builder_cg_program(A, b, x0, res_atol, res_rtol, diff_atol,
-                        diff_rtol, maxits, unbounded: bool,
-                        needs_diff: bool, pipelined: bool = False,
-                        kernels: str = "xla"):
-    """The builder's single-device emission of classic/GV-pipelined CG
-    (base configuration): byte-identity with jax_cg._cg_program /
-    _cg_pipelined_program is pinned in tests/test_hlo_structure.py."""
-    from acg_tpu.solvers.jax_cg import CGResult, _iterate, _scalar_setup
-    assert not needs_diff
-    dtype = b.dtype
-    dot, sdt = _scalar_setup(dtype, False)
-    store = (lambda v: v.astype(dtype)) if sdt != dtype else (lambda v: v)
-    ops = single_ops(A, kernels, dot, sdt, store)
-
-    def dot2(a1, c1, a2, c2):
-        return dot(a1, c1), dot(a2, c2)
-
-    with jax.named_scope("acg.setup"):
-        bnrm2 = jnp.sqrt(dot(b, b))
-        x0nrm2 = jnp.sqrt(dot(x0, x0))
-        if pipelined:
-            r = b - ops.spmv(x0, None)
-            w = ops.spmv(r, None)
-            r0nrm2 = jnp.sqrt(dot(r, r))
-        else:
-            r = b - ops.spmv(x0, None)
-            p = r
-            gamma = dot(r, r)
-            r0nrm2 = jnp.sqrt(gamma)
-        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
-    inf = jnp.asarray(jnp.inf, sdt)
-    if pipelined:
-        zeros = jnp.zeros_like(b)
-        body = pipelined_recurrence(ops, dot2)
-        init_state = (x0, r, w, zeros, zeros, zeros, inf, inf)
-        init_gamma = r0nrm2 * r0nrm2
-        k, state, done = _iterate(
-            body, init_state, lambda s: s[6], maxits, res_tol,
-            diff_tol, lambda s: inf, unbounded,
-            init_gamma=init_gamma, bad_of=None)
-        x, r = state[0], state[1]
-        dxsqr = inf
-        breakdown = jnp.asarray(False)
-        rnrm2 = jnp.sqrt(dot(r, r))
-        done = jnp.logical_or(done, rnrm2 <= res_tol)
-        breakdown = breakdown & ~done
-        return CGResult(x=x, niterations=k, rnrm2=rnrm2,
-                        r0nrm2=r0nrm2, bnrm2=bnrm2, x0nrm2=x0nrm2,
-                        dxnrm2=jnp.sqrt(dxsqr), converged=done,
-                        breakdown=breakdown)
-    body = classic_recurrence(ops)
-    init_state = (x0, r, p, gamma)
-    k, state, done = _iterate(
-        body, init_state, lambda s: s[3], maxits, res_tol, diff_tol,
-        lambda s: inf, unbounded, bad_of=None)
-    x, r, p, gamma = state[:4]
-    rnrm2sqr = gamma
-    dxsqr = inf
-    breakdown = jnp.asarray(False)
-    breakdown = breakdown & ~done
-    return CGResult(x=x, niterations=k, rnrm2=jnp.sqrt(rnrm2sqr),
-                    r0nrm2=r0nrm2, bnrm2=bnrm2, x0nrm2=x0nrm2,
-                    dxnrm2=jnp.sqrt(dxsqr), converged=done,
-                    breakdown=breakdown)
-
-
-def build_dist_program(solver):
-    """The builder's dist-tier emission of classic/GV-pipelined CG
-    (base configuration), composed with the solver's OWN machinery
-    (halo'd SpMV, psum, fused reductions, mesh specs): byte-identity
-    with DistCGSolver._compile()'s hand-built program is pinned in
-    tests/test_hlo_structure.py.
-
-    ``kernels='fused'`` swaps in the interior|border OVERLAPPED SpMV
-    (``make_dist_spmv_overlapped``: halo exchange issued first,
-    interior rows computed while it is in flight, border rows finished
-    after) -- this IS the dispatched program of the distributed fused
-    tier, so the overlapped SpMV lands once here and every recurrence
-    the builder emits inherits it.  Everything else (carry layout,
-    reduction ladder, shard specs) is identical, keeping the non-fused
-    emission byte-stable."""
-    import jax.numpy as _jnp
-    from jax import lax
-    from jax.sharding import PartitionSpec as P
-    from acg_tpu.parallel.dist import (make_dist_spmv,
-                                       make_dist_spmv_overlapped)
-    from acg_tpu.parallel.mesh import PARTS_AXIS
-    from acg_tpu.parallel.reductions import make_pdot, make_pdotk
-    from acg_tpu.solvers.jax_cg import _iterate
-
-    prob = solver.problem
-    pipelined = solver.pipelined
-    axis = PARTS_AXIS
-    if isinstance(solver.kernels, str) and \
-            solver.kernels.startswith("fused"):
-        dist_spmv = make_dist_spmv_overlapped(prob, solver.comm,
-                                              solver._interpret)
-    else:
-        dist_spmv = make_dist_spmv(prob, solver.comm, solver._interpret,
-                                   kernels=solver.kernels, fault=None)
-    single_shard = solver.mesh.devices.size == 1
-
-    def psum(v):
-        return v if single_shard else lax.psum(v, axis)
-
-    def shard_body(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols,
-                   maxits, unbounded=False, needs_diff=False):
-        la, ga = (jax.tree.map(lambda a: a[0], t) for t in (la, ga))
-        sidx, gsrc, gval, scnt, rcnt, b, x0 = (
-            a[0] for a in (sidx, gsrc, gval, scnt, rcnt, b, x0))
-        maxits = maxits.astype(jnp.int32)
-        dtype = b.dtype
-        sdt = acc_dtype(dtype)
-        store = ((lambda v: v.astype(dtype)) if sdt != dtype
-                 else (lambda v: v))
-        res_atol, res_rtol, diff_atol, diff_rtol = tols
-
-        def spmv(x, k=None):
-            return dist_spmv(x, la, ga, sidx, gsrc, gval, scnt, rcnt,
-                             k=k, pidx=None)
-
-        def ldot(a, c):
-            return jnp.dot(a, c, preferred_element_type=sdt)
-
-        pdot = make_pdot(psum, ldot, sdt, False)
-        _pdotk = make_pdotk(psum, ldot, sdt, False)
-
-        def pdot2_fused(a1, c1, a2, c2):
-            return _pdotk((a1, c1), (a2, c2))
-
-        ops = TierOps(spmv=spmv, dot=pdot, psum_stack=psum,
-                      store=store, sdt=sdt)
-        bnrm2 = jnp.sqrt(pdot(b, b))
-        x0nrm2 = jnp.sqrt(pdot(x0, x0))
-        r = b - spmv(x0)
-        if not pipelined:
-            gamma = pdot(r, r)
-            r0nrm2 = jnp.sqrt(gamma)
-        else:
-            gamma = pdot(r, r)
-            r0nrm2 = jnp.sqrt(gamma)
-        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
-        inf = jnp.asarray(jnp.inf, sdt)
-        if not pipelined:
-            body = classic_recurrence(ops)
-            init_state = (x0, r, r, gamma)
-            k, state, done = _iterate(
-                body, init_state, lambda s: s[3], maxits, res_tol,
-                diff_tol, lambda s: inf, unbounded, bad_of=None)
-            x, r_fin, gamma_fin = state[0], state[1], state[3]
-            dxsqr = inf
-            breakdown = jnp.asarray(False)
-            rnrm2 = jnp.sqrt(gamma_fin)
-        else:
-            w = spmv(r)
-            zeros = jnp.zeros_like(b)
-            body = pipelined_recurrence(ops, pdot2_fused)
-            init_state = (x0, r, w, zeros, zeros, zeros, inf, inf)
-            init_gamma = gamma
-            k, state, done = _iterate(
-                body, init_state, lambda s: s[6], maxits, res_tol,
-                diff_tol, lambda s: inf, unbounded,
-                init_gamma=init_gamma, bad_of=None)
-            x, r_fin = state[0], state[1]
-            dxsqr = inf
-            breakdown = jnp.asarray(False)
-            rnrm2 = jnp.sqrt(pdot(r_fin, r_fin))
-            done = jnp.logical_or(done, rnrm2 <= res_tol)
-        breakdown = breakdown & ~done
-        dxnrm2 = jnp.sqrt(dxsqr)
-        out = (x[None], k, rnrm2, r0nrm2, bnrm2, x0nrm2, dxnrm2,
-               done, breakdown)
-        return out
-
-    if single_shard and not prob.halo.has_ghosts:
-        @functools.partial(jax.jit,
-                           static_argnames=("unbounded", "needs_diff",
-                                            "detect"))
-        def program(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0,
-                    tols, maxits, unbounded, needs_diff,
-                    detect=False, mstate=None, carry=None,
-                    k_offset=None):
-            return shard_body(la, ga, sidx, gsrc, gval, scnt, rcnt,
-                              b, x0, tols, maxits,
-                              unbounded=unbounded,
-                              needs_diff=needs_diff)
-
-        return program
-
-    pspec = P(PARTS_AXIS)
-    rspec = P()
-    in_specs = (pspec, pspec,
-                pspec, pspec, pspec, pspec, pspec,
-                pspec, pspec,
-                rspec, rspec)
-    out_specs = (pspec,) + (rspec,) * 8
-
-    @functools.partial(jax.jit,
-                       static_argnames=("unbounded", "needs_diff",
-                                        "detect"))
-    def program(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0,
-                tols, maxits, unbounded, needs_diff, detect=False,
-                mstate=None, carry=None, k_offset=None):
-        extra = ()
-        specs = in_specs
-
-        def smb(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols,
-                maxits, *rest):
-            return shard_body(la, ga, sidx, gsrc, gval, scnt, rcnt,
-                              b, x0, tols, maxits,
-                              unbounded=unbounded,
-                              needs_diff=needs_diff)
-
-        return jax.shard_map(
-            smb,
-            mesh=solver.mesh, in_specs=specs, out_specs=out_specs,
-            check_vma=False,
-        )(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols, maxits,
-          *extra)
-
-    return program
-
-
 # -- measured segment probes (the communication observatory) ---------------
 #
 # SpMV-only / halo-only / reduction-only probe programs for
 # acg_tpu.commbench.segment_decomposition, built from the SAME machinery
-# the builder's dispatched emission composes -- the overlapped-or-not
-# dist SpMV selection of build_dist_program, the make_pdot/make_pdotk
+# the dispatched programs compose -- the overlapped-or-not dist SpMV
+# selection of DistCGSolver._compile, the make_pdot/make_pdotk
 # reduction ladders, _spmv_fn on the single-device tier -- so a measured
 # segment times the ops a real iteration runs, not a replay stand-in.
 # Each probe chains `reps` rounds inside ONE dispatched fori_loop (data
@@ -1221,7 +1439,7 @@ def build_single_segment_probes(solver, b, reps: int) -> list[tuple]:
 def build_dist_segment_probes(solver, b_global, reps: int) -> list[tuple]:
     """``[(name, runner, calls_per_iteration), ...]`` for the dist
     tier: the halo'd SpMV (overlapped when ``kernels='fused'`` -- the
-    same selection :func:`build_dist_program` dispatches), the halo
+    same selection DistCGSolver._compile dispatches), the halo
     exchange alone (xla all_to_all or one-sided DMA, per the solver's
     armed transport), and the psum reduction ladder."""
     from jax import lax

@@ -141,101 +141,27 @@ class CGResult:
     breakdown: jax.Array
 
 
-def _tolerances(crit: StoppingCriteria, r0nrm2, x0nrm2, dtype):
-    """Device-side residual/diff thresholds; 0 disables (cf. cg.c:844-848)."""
-    res_tol = jnp.maximum(jnp.asarray(crit.residual_atol, dtype),
-                          jnp.asarray(crit.residual_rtol, dtype) * r0nrm2)
-    diff_tol = jnp.maximum(jnp.asarray(crit.diff_atol, dtype),
-                           jnp.asarray(crit.diff_rtol, dtype) * x0nrm2)
-    return res_tol, diff_tol
+_CG_STATIC = ("unbounded", "needs_diff", "precise", "kernels", "detect",
+              "fault", "trace", "progress", "precond", "health", "state_io")
 
 
-def _converged(rnrm2sqr, dxnrm2sqr, res_tol, diff_tol):
-    ok = jnp.asarray(False)
-    ok = ok | jnp.where(res_tol > 0, rnrm2sqr < res_tol * res_tol, False)
-    ok = ok | jnp.where(diff_tol > 0, dxnrm2sqr < diff_tol * diff_tol, False)
-    return ok
+def _single_cg(A, b, x0, tols, maxits, *, pipelined, precise, kernels,
+               fault, precond, mstate, **hooks):
+    """The single-device frame: this tier's TierOps around the builder's
+    loop (acg_tpu.recurrence.run_cg)."""
+    from acg_tpu.recurrence import run_cg, single_ops
+
+    dtype = b.dtype
+    dot, sdt = _scalar_setup(dtype, precise)
+    store = (lambda v: v.astype(dtype)) if sdt != dtype else (lambda v: v)
+    ops = single_ops(A, kernels, dot, sdt, store, fault=fault,
+                     precond=precond, mstate=mstate, n=b.shape[0])
+    res, extras = run_cg(ops, b, x0, tols, maxits, pipelined=pipelined,
+                         fault=fault, **hooks)
+    return (res,) + extras if extras else res
 
 
-def _breakdown_guard(gamma, denom):
-    """``(bad, alpha)``: the ONE breakdown predicate every detecting
-    loop shares -- non-finite gamma/denominator, or a non-positive
-    denominator while progress remains -- and the guarded step size
-    (a jnp.where select, NOT a zeroed multiply: 0 * inf is NaN, so a
-    multiplied-out alpha would still poison the frozen vectors)."""
-    bad = ((~jnp.isfinite(denom)) | (~jnp.isfinite(gamma))
-           | ((denom <= 0) & (gamma > 0)))
-    return bad, jnp.where(bad, jnp.zeros_like(gamma), gamma / denom)
-
-
-def _iterate(iter_body, init_state, gamma_of, maxits, res_tol,
-             diff_tol, dx_of, unbounded: bool, init_gamma=None,
-             bad_of=None):
-    """Run the CG iteration to maxits (traced scalar) or convergence.
-
-    ``iter_body(k, state)`` receives the 0-based iteration index -- the
-    hook the deterministic fault injector (acg_tpu.faults) keys on.
-
-    Loop-structure choice, measured on TPU v5e (poisson2d n=2048, f32):
-      * `fori_loop` with a *traced* bound and a minimal carry runs at the
-        same speed as a static bound (~0.43 ms/iter) -- so a dynamic
-        maxits costs nothing and one compile serves every iteration cap;
-      * a `while_loop` whose predicate reads a data-dependent scalar costs
-        ~+0.2 ms/iter (the carry dependence defeats loop pipelining), and
-        testing only every K-th iteration in an outer while is *worse*
-        (~3.7 ms per chunk boundary drain).
-    Hence: tolerance-free solves (benchmark mode) take the pure fori path
-    with no convergence predicate at all -- the analog of the reference
-    always running with a deferred, one-iteration-stale test
-    (``cgcuda.c:980-1052``) -- and tolerance-driven solves pay for the
-    per-iteration device-side test exactly like the reference's
-    device-initiated variant (``cg-kernels-cuda.cu:948-957``).
-
-    ``bad_of`` (breakdown detection, ``detect=True`` programs) reads the
-    carried breakdown flag; a flagged state exits the loop early so the
-    host recovery policy can act.  Detection forces the while path even
-    for unbounded solves -- the ~+0.2 ms/iter predicate cost is the
-    price of early exit, paid only when recovery is requested.
-    """
-    if unbounded and bad_of is None:
-        state = jax.lax.fori_loop(0, maxits, iter_body, init_state)
-        return maxits, state, jnp.asarray(True)
-
-    def body(carry):
-        k, state, _ = carry
-        state = iter_body(k, state)
-        done = (jnp.asarray(False) if unbounded else
-                _converged(gamma_of(state), dx_of(state), res_tol, diff_tol))
-        return (k + 1, state, done)
-
-    def cond(carry):
-        go = (~carry[2]) & (carry[0] < maxits)
-        if bad_of is not None:
-            go = go & (~bad_of(carry[1]))
-        return go
-
-    # init_gamma overrides the carried value for the entry test: the
-    # pipelined recurrence carries gamma_prev = inf at entry, but an
-    # already-converged start (r0 = 0) must return x0 in 0 iterations,
-    # not divide 0/0 in the first update.
-    init_done = (jnp.asarray(False) if unbounded else _converged(
-        gamma_of(init_state) if init_gamma is None else init_gamma,
-        dx_of(init_state), res_tol, diff_tol))
-    k, state, done = jax.lax.while_loop(cond, body,
-                                        (jnp.int32(0), init_state,
-                                         init_done))
-    if unbounded:
-        # unbounded semantics: "converged" = ran the full budget without
-        # a detected breakdown (the only early exit on this path)
-        done = ~bad_of(state)
-    return k, state, done
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("unbounded", "needs_diff", "precise",
-                                    "kernels", "detect", "fault", "trace",
-                                    "progress", "precond", "health",
-                                    "state_io"))
+@functools.partial(jax.jit, static_argnames=_CG_STATIC)
 def _cg_program(A: DeviceMatrix, b, x0, res_atol, res_rtol, diff_atol,
                 diff_rtol, maxits, unbounded: bool, needs_diff: bool,
                 precise: bool = False, kernels: str = "xla",
@@ -243,7 +169,8 @@ def _cg_program(A: DeviceMatrix, b, x0, res_atol, res_rtol, diff_atol,
                 progress: int = 0, precond=None, mstate=None,
                 health=None, state_io: bool = False, carry=None,
                 k_offset=None):
-    """Whole classic-CG solve as one XLA program.
+    """Whole classic-CG solve as one XLA program (the loop itself is
+    acg_tpu.recurrence.run_cg's, shared with the mesh tier).
 
     ``precise`` switches the CG scalars' dot products to the compensated
     dot2 (acg_tpu.ops.precision): ~2x working precision for gamma and
@@ -252,285 +179,33 @@ def _cg_program(A: DeviceMatrix, b, x0, res_atol, res_rtol, diff_atol,
     f32 (``_scalar_setup``) and rounds the updated vectors once on
     store, so only half-width bytes cross HBM.
 
-    ``detect`` (the resilience tier) carries a breakdown flag: a
-    non-finite gamma or non-positive (p, Ap) FREEZES the iterate --
-    alpha/beta would otherwise launder the poison into x -- and exits
-    the loop so the host recovery policy can restart from the last good
-    x.  ``fault`` is a static acg_tpu.faults.FaultSpec the injector
-    threads into the loop (None compiles the unchanged program).
+    The static hooks, each compiling none of its ops when off:
+    ``detect`` (the resilience tier) carries a breakdown flag -- a
+    non-finite gamma or non-positive (p, Ap) FREEZES the iterate and
+    exits so the host recovery policy can restart from the last good x;
+    ``fault`` (acg_tpu.faults.FaultSpec) threads the injector into the
+    loop; ``trace`` rides a ``(trace, 4)`` ring of per-iteration
+    ``(||r||^2, alpha, beta, pAp)`` in the carry, fetched once with the
+    result, and ``progress`` emits a host heartbeat every that-many
+    iterations (acg_tpu.telemetry); ``precond`` (a
+    :class:`acg_tpu.precond.PrecondSpec`, state in ``mstate``) makes
+    the loop preconditioned CG, with the convergence test and rnrm2
+    kept on the TRUE residual; ``health`` (a
+    :class:`acg_tpu.health.HealthSpec`) arms the true-residual audit,
+    the stall detector and the ABFT checksum test.
 
-    ``trace`` (the telemetry tier, acg_tpu.telemetry) rides a
-    ``(trace, 4)`` ring buffer of per-iteration ``(||r||^2, alpha,
-    beta, pAp)`` in the loop carry -- recorded device-side, fetched
-    ONCE with the result, no per-iteration host traffic -- and makes
-    the program return ``(CGResult, buffer)``.  ``progress`` emits a
-    host heartbeat every that-many iterations (jax.debug.callback).
-    Both are static: 0 compiles the byte-identical pristine program.
-
-    ``precond`` (a static :class:`acg_tpu.precond.PrecondSpec`) turns
-    the loop into PRECONDITIONED CG: ``mstate`` (the preconditioner
-    state pytree, an ordinary argument) feeds ``z = M^-1 r`` after each
-    residual update, the CG scalar becomes ``gamma = (r, z)``, and the
-    carry grows one extra true-residual scalar ``rr = (r, r)`` so the
-    convergence test (and the reported rnrm2) keep the UNpreconditioned
-    meaning while the telemetry ring records the preconditioned norm.
-    ``None`` compiles the byte-identical unpreconditioned program
-    (pinned in tests/test_hlo_structure.py).
-
-    ``health`` (a static :class:`acg_tpu.health.HealthSpec`) arms the
-    numerical-health tier: every ``health.every`` iterations a
-    ``lax.cond``-guarded audit recomputes the TRUE residual
-    ``b - A x`` through this program's own SpMV and carries the
-    relative gap ``||r_true - r_rec||/||b||`` in a 4-scalar audit
-    vector (returned with the result; an extra ``gap`` ring column
-    when telemetry is also armed); the stagnation/sign detectors and a
-    tripped gap feed the breakdown flag (``detect`` must then be
-    armed).  ``None`` compiles the byte-identical unaudited program.
-    ``health.abft`` additionally arms the Huang-Abraham
-    checksum-protected SpMV: the column checksum ``c = A^T 1`` is
-    computed once at setup through this program's own SpMV and a
-    ``lax.cond``-guarded in-loop test compares ``sum(A p)`` against
-    ``(c, p)`` at the audit cadence -- silent bit-level corruption
-    (``sdc:flip``) detected on device and routed into the breakdown
-    path.
-
-    ``state_io``/``carry`` (the survivability tier, acg_tpu.
-    checkpoint): ``state_io`` makes the program ALSO return the final
-    loop carry ``(r, p, gamma[, rr])`` (x rides the result already),
-    and a non-None ``carry`` of that shape re-enters the recurrence
-    exactly where a previous chunk left it (``x0`` then holds the
-    snapshot iterate; the setup ``r = b - A x0`` is skipped, so the
-    chunked trajectory is ITERATION-IDENTICAL to an uninterrupted
-    run).  ``k_offset`` (chunked dispatches only; None otherwise) is
-    the trajectory iteration this chunk starts at, so the health
-    tier's audit/ABFT cadence stays phased to GLOBAL iteration
-    numbers across chunk boundaries.  Disarmed programs never name
-    any of the three and lower byte-identical code (pinned in
-    tests/test_checkpoint.py).
-
-    The ops carry the device-scope vocabulary in their ``op_name``:
-    ``acg.setup`` (r0 = b - A x0 and the pre-loop dots), ``acg.spmv``
-    (every operator application, set-up and loop), ``acg.dot`` and
-    ``acg.update`` (the loop's dot products and vector updates)."""
-    dtype = b.dtype
-    dot, sdt = _scalar_setup(dtype, precise)
-    store = (lambda v: v.astype(dtype)) if sdt != dtype else (lambda v: v)
-    spmv_ = _spmv_fn(kernels)
-    with jax.named_scope("acg.setup"):
-        bnrm2 = jnp.sqrt(dot(b, b))
-        x0nrm2 = jnp.sqrt(dot(x0, x0))
-        if precond is not None:
-            from acg_tpu.precond import make_apply
-            papply = make_apply(precond, spmv_)
-        if carry is not None:
-            # resume: the provided carry IS the loop state; nothing is
-            # recomputed, so the Krylov recurrence continues exactly
-            if precond is not None:
-                r, p, gamma, rr = carry
-                r0nrm2 = jnp.sqrt(rr)
-            else:
-                r, p, gamma = carry
-                r0nrm2 = jnp.sqrt(gamma)
-        elif precond is not None:
-            r = b - spmv_(A, x0)
-            z0 = papply(mstate, A, r)
-            p = store(z0)
-            gamma = dot(r, z0)
-            rr = dot(r, r)
-            r0nrm2 = jnp.sqrt(rr)
-        else:
-            r = b - spmv_(A, x0)
-            p = r
-            gamma = dot(r, r)
-            r0nrm2 = jnp.sqrt(gamma)
-        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
-    inf = jnp.asarray(jnp.inf, sdt)
-
-    if trace or progress:
-        from acg_tpu import telemetry
-    if health is not None:
-        from acg_tpu import health as _health
-    if health is not None and health.abft:
-        # the column checksum c = A^T 1 (= A 1: symmetric systems),
-        # through THIS program's own SpMV selection -- one extra SpMV
-        # per solve, zero per-check SpMVs
-        cvec = spmv_(A, jnp.ones_like(b)).astype(sdt)
-
-        def dot3(a1, c1, a2, c2, a3, c3):
-            return dot(a1, c1), dot(a2, c2), dot(a3, c3)
-
-    # carry layout: (x, r, p, gamma [, rr] [, dx] [, bad] [, aud]
-    # [, ring]) -- rr (the true residual the convergence test reads)
-    # joins only under precond, dx only under a diff criterion, the
-    # audit vector only under an armed health spec
-    dx_i = 5 if precond is not None else 4
-
-    # dxsqr joins the carry only when a diff criterion is active: every
-    # extra loop-carried scalar measurably slows the TPU loop (~0.1 ms/it)
-    def body(k, state):
-        if trace:
-            buf, state = state[-1], state[:-1]
-        if health is not None:
-            aud, state = state[-1], state[:-1]
-        x, r, p, gamma = state[:4]
-        # NOT the fused dia_spmv_dot: measured in-loop, the in-kernel
-        # (p,t) scalar costs ~15% (1,355 vs 1,589 iters/s interleaved
-        # A/B) -- the opaque kernel boundary forfeits XLA's fusion of
-        # the updates, the same verdict as the fused 6-vector update
-        # (BASELINE.md)
-        t = spmv_(A, p)
-        if fault is not None:
-            t = fault.apply_spmv(t, k)
-        with jax.named_scope("acg.dot"):
-            pdott = dot(p, t)
-        if fault is not None:
-            pdott = fault.apply_dot(pdott, k)
-        with jax.named_scope("acg.update"):
-            if detect:
-                # breakdown BEFORE the updates: a non-finite t/pdott or
-                # an indefiniteness signal must not reach x
-                bad, alpha = _breakdown_guard(gamma, pdott)
-                x = store(jnp.where(bad, x, x + alpha * p))
-                r = store(jnp.where(bad, r, r - alpha * t))
-            else:
-                alpha = gamma / pdott
-                x = store(x + alpha * p)
-                r = store(r - alpha * t)
-        if precond is not None:
-            z = papply(mstate, A, r)
-            if fault is not None:
-                z = fault.apply_precond(z, k)
-            with jax.named_scope("acg.dot"):
-                gamma_next = dot(r, z)
-                rr_next = dot(r, r)
-            with jax.named_scope("acg.update"):
-                beta = gamma_next / gamma
-                p_next = store(z + beta * p)
-            out = (x, r, p_next, gamma_next, rr_next)
-        else:
-            with jax.named_scope("acg.dot"):
-                gamma_next = dot(r, r)
-            with jax.named_scope("acg.update"):
-                beta = gamma_next / gamma
-                p_next = store(r + beta * p)
-            out = (x, r, p_next, gamma_next)
-        if needs_diff:
-            with jax.named_scope("acg.dot"):
-                dx = alpha * alpha * dot(p, p)
-            if detect:
-                # freeze dx too: a zeroed alpha would make the frozen
-                # iteration "satisfy" the diff criterion and launder the
-                # breakdown into a converged exit
-                dx = jnp.where(bad, state[dx_i], dx)
-            out = out + (dx,)
-        fire = None
-        if health is not None:
-            # the health cadence is phased to TRAJECTORY iterations:
-            # chunked dispatches (the checkpoint tier) pass the chunk's
-            # starting iteration so audits fire at the same global
-            # iterations as an uninterrupted run
-            kk = k if k_offset is None else k + k_offset
-
-            # in-loop true-residual audit: b - A x through THIS
-            # program's SpMV, guarded by lax.cond so non-audited
-            # iterations pay only the predicate
-            def compute_gap():
-                return _health.relative_gap(b - spmv_(A, x), r,
-                                            dot, bnrm2, sdt)
-
-            aud, fire = _health.audit_update(aud, health, kk, compute_gap)
-            # residual non-decrease, measured on the scalar the
-            # convergence test reads (preconditioned: the carried rr)
-            prog_now = out[4] if precond is not None else gamma_next
-            prog_prev = state[4] if precond is not None else gamma
-            aud = _health.stall_update(aud, health,
-                                       prog_now < prog_prev)
-            if health.abft:
-                # Huang-Abraham checksum test of this iteration's
-                # t = A p against the precomputed column checksum
-                aud = _health.abft_update(aud, health, kk, t, p, cvec,
-                                          dot3, sdt, t.shape[0])
-        if detect:
-            # a poison that slipped past pdott (e.g. a NaN row of t with
-            # a finite dot) lands in r: flag it one iteration deferred.
-            # Under precond, a NEGATIVE (r, z) is the non-SPD-M signal
-            # (the precond: fault site's deterministic twin)
-            deferred = bad | (~jnp.isfinite(gamma_next))
-            if precond is not None:
-                deferred = deferred | (gamma_next < 0)
-            if health is not None:
-                if precond is None:
-                    # sign anomaly: a negative computed (r, r) is
-                    # arithmetic poison the finite-value guard misses
-                    deferred = deferred | (gamma_next < 0)
-                deferred = deferred | _health.trip(aud, health)
-            out = out + (deferred,)
-        if health is not None:
-            out = out + (aud,)
-        if trace:
-            # record the RAW scalars (a poisoned pdott/gamma_next stays
-            # visible in the window the recovery log quotes); under
-            # precond gamma IS the preconditioned residual norm^2
-            audit_col = (_health.ring_gap(aud, fire, sdt)
-                         if health is not None else None)
-            out = out + (telemetry.ring_record(buf, k, gamma_next, alpha,
-                                               beta, pdott,
-                                               audit=audit_col),)
-        if progress:
-            telemetry.heartbeat(k, gamma_next, progress)
-        return out
-
-    # the audit vector and ring buffer ride LAST in the carry (in that
-    # order) so every existing index (dx, the deferred-bad freeze
-    # reads) is untouched; only the tail accessors below shift
-    init_state = (x0, r, p, gamma)
-    if precond is not None:
-        init_state = init_state + (rr,)
-    init_state = init_state + ((inf,) if needs_diff else ())
-    if detect:
-        init_state = init_state + (jnp.asarray(False),)
-    if health is not None:
-        init_state = init_state + (_health.audit_init(sdt, health),)
-    if trace:
-        init_state = init_state + (telemetry.ring_init(
-            trace, sdt, audit=health is not None),)
-    ntail = (1 if trace else 0) + (1 if health is not None else 0)
-    bad_i = -1 - ntail
-    # the convergence test reads the TRUE residual either way: gamma
-    # itself unpreconditioned, the carried rr under precond
-    conv_i = 4 if precond is not None else 3
-    k, state, done = _iterate(
-        body, init_state, lambda s: s[conv_i], maxits,
-        res_tol, diff_tol,
-        (lambda s: s[dx_i]) if needs_diff else (lambda s: inf),
-        unbounded, bad_of=(lambda s: s[bad_i]) if detect else None)
-    x, r, p, gamma = state[:4]
-    rnrm2sqr = state[4] if precond is not None else gamma
-    dxsqr = state[dx_i] if needs_diff else inf
-    breakdown = state[bad_i] if detect else jnp.asarray(False)
-    # a breakdown flagged on the same iteration the tolerance was met is
-    # convergence, not breakdown: at the f32 floor the (p, Ap) scalar
-    # legitimately rounds to <= 0 once progress is exhausted
-    breakdown = breakdown & ~done
-    res = CGResult(x=x, niterations=k, rnrm2=jnp.sqrt(rnrm2sqr),
-                   r0nrm2=r0nrm2, bnrm2=bnrm2, x0nrm2=x0nrm2,
-                   dxnrm2=jnp.sqrt(dxsqr), converged=done,
-                   breakdown=breakdown)
-    extras = ()
-    if trace:
-        extras = extras + (state[-1],)
-    if health is not None:
-        extras = extras + (state[-2] if trace else state[-1],)
-    if state_io:
-        # the loop carry, strictly last (x rides the result already):
-        # what the checkpoint chunk driver snapshots and threads into
-        # the next dispatch's ``carry``
-        core = (r, p, gamma)
-        if precond is not None:
-            core = core + (state[4],)
-        extras = extras + (core,)
-    return (res,) + extras if extras else res
+    Returns the :class:`CGResult`, followed by the ring, the audit
+    vector and -- under ``state_io`` -- the loop carry ``(r, p, gamma[,
+    rr])``; a ``carry`` of that shape re-enters the recurrence where a
+    chunk left it, and ``k_offset`` keeps the audit cadence phased to
+    trajectory iterations (acg_tpu.checkpoint)."""
+    return _single_cg(
+        A, b, x0, (res_atol, res_rtol, diff_atol, diff_rtol), maxits,
+        pipelined=False, precise=precise, kernels=kernels, fault=fault,
+        precond=precond, mstate=mstate, unbounded=unbounded,
+        needs_diff=needs_diff, detect=detect, trace=trace,
+        progress=progress, health=health, state_io=state_io, carry=carry,
+        k_offset=k_offset)
 
 
 @functools.partial(jax.jit,
@@ -755,11 +430,7 @@ def _cg_fused_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
                     breakdown=jnp.asarray(False))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("unbounded", "needs_diff", "precise",
-                                    "kernels", "detect", "fault", "trace",
-                                    "progress", "precond", "health",
-                                    "state_io"))
+@functools.partial(jax.jit, static_argnames=_CG_STATIC)
 def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
                           diff_atol, diff_rtol, maxits, unbounded: bool,
                           needs_diff: bool, precise: bool = False,
@@ -768,350 +439,24 @@ def _cg_pipelined_program(A: DeviceMatrix, b, x0, res_atol, res_rtol,
                           precond=None, mstate=None, health=None,
                           state_io: bool = False, carry=None,
                           k_offset=None):
-    """Whole pipelined-CG (Ghysels-Vanroose) solve as one XLA program.
-
-    ``detect``/``fault``/``trace``/``progress`` as in
-    :func:`_cg_program`.  The pipelined recurrences are the brittle ones
-    (deep pipelining amplifies rounding -- Cornelis & Vanroose,
-    arXiv:1801.04728), and a poisoned q/w shows up one iteration
-    deferred in the (w, r) reduction: detection here is inherently one
-    iteration stale, like the convergence test.  The telemetry window
-    records the CARRIED gamma = ||r||^2 from before the update (the
-    same one-iteration-stale quantity the convergence test uses) and
-    the alpha denominator in the pAp slot -- exactly the recurrence
-    scalars whose drift the deep-pipelining literature plots.
-
-    ``precond``/``mstate`` arm the PRECONDITIONED pipelined variant
-    (Ghysels-Vanroose's M^-1 formulation, the method arXiv:1801.04728 /
-    1905.06850 actually pipeline): the carry grows ``u = M^-1 r`` and
-    ``q = M^-1 s`` plus the extra ``w/m/n`` recurrences -- one
-    preconditioner apply (``m = M^-1 w``) and one SpMV (``n = A m``)
-    per iteration, both overlapping the fused reduction exactly like
-    the unpreconditioned q = A w.  The fused reduction carries THREE
-    scalars (gamma = (r, u), delta = (w, u), rr = (r, r)) so the mesh
-    tiers keep the single-allreduce property.  ``None`` compiles the
-    byte-identical unpreconditioned program.
-
-    ``health`` (acg_tpu.health.HealthSpec) arms the in-loop
-    true-residual audit + stagnation/sign detectors exactly as in
-    :func:`_cg_program` -- this is the tier the audit matters MOST for:
-    the pipelined recurrences are the ones whose recursively-updated
-    residual drifts from ``b - A x`` (arXiv:1801.04728), and the audit
-    measures that drift with the loop's own SpMV."""
-    dtype = b.dtype
-    dot, sdt = _scalar_setup(dtype, precise)
-    store = (lambda v: v.astype(dtype)) if sdt != dtype else (lambda v: v)
-    spmv_ = _spmv_fn(kernels)
-    with jax.named_scope("acg.setup"):
-        bnrm2 = jnp.sqrt(dot(b, b))
-        x0nrm2 = jnp.sqrt(dot(x0, x0))
-        if precond is not None:
-            from acg_tpu.precond import make_apply
-            papply = make_apply(precond, spmv_)
-        # resume (the survivability tier): a provided carry re-enters
-        # the GV recurrence exactly -- x0 holds the snapshot iterate,
-        # and the carried vectors (incl. w = A-image and the z/t/q
-        # scratch whose recurrences the pipelined variant never
-        # rebuilds) replace the whole setup.  carry layout matches
-        # checkpoint.carry_names
-        c_in = None
-        if carry is not None:
-            c_in = carry
-            if precond is not None:
-                r, rr0 = c_in[0], c_in[9]
-                r0nrm2 = jnp.sqrt(rr0)
-            else:
-                r = c_in[0]
-                r0nrm2 = jnp.sqrt(jnp.maximum(c_in[5], 0))
-        elif precond is not None:
-            r = b - spmv_(A, x0)
-            u0 = store(papply(mstate, A, r))
-            w = spmv_(A, u0)
-            rr0 = dot(r, r)
-            r0nrm2 = jnp.sqrt(rr0)
-        else:
-            r = b - spmv_(A, x0)
-            w = spmv_(A, r)
-            r0nrm2 = jnp.sqrt(dot(r, r))
-        res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
-        diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
-    inf = jnp.asarray(jnp.inf, sdt)
-    zeros = jnp.zeros_like(b)
-    if trace or progress:
-        from acg_tpu import telemetry
-    if health is not None:
-        from acg_tpu import health as _health
-    if health is not None and health.abft:
-        # column checksum through this program's own SpMV (see
-        # _cg_program); the pipelined test verifies q = A w / n = A m
-        cvec = spmv_(A, jnp.ones_like(b)).astype(sdt)
-
-        def dot3(a1, c1, a2, c2, a3, c3):
-            return dot(a1, c1), dot(a2, c2), dot(a3, c3)
-
-    def pbody(k, state):
-        """Preconditioned GV body: carry (x, r, u, w, p, s, q, z,
-        gamma_prev, alpha_prev, rr) -- s is the A-direction (the
-        unpreconditioned t), z the A M^-1 A-direction, q the M^-1
-        A-direction."""
-        if trace:
-            buf, state = state[-1], state[:-1]
-        if health is not None:
-            aud, state = state[-1], state[:-1]
-        x, r, u, w, p, s, q, z, gamma_prev, alpha_prev = state[:10]
-        rr_prev = state[10]
-        # the iteration's three reductions, fused (ONE allreduce on a
-        # mesh): gamma/delta drive the recurrences, rr feeds the true-
-        # residual convergence test (stale by one, like gamma)
-        with jax.named_scope("acg.dot"):
-            gamma = dot(r, u)
-            delta = dot(w, u)
-            rr = dot(r, r)
-        if fault is not None:
-            delta = fault.apply_dot(delta, k)
-        # m = M^-1 w and n = A m overlap the reduction under XLA's
-        # scheduler -- the preconditioned restatement of q = A w
-        m = papply(mstate, A, w)
-        if fault is not None:
-            m = fault.apply_precond(m, k)
-        nvec = spmv_(A, m)
-        if fault is not None:
-            nvec = fault.apply_spmv(nvec, k)
-        with jax.named_scope("acg.update"):
-            beta = gamma / gamma_prev           # inf -> 0 on first iteration
-            denom = delta - beta * (gamma / alpha_prev)
-            if detect:
-                bad, alpha = _breakdown_guard(gamma, denom)
-                # a negative (r, u) is the non-SPD-M signal (precond:
-                # fault twin); the unpreconditioned guard cannot see it
-                bad = bad | (gamma < 0)
-                alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
-            else:
-                alpha = gamma / denom
-            z = store(nvec + beta * z)
-            q = store(m + beta * q)
-            s = store(w + beta * s)
-            p = store(u + beta * p)
-            if detect:
-                x = store(jnp.where(bad, x, x + alpha * p))
-                r = store(jnp.where(bad, r, r - alpha * s))
-                u = store(jnp.where(bad, u, u - alpha * q))
-                w = store(jnp.where(bad, w, w - alpha * z))
-            else:
-                x = store(x + alpha * p)
-                r = store(r - alpha * s)
-                u = store(u - alpha * q)
-                w = store(w - alpha * z)
-        out = (x, r, u, w, p, s, q, z, gamma, alpha, rr)
-        if needs_diff:
-            with jax.named_scope("acg.dot"):
-                dx = alpha * alpha * dot(p, p)
-            if detect:
-                dx = jnp.where(bad, state[11], dx)
-            out = out + (dx,)
-        fire = None
-        if health is not None:
-            kk = k if k_offset is None else k + k_offset
-
-            def compute_gap():
-                return _health.relative_gap(b - spmv_(A, x), r,
-                                            dot, bnrm2, sdt)
-
-            aud, fire = _health.audit_update(aud, health, kk, compute_gap)
-            # progress measured on the fused (r, r) scalar (stale by
-            # one, like the convergence test)
-            aud = _health.stall_update(aud, health, rr < rr_prev)
-            if health.abft:
-                # checksum test of this iteration's n = A m
-                aud = _health.abft_update(aud, health, kk, nvec, m,
-                                          cvec, dot3, sdt,
-                                          nvec.shape[0])
-        if detect:
-            flag = bad
-            if health is not None:
-                flag = flag | _health.trip(aud, health)
-            out = out + (flag,)
-        if health is not None:
-            out = out + (aud,)
-        if trace:
-            # gamma = the PRECONDITIONED residual norm^2 (stale by one,
-            # like the convergence test); alpha denominator in pAp slot
-            audit_col = (_health.ring_gap(aud, fire, sdt)
-                         if health is not None else None)
-            out = out + (telemetry.ring_record(buf, k, gamma, alpha,
-                                               beta, denom,
-                                               audit=audit_col),)
-        if progress:
-            telemetry.heartbeat(k, gamma, progress)
-        return out
-
-    def body(k, state):
-        if trace:
-            buf, state = state[-1], state[:-1]
-        if health is not None:
-            aud, state = state[-1], state[:-1]
-        x, r, w, p, t, z, gamma_prev, alpha_prev = state[:8]
-        # both reductions of the iteration, fused (one allreduce on a mesh)
-        with jax.named_scope("acg.dot"):
-            gamma = dot(r, r)
-            delta = dot(w, r)
-        if fault is not None:
-            delta = fault.apply_dot(delta, k)
-        # SpMV overlaps the allreduce in the reference (cgcuda.c:1750-1790);
-        # under XLA the scheduler owns that overlap.
-        q = spmv_(A, w)
-        if fault is not None:
-            q = fault.apply_spmv(q, k)
-        # the SpMV input, before the 6-vector update rebinds w below
-        # (the ABFT check verifies q against THIS vector)
-        w_in = w
-        with jax.named_scope("acg.update"):
-            beta = gamma / gamma_prev           # inf -> 0 on first iteration
-            denom = delta - beta * (gamma / alpha_prev)
-            if detect:
-                # the alpha denominator plays the (p, Ap) role here;
-                # freeze x/r/w on breakdown (p/t/z are scratch once the
-                # loop exits)
-                bad, alpha = _breakdown_guard(gamma, denom)
-                if health is not None:
-                    # sign anomaly: a negative computed (r, r) is
-                    # arithmetic poison (the finite-value guard misses
-                    # it)
-                    bad = bad | (gamma < 0)
-                    alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
-            else:
-                alpha = gamma / denom
-            # the 6-vector update stays in XLA even under
-            # kernels="pallas": the hand-written fused kernel (ops.
-            # pallas_kernels.fused_pipelined_update) wins in isolation
-            # (~1.35x) but inside the loop it is an opaque call that
-            # forfeits XLA's fusion of the *next* iteration's dots into
-            # these writes -- measured 894 vs 1818 iters/s on the
-            # flagship (BASELINE.md)
-            z = store(q + beta * z)
-            t = store(w + beta * t)
-            p = store(r + beta * p)
-            if detect:
-                x = store(jnp.where(bad, x, x + alpha * p))
-                r = store(jnp.where(bad, r, r - alpha * t))
-                w = store(jnp.where(bad, w, w - alpha * z))
-            else:
-                x = store(x + alpha * p)
-                r = store(r - alpha * t)
-                w = store(w - alpha * z)
-        out = (x, r, w, p, t, z, gamma, alpha)
-        if needs_diff:
-            with jax.named_scope("acg.dot"):
-                dx = alpha * alpha * dot(p, p)
-            if detect:
-                # freeze dx on breakdown (see _cg_program): alpha = 0
-                # must not fake the diff criterion
-                dx = jnp.where(bad, state[8], dx)
-            out = out + (dx,)
-        fire = None
-        if health is not None:
-            kk = k if k_offset is None else k + k_offset
-
-            def compute_gap():
-                return _health.relative_gap(b - spmv_(A, x), r,
-                                            dot, bnrm2, sdt)
-
-            aud, fire = _health.audit_update(aud, health, kk, compute_gap)
-            aud = _health.stall_update(aud, health, gamma < gamma_prev)
-            if health.abft:
-                # checksum test of this iteration's q = A w (w_in: the
-                # pre-update input that produced q)
-                aud = _health.abft_update(aud, health, kk, q, w_in,
-                                          cvec, dot3, sdt, q.shape[0])
-        if detect:
-            flag = bad
-            if health is not None:
-                flag = flag | _health.trip(aud, health)
-            out = out + (flag,)
-        if health is not None:
-            out = out + (aud,)
-        if trace:
-            # the carried gamma (stale by one, like the convergence
-            # test) and the alpha denominator in the pAp slot
-            audit_col = (_health.ring_gap(aud, fire, sdt)
-                         if health is not None else None)
-            out = out + (telemetry.ring_record(buf, k, gamma, alpha,
-                                               beta, denom,
-                                               audit=audit_col),)
-        if progress:
-            telemetry.heartbeat(k, gamma, progress)
-        return out
-
-    # convergence tests the carried gamma = ||r||^2 from *before* the
-    # update -- one iteration stale, the reference's deferred test
-    # (cgcuda.c:1798-1810); saves a fresh dot per iteration.  The
-    # preconditioned carry tests the carried rr (same staleness), so
-    # tolerances keep the true-residual meaning
-    if precond is not None:
-        if c_in is not None:
-            init_state = (x0,) + tuple(c_in) + (
-                (inf,) if needs_diff else ())
-        else:
-            init_state = (x0, r, u0, w, zeros, zeros, zeros, zeros, inf,
-                          inf, rr0) + ((inf,) if needs_diff else ())
-        loop_body = pbody
-        conv_of = lambda s: s[10]
-        dx_of = (lambda s: s[11]) if needs_diff else (lambda s: inf)
-        init_gamma = rr0
-    else:
-        if c_in is not None:
-            init_state = (x0,) + tuple(c_in) + (
-                (inf,) if needs_diff else ())
-            init_gamma = c_in[5]
-        else:
-            init_state = (x0, r, w, zeros, zeros, zeros, inf, inf) + (
-                (inf,) if needs_diff else ())
-            init_gamma = r0nrm2 * r0nrm2
-        loop_body = body
-        conv_of = lambda s: s[6]
-        dx_of = (lambda s: s[8]) if needs_diff else (lambda s: inf)
-    if detect:
-        init_state = init_state + (jnp.asarray(False),)
-    if health is not None:
-        init_state = init_state + (_health.audit_init(sdt, health),)
-    if trace:
-        init_state = init_state + (telemetry.ring_init(
-            trace, sdt, audit=health is not None),)
-    ntail = (1 if trace else 0) + (1 if health is not None else 0)
-    bad_i = -1 - ntail
-    k, state, done = _iterate(
-        loop_body, init_state, conv_of, maxits,
-        res_tol, diff_tol, dx_of,
-        unbounded, init_gamma=init_gamma,
-        bad_of=(lambda s: s[bad_i]) if detect else None)
-    x, r = state[0], state[1]
-    dxsqr = ((state[11] if precond is not None else state[8])
-             if needs_diff else inf)
-    breakdown = state[bad_i] if detect else jnp.asarray(False)
-    rnrm2 = jnp.sqrt(dot(r, r))
-    # the in-loop test is one iteration stale; at the maxits boundary a
-    # solve whose final *fresh* residual meets tolerance must not report
-    # converged=False with a below-tolerance rnrm2 in the same stats block
-    done = jnp.logical_or(done, rnrm2 <= res_tol)
-    # ... and a breakdown whose frozen residual already meets tolerance
-    # is convergence: near the floor the pipelined denominator
-    # legitimately rounds <= 0 (the recurrences' known brittleness)
-    breakdown = breakdown & ~done
-    res = CGResult(x=x, niterations=k, rnrm2=rnrm2, r0nrm2=r0nrm2,
-                   bnrm2=bnrm2, x0nrm2=x0nrm2, dxnrm2=jnp.sqrt(dxsqr),
-                   converged=done, breakdown=breakdown)
-    extras = ()
-    if trace:
-        extras = extras + (state[-1],)
-    if health is not None:
-        extras = extras + (state[-2] if trace else state[-1],)
-    if state_io:
-        # the GV loop carry, strictly last (checkpoint.carry_names
-        # order minus x, which rides the result)
-        core = tuple(state[1:11] if precond is not None
-                     else state[1:8])
-        extras = extras + (core,)
-    return (res,) + extras if extras else res
+    """Whole pipelined-CG (Ghysels-Vanroose) solve as one XLA program,
+    with the hooks of :func:`_cg_program`.  The pipelined recurrences
+    are the brittle ones (deep pipelining amplifies rounding --
+    Cornelis & Vanroose, arXiv:1801.04728), and a poisoned q/w shows up
+    one iteration deferred in the (w, r) reduction: detection here is
+    inherently one iteration stale, like the convergence test -- and
+    the health audit matters most here, since these are the recurrences
+    whose recursively-updated residual drifts from ``b - A x``.
+    ``precond`` arms the preconditioned GV form (three scalars in the
+    one fused reduction); the state_io carry follows
+    checkpoint.carry_names."""
+    return _single_cg(
+        A, b, x0, (res_atol, res_rtol, diff_atol, diff_rtol), maxits,
+        pipelined=True, precise=precise, kernels=kernels, fault=fault,
+        precond=precond, mstate=mstate, unbounded=unbounded,
+        needs_diff=needs_diff, detect=detect, trace=trace,
+        progress=progress, health=health, state_io=state_io, carry=carry,
+        k_offset=k_offset)
 
 
 class JaxCGSolver:
@@ -1175,10 +520,8 @@ class JaxCGSolver:
         self.pipelined = pipelined
         self.precise_dots = precise_dots
         # recurrence selection (acg_tpu.recurrence): classic/pipelined
-        # resolve onto the existing hand-built programs (byte-identical
-        # dispatch -- the builder emission is pinned equal in
-        # tests/test_hlo_structure.py); sstep:S / pipelined:L dispatch
-        # the communication-avoiding builder programs
+        # resolve onto _cg_program / _cg_pipelined_program; sstep:S /
+        # pipelined:L dispatch the communication-avoiding programs
         from acg_tpu.recurrence import parse_algorithm
         self.algo = parse_algorithm(algorithm)
         if self.algo is not None and not self.algo.communication_avoiding:

@@ -151,8 +151,8 @@ def ring_record(buf, k, rnrm2sqr, alpha, beta, pAp, audit=None):
     """Write iteration ``k``'s scalars into slot ``k % capacity``.
     One dynamic_update_slice per iteration -- the documented price of
     telemetry-on (every extra loop-carried array costs; see the
-    jax_cg._cg_program carry notes); disarmed programs compile without
-    any of this.  ``audit`` fills the optional gap column (a ring built
+    recurrence.classic_recurrence carry notes); disarmed programs
+    compile without any of this.  ``audit`` fills the optional gap column (a ring built
     with ``audit=True`` only)."""
     import jax
     import jax.numpy as jnp

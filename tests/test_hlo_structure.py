@@ -457,72 +457,8 @@ def test_explain_sections_append_only():
     assert d["costmodel"]["comm"]["neighbors"] == [{"src": 0, "dst": 1}]
 
 
-# -- recurrence builder (acg_tpu.recurrence): byte-identity + the
-# communication-avoiding collective pins --------------------------------
-
-def _norm_module(txt):
-    """Normalise the ONE permitted difference between builder-emitted
-    and hand-built programs: the module symbol, which StableHLO derives
-    from the jitted wrapper's Python name (`module @jit_<fn>`), not
-    from the traced computation.  Everything after it must match
-    byte-for-byte."""
-    return re.sub(r"module @jit_\w+", "module @jit_PROGRAM", txt,
-                  count=1)
-
-
-def test_builder_emission_byte_identical_single():
-    """The builder's classic/GV-pipelined emission (recurrence.
-    _builder_cg_program, composed from classic_recurrence /
-    pipelined_recurrence over TierOps) lowers BYTE-IDENTICAL StableHLO
-    to the hand-built jax_cg programs -- the proof the recurrence
-    refactor is a no-op for current users (ISSUE 12 acceptance)."""
-    import jax.numpy as jnp
-
-    from acg_tpu import recurrence as rec
-    from acg_tpu.io.generators import poisson2d_coo as _p2
-    from acg_tpu.matrix import SymCsrMatrix as _S
-    from acg_tpu.ops.spmv import device_matrix_from_csr
-    from acg_tpu.solvers import jax_cg as jc
-
-    r, c, v, N = _p2(12)
-    csr = _S.from_coo(N, r, c, v).to_csr()
-    A = device_matrix_from_csr(csr, dtype=jnp.float64)
-    b = jnp.ones(N)
-    x0 = jnp.zeros(N)
-    z = jnp.float64(0.0)
-    a = (A, b, x0, z, jnp.float64(1e-8), z, z, jnp.int32(50))
-    for pipelined in (False, True):
-        hand = (jc._cg_pipelined_program if pipelined
-                else jc._cg_program).lower(
-            *a, unbounded=False, needs_diff=False).as_text()
-        built = rec._builder_cg_program.lower(
-            *a, unbounded=False, needs_diff=False,
-            pipelined=pipelined).as_text()
-        assert _norm_module(built) == _norm_module(hand), \
-            f"builder emission diverged (pipelined={pipelined})"
-
-
-def test_builder_emission_byte_identical_dist(prob):
-    """Dist-tier twin: recurrence.build_dist_program composes the SAME
-    recurrence bodies with DistCGSolver's halo'd SpMV / fused-psum
-    machinery and lowers byte-identical StableHLO to the hand-built
-    shard_map program."""
-    from acg_tpu import recurrence as rec
-
-    for pipelined in (False, True):
-        s = DistCGSolver(prob, pipelined=pipelined)
-        b, x0, la, ga, sidx, gsrc, gval, scnt, rcnt = s.device_args(
-            np.ones(prob.n))
-        tols = jnp.zeros(4)
-        args = (la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0, tols,
-                jnp.int32(5))
-        hand = s._program.lower(*args, unbounded=True,
-                                needs_diff=False).as_text()
-        built = rec.build_dist_program(s).lower(
-            *args, unbounded=True, needs_diff=False).as_text()
-        assert _norm_module(built) == _norm_module(hand), \
-            f"dist builder emission diverged (pipelined={pipelined})"
-
+# -- recurrence builder (acg_tpu.recurrence): the communication-avoiding
+# collective pins ------------------------------------------------------
 
 def _ca_counts(prob, algorithm):
     s = DistCGSolver(prob, algorithm=algorithm)

@@ -88,19 +88,28 @@ def test_apply_bitwise_parity_per_stencil(dtype):
                                 dict(precond="jacobi")])
 def test_solver_trajectory_parity_bitwise(aniso_pair, kw):
     """Tiers whose applies consume/produce LOOP-CARRIED state --
-    classic (the headline bench protocol), s-step, jacobi PCG --
-    produce BITWISE-identical iterates matrix-free vs assembled: the
-    structured apply's per-element products equal the assembled
-    plane products, and nothing fuses across the apply boundary."""
+    classic (the headline bench protocol), s-step, jacobi PCG -- take
+    the same iterations matrix-free vs assembled, with iterates equal
+    to an ulp bound of float64.  Per apply the structured products are
+    bitwise the assembled ones (test_apply_bitwise_parity_per_stencil),
+    but the two are different programs: XLA fuses the generated planes
+    and the stored planes into the loop differently, so they round
+    differently (one ulp from the first iteration on), and CG amplifies
+    that near convergence -- measured on this system 149,488 ulp of
+    max|x| (classic), 34,092 (s-step) and 2.2 (jacobi)."""
     _, A, op = aniso_pair
     b = np.random.default_rng(0).standard_normal(A.nrows)
     crit = StoppingCriteria(maxits=600, residual_rtol=1e-9)
     sa = JaxCGSolver(A, kernels="xla", **kw)
     sm = JaxCGSolver(op, kernels="xla", **kw)
-    xa = sa.solve(b, criteria=crit)
-    xm = sm.solve(b, criteria=crit)
+    xa = np.asarray(sa.solve(b, criteria=crit))
+    xm = np.asarray(sm.solve(b, criteria=crit))
     assert sa.stats.niterations == sm.stats.niterations
-    assert np.array_equal(np.asarray(xa), np.asarray(xm))
+    ulps = {"": 2 ** 20, "sstep:4": 2 ** 18, "jacobi": 16}[
+        str(kw.get("algorithm", kw.get("precond", "")))]
+    np.testing.assert_allclose(
+        xm, xa, rtol=0, atol=ulps * np.finfo(xa.dtype).eps
+        * np.abs(xa).max())
 
 
 @pytest.mark.parametrize("kw", [dict(pipelined=True),

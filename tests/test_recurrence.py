@@ -119,10 +119,17 @@ def test_sstep_host_oracle_trajectory_parity(aniso):
     m = min(len(th), recs.shape[0])
     assert m > 50
     # same recurrence, same arithmetic order: tight relative agreement
-    # (from_ring converts the stored ||r||^2 to norms -- sqrt here too)
-    np.testing.assert_allclose(recs[:m, 0], np.sqrt(th[:m, 0]),
-                               rtol=1e-6)
-    np.testing.assert_allclose(recs[:m, 1], th[:m, 1], rtol=1e-6)
+    # (from_ring converts the stored ||r||^2 to norms -- sqrt here too).
+    # The device program and numpy round differently, and the scalars
+    # are quadratic forms of Gram matrices whose rounding stays at the
+    # scale of the starting residual: a scalar formed at ||r_i|| carries
+    # a relative rounding floor of ulps * ||r_0|| / ||r_i|| (measured:
+    # the last record, at ||r_i|| = 1e-8 ||r_0||, differs by 5.8e-6 in
+    # the norm and 3.1e-6 in alpha; every earlier one by under 6e-7)
+    floor = 2 ** 10 * np.finfo(np.float64).eps * recs[0, 0] / recs[:m, 0]
+    for col, want in ((0, np.sqrt(th[:m, 0])), (1, th[:m, 1])):
+        err = np.abs(recs[:m, col] - want)
+        assert np.all(err <= (1e-6 + floor) * np.abs(want)), col
 
 
 @pytest.mark.parametrize("s", [2, 4, 8])
@@ -415,7 +422,7 @@ def test_refusals(aniso):
     with pytest.raises(ValueError, match="residual criteria"):
         s.solve(aniso["b"],
                 criteria=StoppingCriteria(diff_rtol=1e-6, maxits=10))
-    # classic/pipelined aliases resolve onto the hand-built programs
+    # classic/pipelined aliases resolve onto the classic/pipelined programs
     s = JaxCGSolver(A, algorithm="pipelined")
     assert s.algo is None and s.pipelined
 

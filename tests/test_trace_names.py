@@ -46,30 +46,21 @@ def dia():
     return A
 
 
-def _program(kind):
-    from acg_tpu import recurrence as rec
-    from acg_tpu.solvers import jax_cg as jc
-
-    if kind == "classic":
-        return jc._cg_program, {}
-    if kind == "pipelined":
-        return jc._cg_pipelined_program, {}
-    return rec._builder_cg_program, {"pipelined": kind.endswith("pipelined")}
-
-
-@pytest.mark.parametrize("kind", ["classic", "pipelined", "builder-classic",
-                                  "builder-pipelined"])
+@pytest.mark.parametrize("kind", ["classic", "pipelined"])
 def test_loop_ops_carry_acg_scopes(dia, kind):
     """The compiled single-device program (DIA, interpreted Pallas
     kernel): the loop body's ops sit under acg.spmv, acg.dot and
     acg.update, and the set-up SpMV under acg.setup/acg.spmv."""
-    program, extra = _program(kind)
+    from acg_tpu.solvers import jax_cg as jc
+
+    program = jc._cg_pipelined_program if kind == "pipelined" \
+        else jc._cg_program
     b = jnp.ones(dia.nrows, jnp.float32)
     z = jnp.float32(0.0)
     text = program.lower(
         dia, b, jnp.zeros_like(b), z, jnp.float32(1e-6), z, z,
         jnp.int32(20), unbounded=False, needs_diff=False,
-        kernels="pallas-interpret", **extra).compile().as_text()
+        kernels="pallas-interpret").compile().as_text()
     names = op_names(text)
     assert {"acg.spmv", "acg.dot", "acg.update"} <= scopes_under(names, BODY)
     assert any("acg.setup/acg.spmv/" in n for n in names), sorted(names)
@@ -177,33 +168,48 @@ def test_compile_span_only_with_warmup(tmp_path, warm_solver, warmup,
 
 
 @pytest.fixture(scope="module")
-def mesh_solver():
-    """``DistCGSolver`` over four devices of the CPU mesh: a 16x16
-    Poisson grid, graph-partitioned (the built-in bisection), the
-    ``all_to_all`` halo; warmed so the captures hold no compile."""
+def mesh_prob():
+    """A 16x16 Poisson grid graph-partitioned into four parts (the
+    built-in bisection)."""
     from acg_tpu.graph import partition_matrix
-    from acg_tpu.parallel.dist import DistCGSolver, DistributedProblem
-    from acg_tpu.parallel.mesh import solve_mesh
+    from acg_tpu.parallel.dist import DistributedProblem
     from acg_tpu.partition import partition_rows
 
     r, c, v, n = poisson2d_coo(16)
     csr = SymCsrMatrix.from_coo(n, r, c, v).to_csr()
     part = partition_rows(csr, 4, method="graph", use_metis="never")
-    prob = DistributedProblem.build(
+    return DistributedProblem.build(
         csr, part, 4, dtype=jnp.float32,
         subs=partition_matrix(csr, part, 4), vector_dtype=jnp.float32)
-    solver = DistCGSolver(prob, comm="xla", mesh=solve_mesh(4))
-    b = np.ones(n)
+
+
+def _mesh_solver(prob, pipelined=False):
+    from acg_tpu.parallel.dist import DistCGSolver
+    from acg_tpu.parallel.mesh import solve_mesh
+
+    return DistCGSolver(prob, comm="xla", mesh=solve_mesh(4),
+                        pipelined=pipelined)
+
+
+@pytest.fixture(scope="module")
+def mesh_solver(mesh_prob):
+    """``DistCGSolver`` over four devices of the CPU mesh, the
+    ``all_to_all`` halo; warmed so the captures hold no compile."""
+    solver = _mesh_solver(mesh_prob)
+    b = np.ones(mesh_prob.n)
     crit = StoppingCriteria(maxits=200, residual_rtol=1e-6)
     solver.solve(b, criteria=crit, host_result=False)
     return solver, b, crit
 
 
-def test_mesh_loop_ops_carry_acg_scopes(mesh_solver):
-    """The four-part classic program (shard_map, all_to_all halo): the
-    loop body's ops sit under acg.spmv, acg.halo, acg.dot and
-    acg.update, and the set-up residual under acg.setup."""
-    solver, b, crit = mesh_solver
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_mesh_loop_ops_carry_acg_scopes(mesh_prob, pipelined):
+    """The four-part classic and pipelined programs (shard_map,
+    all_to_all halo): the loop body's ops sit under acg.spmv, acg.halo,
+    acg.dot and acg.update, and the set-up residual under acg.setup."""
+    solver = _mesh_solver(mesh_prob, pipelined)
+    b = np.ones(mesh_prob.n)
+    crit = StoppingCriteria(maxits=200, residual_rtol=1e-6)
     names = op_names(solver.lower_solve(b, criteria=crit)
                      .compile().as_text())
     assert {"acg.spmv", "acg.halo", "acg.dot", "acg.update"} <= \
