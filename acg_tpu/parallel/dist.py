@@ -1181,6 +1181,38 @@ def _replaced_segments(spmv, pdot, b, x0, tols, maxits, unbounded: bool,
             x0nrm2, inf, done, ~jnp.isfinite(gamma_f))
 
 
+def place_operator(solver, irows=None, zeros=None):
+    """Place on ``solver``'s mesh (its ``_sharding``) every solve input
+    that does not depend on b: the stacked local blocks, the ghost block
+    (``irows``, the fused tier's interior row lists, ride its tuple),
+    the halo plan and the neighbour counts, and ``zeros`` (a stacked
+    zero x0) when given.  The mesh solvers call it once and hold what it
+    returns, ``((la, ga, sidx, gsrc, gval, scnt, rcnt), x0 or None)``,
+    so each solve uploads only b and a given x0.  Runs under the host
+    span ``acg:place``, waits for the copies, and bills them to the
+    solver's stats: timing ``place`` and one more
+    ``operator_placements``."""
+    from acg_tpu import telemetry
+    prob = solver.problem
+    put = functools.partial(put_global, sharding=solver._sharding)
+    t0 = time.perf_counter()
+    with telemetry.span("place"):
+        la = jax.tree.map(put, prob.local.arrays)
+        ga = jax.tree.map(put, (prob.ghost.rows, prob.ghost.data,
+                                prob.ghost.cols))
+        if irows is not None:
+            ga = ga + (put(irows),)
+        scnt, rcnt = prob.neighbor_counts()
+        op = (la, ga, put(prob.halo.send_idx), put(prob.halo.ghost_src),
+              put(prob.halo.ghost_valid), put(scnt), put(rcnt))
+        x0 = None if zeros is None else put(zeros)
+        jax.block_until_ready((op, x0))
+    st = solver.stats
+    telemetry.add_timing(st, "place", time.perf_counter() - t0)
+    st.operator_placements += 1
+    return op, x0
+
+
 class DistCGSolver:
     """Whole-solve SPMD CG program over a 1-D mesh of ``nparts`` devices.
 
@@ -1332,6 +1364,9 @@ class DistCGSolver:
         # preconditioner state: host-stacked (jacobi/bjacobi) or device
         # scalars (cheby), built lazily at first solve/lower
         self._mstate = None
+        # the placed operator and zero x0 (place_operator), held from
+        # the first solve/lower like the preconditioner state
+        self._held = None
         # numerical-health tier (acg_tpu.health): static spec baked
         # into the compiled SPMD program; refusals mirror JaxCGSolver's
         if health is not None:
@@ -1849,7 +1884,7 @@ class DistCGSolver:
 
     def _interior_rows(self) -> np.ndarray:
         """Cached stacked interior row lists (the fused tier's split;
-        host numpy, placed by device_args like the halo plan)."""
+        host numpy, placed with the operator like the halo plan)."""
         if getattr(self, "_irows", None) is None:
             self._irows = interior_border_split(self.problem)
         return self._irows
@@ -1951,36 +1986,40 @@ class DistCGSolver:
         return np.dtype(np.float32 if self.replace_every
                         else self.problem.vdtype)
 
+    def _held_operator(self):
+        """``(operator leaves, zero x0)`` on the mesh, placed at the
+        first call (:func:`place_operator`) and held for every later
+        solve, lower and profile of this solver."""
+        if self._held is None:
+            prob = self.problem
+            # the interior row lists ride the ghost-block tuple (the
+            # split SpMV consumes both row sets together); the pytree-
+            # prefix shard specs cover the longer tuple unchanged
+            irows = self._interior_rows() if self.kernels == "fused" else None
+            self._held = place_operator(
+                self, irows, np.zeros((prob.nparts, prob.nmax_owned),
+                                      dtype=self._solve_dtype()))
+        return self._held
+
     def device_args(self, b_global: np.ndarray,
                     x0: np.ndarray | None = None):
-        """Scatter + place every solve input on the mesh (the upload
-        stage of ``acgsolvercuda_init``, ``cgcuda.c:143-332``); shared
-        by :meth:`solve` and the per-op profiler.
+        """Every solve input on the mesh (the upload stage of
+        ``acgsolvercuda_init``, ``cgcuda.c:143-332``): b and a given x0
+        scattered into the stacked layout and put, beside the operator
+        and zero x0 held since the first call; shared by :meth:`solve`
+        and the per-op profiler.
 
         Under ``replace_every`` the outer iteration owns b/x0 in f32
         (scattering them to bf16 would bake a u_bf16 backward error
         into every replaced residual)."""
         prob = self.problem
         dtype = self._solve_dtype()
+        op, zeros = self._held_operator()
         put = functools.partial(put_global, sharding=self._sharding)
         b = put(prob.scatter(np.asarray(b_global), dtype=dtype))
-        x0 = put(prob.scatter(np.asarray(x0), dtype=dtype)
-                 if x0 is not None
-                 else np.zeros((prob.nparts, prob.nmax_owned), dtype=dtype))
-        la = jax.tree.map(put, prob.local.arrays)
-        ga = jax.tree.map(put, (prob.ghost.rows, prob.ghost.data,
-                                prob.ghost.cols))
-        if self.kernels == "fused":
-            # the interior row lists ride the ghost-block tuple (the
-            # split SpMV consumes both row sets together); the pytree-
-            # prefix shard specs cover the longer tuple unchanged
-            ga = ga + (put(self._interior_rows()),)
-        sidx = put(prob.halo.send_idx)
-        gsrc = put(prob.halo.ghost_src)
-        gval = put(prob.halo.ghost_valid)
-        scnt_np, rcnt_np = prob.neighbor_counts()
-        return (b, x0, la, ga, sidx, gsrc, gval,
-                put(scnt_np), put(rcnt_np))
+        x0 = (put(prob.scatter(np.asarray(x0), dtype=dtype))
+              if x0 is not None else zeros)
+        return (b, x0) + op
 
     def lower_solve(self, b_global, x0=None, criteria=None):
         """Lower (but do not run) the EXACT whole-solve SPMD program this
@@ -2314,6 +2353,9 @@ class DistCGSolver:
             # pristine program serves every clean solve
             program = self._program_for(fault)
 
+            # the operator crosses to the mesh once (acg:place, first
+            # solve only); the transfer phase then holds b and x0
+            self._held_operator()
             t_xfer = time.perf_counter()
             with telemetry.annotate("transfer"):
                 b, x0, la, ga, sidx, gsrc, gval, scnt, rcnt = \
@@ -2772,6 +2814,7 @@ class DistCGSolver:
             telemetry.record_event(st, "fault-armed",
                                    f"{fault0.site}:{fault0.mode}"
                                    f"@{fault0.iteration}")
+        self._held_operator()
         t_xfer = time.perf_counter()
         with telemetry.annotate("transfer"):
             dev = self.device_args(b_global, x0)

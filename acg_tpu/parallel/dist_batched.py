@@ -36,7 +36,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from acg_tpu.errors import AcgError, ErrorCode, NotConvergedError
 from acg_tpu.ops.spmv import acc_dtype
-from acg_tpu.parallel.dist import DistributedProblem
+from acg_tpu.parallel.dist import DistributedProblem, place_operator
 from acg_tpu.parallel.mesh import PARTS_AXIS, solve_mesh
 from acg_tpu.parallel.reductions import make_pdot_cols, make_pdotk_cols
 from acg_tpu.parallel.multihost import get_global, put_global
@@ -170,6 +170,8 @@ class BatchedDistCGSolver:
         self.last_trace = None
         self._inner1 = None
         self._programs: dict = {}
+        # the placed operator (place_operator), held from the first call
+        self._op = None
 
     # -- B=1 delegation ----------------------------------------------------
 
@@ -461,7 +463,16 @@ class BatchedDistCGSolver:
             out[:, j] = prob.gather(st[:, :, j])
         return out
 
+    def _held_operator(self):
+        """The operator leaves on the mesh, placed at the first call
+        (:func:`~acg_tpu.parallel.dist.place_operator`) and held."""
+        if self._op is None:
+            self._op, _ = place_operator(self)
+        return self._op
+
     def device_args(self, B_global, x0=None):
+        """B's columns and a given x0 scattered and put, beside the held
+        operator; the zero x0 is put per call (its width is B's)."""
         prob = self.problem
         dtype = np.dtype(prob.vdtype)
         put = functools.partial(put_global, sharding=self._sharding)
@@ -473,15 +484,7 @@ class BatchedDistCGSolver:
                     if x0 is not None
                     else np.zeros((prob.nparts, prob.nmax_owned,
                                    Bg.shape[1]), dtype=dtype))
-        la = jax.tree.map(put, prob.local.arrays)
-        ga = jax.tree.map(put, (prob.ghost.rows, prob.ghost.data,
-                                prob.ghost.cols))
-        sidx = put(prob.halo.send_idx)
-        gsrc = put(prob.halo.ghost_src)
-        gval = put(prob.halo.ghost_valid)
-        scnt_np, rcnt_np = prob.neighbor_counts()
-        return (b, x0_st, la, ga, sidx, gsrc, gval,
-                put(scnt_np), put(rcnt_np))
+        return (b, x0_st) + self._held_operator()
 
     def lower_solve(self, B_global, x0=None, criteria=None):
         """Lower (don't run) the dispatched program -- the HLO-pin
@@ -546,6 +549,7 @@ class BatchedDistCGSolver:
             return self._solve_ckpt(Bg, x0, crit, raise_on_divergence,
                                     warmup, host_result)
         from acg_tpu import telemetry
+        self._held_operator()
         t_xfer = time.perf_counter()
         with telemetry.annotate("transfer"):
             dev = self.device_args(Bg, x0)

@@ -165,6 +165,10 @@ class SolverStats:
     # misprediction ratio) -- the self-correction feedback the planner
     # consults on replan.  Appends strictly last
     plan: dict = dataclasses.field(default_factory=dict)
+    # mesh solvers (parallel.dist.place_operator): how many times the
+    # partitioned operator was placed on the mesh -- once per solver,
+    # however many solves follow
+    operator_placements: int = 0
 
     def to_dict(self) -> dict:
         """Machine-readable twin of :meth:`fwrite` -- the ``stats`` key
@@ -215,6 +219,7 @@ class SolverStats:
             "slo": dict(self.slo),
             "batch": dict(self.batch),
             "plan": dict(self.plan),
+            "operator_placements": self.operator_placements,
         }
         if self.trace is not None:
             d["trace"] = self.trace.to_dict()

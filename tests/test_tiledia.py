@@ -299,3 +299,47 @@ def test_solve_route_counts_tiles_segments_and_fill():
         int((t < ntiles).sum()) for t in seg_tile)
     assert got["spmv_fill"] == pytest.approx(planes.size / nnz)
     assert 1.0 < got["spmv_fill"] <= TILEDIA_FILL_LIMIT
+
+
+@pytest.mark.parametrize("given_x0", [False, True])
+@pytest.mark.parametrize("tier", ["auto", "fused", "batched"])
+def test_operator_placed_once_per_solver(tier, given_x0):
+    """A mesh solver places its operator (blocks, ghost block, halo plan,
+    counts, zero x0) once and holds it: two solves with different b on
+    one solver are bitwise those of two fresh solvers, ``device_args``
+    hands back the same operator arrays each time, the stats count one
+    placement, and a given x0 is still the one the solve starts from."""
+    from acg_tpu.parallel.dist_batched import BatchedDistCGSolver
+
+    csr = poisson(32)
+    prob = graph_problem(csr, 4)
+    rng = np.random.default_rng(7)
+    cols = 2 if tier == "batched" else 1
+    shape = (prob.n, cols) if cols > 1 else (prob.n,)
+    bs = [rng.standard_normal(shape) for _ in range(2)]
+    x0 = rng.standard_normal(shape) if given_x0 else None
+    crit = StoppingCriteria(maxits=6, residual_rtol=0.0)
+
+    def make():
+        if tier == "batched":
+            return BatchedDistCGSolver(prob)
+        return DistCGSolver(prob, kernels=tier)
+
+    s = make()
+    held = [s.device_args(b, x0) for b in bs]
+    xs = [s.solve(b, x0=x0, criteria=crit) for b in bs]
+    held.append(s.device_args(bs[0], x0))
+    for b, x in zip(bs, xs):
+        np.testing.assert_array_equal(x, make().solve(b, x0=x0,
+                                                      criteria=crit))
+    assert not np.array_equal(xs[0], xs[1])
+    first = jax.tree.leaves(held[0][2:])
+    for dev in held[1:]:
+        assert all(a is c for a, c in zip(jax.tree.leaves(dev[2:]), first))
+    if tier != "batched" and not given_x0:
+        assert all(dev[1] is held[0][1] for dev in held)
+    assert s.stats.operator_placements == 1
+    assert s.stats.to_dict()["operator_placements"] == 1
+    assert s.stats.timings["place"] > 0
+    x0nrm2 = 0.0 if x0 is None else np.linalg.norm(x0, axis=0).max()
+    assert s.stats.x0nrm2 == pytest.approx(x0nrm2, rel=1e-12)

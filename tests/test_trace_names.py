@@ -251,6 +251,28 @@ def test_mesh_solve_host_spans_in_order(tmp_path, mesh_solver, host_result):
     assert first["acg:solve"][1] <= first["acg:fetch"][0]
 
 
+def test_mesh_solver_places_operator_once(tmp_path, mesh_prob):
+    """A fresh ``DistCGSolver``'s first solve places the operator under
+    one ``acg:place`` span inside ``acg:prepare`` and before
+    ``acg:transfer``; the next solve holds no ``acg:place``."""
+    solver = _mesh_solver(mesh_prob)
+    b = np.ones(mesh_prob.n)
+    crit = StoppingCriteria(maxits=200, residual_rtol=1e-6)
+    first = capture_spans(tmp_path / "first", lambda: solver.solve(
+        b, criteria=crit, host_result=False))
+    place = [(s, e) for name, s, e in first if name == "acg:place"]
+    assert len(place) == 1, first
+    (ps, pe), = place
+    prep = [(s, e) for name, s, e in first if name == "acg:prepare"][0]
+    xfer = [(s, e) for name, s, e in first if name == "acg:transfer"][0]
+    assert prep[0] <= ps and pe <= prep[1]
+    assert pe <= xfer[0]
+    again = capture_spans(tmp_path / "again", lambda: solver.solve(
+        b, criteria=crit, host_result=False))
+    assert "acg:place" not in {name for name, _, _ in again}
+    assert solver.stats.operator_placements == 1
+
+
 def test_spans_keep_the_status_phase(warm_solver):
     """The solve's sub-spans do not move the status document's phase:
     only pipeline phases (transfer, solve) reach the observatory."""
