@@ -144,22 +144,26 @@ class SolverSnapshot:
 
 # the carry leaves that are psum'd scalars (mesh tiers: replicated,
 # not sharded) -- everything else is a per-part vector
-SCALAR_LEAVES = frozenset({"gamma", "alpha", "rr"})
+SCALAR_LEAVES = frozenset({"gamma", "alpha", "rr", "gmax", "pending"})
 
 
-def carry_names(pipelined: bool, precond: bool) -> tuple:
+def carry_names(pipelined: bool, precond: bool,
+                replaces: bool = False) -> tuple:
     """The canonical order of the loop-carry leaves a snapshot stores
     (x first, then the recurrence vectors, then the scalars) -- ONE
     layout shared by the snapshot writer, the resume reconstruction,
     and every tier's ``state_io`` program outputs, so the single- and
-    multi-part tiers' snapshots stay field-compatible."""
+    multi-part tiers' snapshots stay field-compatible.  ``replaces``
+    (recurrence.replaces) adds the residual replacement's window: the
+    largest ||r||^2 since the last replacement and the pending flag."""
     if not pipelined:
         names = ("x", "r", "p", "gamma")
         return names + (("rr",) if precond else ())
     if precond:
         return ("x", "r", "u", "w", "p", "s", "q", "z",
                 "gamma", "alpha", "rr")
-    return ("x", "r", "w", "p", "t", "z", "gamma", "alpha")
+    return (("x", "r", "w", "p", "t", "z", "gamma", "alpha")
+            + (("gmax", "pending") if replaces else ()))
 
 
 def ca_carry_names(kind: str) -> tuple:

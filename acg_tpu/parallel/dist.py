@@ -854,7 +854,7 @@ def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
         from acg_tpu.ops.pallas_kernels import dia_spmv, tiledia_spmv
 
     def dist_spmv(x_loc, la, ga, sidx, gsrc, gval, scnt, rcnt,
-                  k=None, pidx=None):
+                  k=None, pidx=None, after=None):
         with jax.named_scope("acg.spmv"):
             if use_pallas and local_block.format == "tiledia":
                 y = tiledia_spmv(*la, x_loc, local_block.offsets,
@@ -869,7 +869,8 @@ def make_dist_spmv(prob: "DistributedProblem", comm: str, interpret: bool,
             if comm == "dma":
                 ghost = halo_exchange_dma(x_loc, sidx, gsrc, gval,
                                           scnt, rcnt,
-                                          axis, interpret=interpret)
+                                          axis, interpret=interpret,
+                                          after=after)
             else:
                 ghost = halo_exchange(x_loc, sidx, gsrc, axis)
             if fault is not None and k is not None:
@@ -989,7 +990,7 @@ def make_dist_spmv_overlapped(prob: "DistributedProblem", comm: str,
                           preferred_element_type=adt).astype(x.dtype)
 
     def dist_spmv(x_loc, la, ga, sidx, gsrc, gval, scnt, rcnt,
-                  k=None, pidx=None):
+                  k=None, pidx=None, after=None):
         grows, gdata, gcols, irows = ga
         # 1. issue the halo exchange FIRST: nothing below depends on it
         #    until the border finish, so the scheduler can run the
@@ -1001,7 +1002,8 @@ def make_dist_spmv_overlapped(prob: "DistributedProblem", comm: str,
             if comm == "dma":
                 ghost = halo_exchange_dma(x_loc, sidx, gsrc, gval,
                                           scnt, rcnt, axis,
-                                          interpret=interpret)
+                                          interpret=interpret,
+                                          after=after)
             else:
                 ghost = halo_exchange(x_loc, sidx, gsrc, axis)
         # 2. interior rows: zero ghost dependencies, free to overlap
@@ -1528,6 +1530,7 @@ class DistCGSolver:
         from acg_tpu.recurrence import TierOps, run_cg
         prob = self.problem
         pipelined = self.pipelined
+        nrep_out = self._replaces()
         replace_every = self.replace_every
         replace_restart = self.replace_restart
         axis = PARTS_AXIS
@@ -1567,7 +1570,8 @@ class DistCGSolver:
         # replicate -- shared by shard_body's state_io outputs, the
         # shard_map specs, and the chunk driver's snapshot writer
         from acg_tpu.checkpoint import SCALAR_LEAVES, carry_names
-        c_names = carry_names(pipelined, precond_spec is not None)[1:]
+        c_names = carry_names(pipelined, precond_spec is not None,
+                              nrep_out)[1:]
 
         def shard_body(la, ga, sidx, gsrc, gval, scnt, rcnt, b, x0,
                        tols, maxits, mstate=None, unbounded=False,
@@ -1604,6 +1608,16 @@ class DistCGSolver:
                 return dist_spmv(x, la, ga, sidx, gsrc, gval, scnt, rcnt,
                                  k=k, pidx=pidx)
 
+            # the one-sided puts: an exchange that must follow another
+            # waits for a reduction (halo_exchange_dma's ``after``); the
+            # all_to_all is a collective and needs none
+            spmv_after = None
+            if self.comm == "dma" and prob.halo.has_ghosts \
+                    and not single_shard:
+                def spmv_after(x, s, k=None):
+                    return dist_spmv(x, la, ga, sidx, gsrc, gval, scnt,
+                                     rcnt, k=k, pidx=pidx, after=s)
+
             def ldot(a, c):
                 return jnp.dot(a, c, preferred_element_type=sdt)
 
@@ -1637,7 +1651,8 @@ class DistCGSolver:
                           store=store, sdt=sdt,
                           dotk=make_pdotk(psum, ldot, sdt, precise),
                           precond=papply, pidx=pidx, n=int(prob.n),
-                          leader=leader, what="dist-cg")
+                          leader=leader, what="dist-cg",
+                          spmv_after=spmv_after)
             # every scalar the loop tests is psum'd, so `done` and the
             # breakdown flag are identical on every shard and the while
             # predicates agree across the mesh
@@ -1648,6 +1663,10 @@ class DistCGSolver:
             out = (res.x[None], res.niterations, res.rnrm2, res.r0nrm2,
                    res.bnrm2, res.x0nrm2, res.dxnrm2, res.converged,
                    res.breakdown)
+            if nrep_out:
+                # the replacement counter (a psum'd predicate's count,
+                # replicated) rides right after the nine result leaves
+                out = out + (res.nreplacements,)
             if state_io:
                 # the final loop carry, strictly last: vector leaves
                 # re-stack the parts axis, psum'd scalars stay replicated
@@ -1691,7 +1710,7 @@ class DistCGSolver:
         # the telemetry ring (psum'd scalars) and the audit vector
         # (psum'd gap) are replicated
         out_specs = (pspec,) + (rspec,) * (
-            8 + (1 if self.trace else 0)
+            8 + (1 if nrep_out else 0) + (1 if self.trace else 0)
             + (1 if self.health_spec is not None else 0))
         # the state_io carry: vector leaves shard like x, psum'd
         # scalars replicate (checkpoint.carry_names order)
@@ -2401,10 +2420,11 @@ class DistCGSolver:
             # rspec output -> fully replicated: every process holds a
             # complete copy, np.asarray reads the local one
             return telemetry.ConvergenceTrace.from_ring(
-                np.asarray(out[9]), int(out[1]),
+                np.asarray(out[9 + nrep_out]), int(out[1]),
                 solver=self._solver_name())
 
         hl = self.health_spec is not None
+        nrep_out = self._replaces()
 
         def attempt_aud(out):
             """The replicated audit vector (rides LAST, after the
@@ -2419,6 +2439,7 @@ class DistCGSolver:
                 jax.block_until_ready(out[0])
         with telemetry.span("fetch"):
             niter = int(out[1])
+            nrep = int(out[9]) if nrep_out else 0
             broke = detect and bool(out[8])
         first_norms = None
         # first note_audit resets the summary, later attempts merge
@@ -2507,6 +2528,7 @@ class DistCGSolver:
                     out = program(*args, **kwargs)
                     jax.block_until_ready(out[0])
                     niter += int(out[1])
+                    nrep += int(out[9]) if nrep_out else 0
                     continue
                 if driver.on_breakdown(k_done):
                     x_next = out[0]
@@ -2540,6 +2562,7 @@ class DistCGSolver:
                     out = program(*args, **kwargs)
                     jax.block_until_ready(out[0])
                     niter += int(out[1])
+                    nrep += int(out[9]) if nrep_out else 0
                     continue
                 can_host = (pol is not None and pol.fallback_host
                             and prob.owned_parts is None
@@ -2596,6 +2619,7 @@ class DistCGSolver:
             st.nsolves += 1
             st.niterations = niter
             st.ntotaliterations += niter
+            st.nreplacements = nrep
             if hl:
                 from acg_tpu import health as health_mod
                 health_mod.note_audit(st, attempt_aud(out),
@@ -2613,6 +2637,16 @@ class DistCGSolver:
             raise NotConvergedError(
                 f"{niter} iterations, residual {st.rnrm2:.3e}")
         return x
+
+    def _replaces(self) -> bool:
+        """Whether this solver's program is run_cg's (not the
+        communication-avoiding or replace_every programs) and replaces
+        residuals (recurrence.replaces): its counter then rides right
+        after the nine result leaves, and its window in the carry."""
+        from acg_tpu.recurrence import replaces
+        return (self.algo is None and not self.replace_every
+                and replaces(self.pipelined, self.problem.vdtype,
+                             self.precond_spec is not None))
 
     def _crash_refusal(self) -> None:
         """``crash:exit`` fires from the checkpoint chunk driver between
@@ -2829,10 +2863,12 @@ class DistCGSolver:
         fixed = (la, ga, sidx, gsrc, gval, scnt, rcnt, b)
         hl = self.health_spec is not None
         tr = self.trace
+        nrep_out = self._replaces()
         pc_kind = (str(self.precond_spec)
                    if self.precond_spec is not None else None)
         names = ckpt_mod.carry_names(self.pipelined,
-                                     self.precond_spec is not None)
+                                     self.precond_spec is not None,
+                                     nrep_out)
         ncore = len(names) - 1
         scalar = ckpt_mod.SCALAR_LEAVES
         put = functools.partial(put_global, sharding=self._sharding)
@@ -2842,10 +2878,15 @@ class DistCGSolver:
         def to_dev(arrs):
             """Host snapshot arrays -> placed carry leaves (vectors
             scattered over the mesh, scalars as plain device scalars)."""
-            return tuple(
-                jnp.asarray(arrs[nm], dtype=sdt) if nm in scalar
-                else put(np.asarray(arrs[nm], dtype=dtype))
-                for nm in names[1:])
+            def leaf(nm):
+                a = np.asarray(arrs[nm])
+                if nm not in scalar:
+                    return put(a.astype(dtype))
+                # the replacement's pending flag stays a flag
+                return jnp.asarray(a, dtype=sdt if a.dtype.kind == "f"
+                                   else a.dtype)
+
+            return tuple(leaf(nm) for nm in names[1:])
 
         def to_host(x_st, core):
             arrs = {"x": np.asarray(get_global(x_st))}
@@ -2860,12 +2901,14 @@ class DistCGSolver:
             out = program(*fixed, x_cur, tols, jnp.int32(m),
                           carry=carry, k_offset=koff, **kwargs)
             core = out[-ncore:]
-            ring = out[9] if tr else None
-            aud = out[9 + (1 if tr else 0)] if hl else None
-            return out[:9], ring, aud, core
+            at = 9 + nrep_out
+            ring = out[at] if tr else None
+            aud = out[at + (1 if tr else 0)] if hl else None
+            return out[:at], ring, aud, core
 
         # -- resume reconstruction ------------------------------------
         consumed = 0          # trajectory iterations (incl. pre-crash)
+        nrep = 0
         executed = 0          # iterations THIS process actually ran
         resumed_from = None
         carry = None
@@ -2975,6 +3018,7 @@ class DistCGSolver:
                 jax.block_until_ready(res[0])
                 t_end = time.time()
                 k_chunk = int(res[1])
+                nrep += int(res[9]) if nrep_out else 0
                 if k_chunk > 0:
                     # measured s/iteration sizes the next chunk under
                     # the wall-clock cadence (cfg.chunk_for)
@@ -3142,6 +3186,7 @@ class DistCGSolver:
         telemetry.add_timing(st, "solve", t_solve)
         st.nsolves += 1
         st.niterations = executed
+        st.nreplacements = nrep
         st.ntotaliterations += executed
         st.bnrm2, st.x0nrm2, st.r0nrm2 = first_norms
         st.rnrm2 = float(res[2])

@@ -35,6 +35,16 @@ Synchronisation details:
     proves it has consumed the previous exchange's buffers.  Interpret
     mode has no barrier primitive and skips it (its DMA emulation
     rendezvouses on fresh buffers, so the hazard does not exist there).
+  * The barrier proves a neighbour entered this exchange, not that
+    every neighbour has left the one before.  Classic CG always has a
+    psum between two exchanges and runs on four v5e chips; the
+    pipelined program, whose set-up runs two exchanges with nothing
+    global between them, stopped in its first solve there.  A caller
+    whose exchange follows another passes a reduction's scalar as
+    ``after``: the send buffer then depends on it, so every part has
+    left the last exchange before any enters this one (compiled for a
+    described v5e 2x2, ``tests/test_tpu_compile.py``; not yet run on
+    chips).
   * Receive-plane rows of non-neighbours are never written; the unpack
     masks padding ghost slots (``ghost_valid``) so those uninitialised
     rows are never observed.
@@ -280,7 +290,8 @@ def halo_exchange_dma(x_loc: jax.Array, send_idx: jax.Array,
                       ghost_src: jax.Array, ghost_valid: jax.Array,
                       send_counts: jax.Array, recv_counts: jax.Array,
                       axis: str = PARTS_AXIS,
-                      interpret: bool | None = None) -> jax.Array:
+                      interpret: bool | None = None,
+                      after: jax.Array | None = None) -> jax.Array:
     """Exchange ghost values by one-sided remote DMA; call inside
     `shard_map` over ``axis``.
 
@@ -290,11 +301,18 @@ def halo_exchange_dma(x_loc: jax.Array, send_idx: jax.Array,
     and ``ghost_valid``, which masks padding ghost slots whose gathers
     would otherwise read receive-plane rows no neighbour ever wrote
     (uninitialised device memory on real TPUs).
+
+    ``after``, a scalar of a mesh-wide reduction, makes the send buffer
+    depend on it (``after * 0`` is not folded for floats), so the kernel
+    starts only once every part has joined that reduction (the
+    synchronisation details above).
     """
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     with jax.named_scope("acg.halo"):
         sendbuf = x_loc[send_idx]                # pack (halo.cu:41-54)
+        if after is not None:
+            sendbuf = sendbuf + (after * 0).astype(sendbuf.dtype)
         recvbuf = _exchange(sendbuf, send_counts, recv_counts, axis,
                             interpret)
         ghost = recvbuf.reshape(-1)[ghost_src]   # unpack (halo.cu:94-107)

@@ -166,8 +166,10 @@ def _setup_probe_costs(solver, b, x0) -> dict | None:
     ``_cg_pipelined_program`` setup (norms, initial residual, and for
     the pipelined variant ``w = A r`` plus the epilogue's fresh ``(r,
     r)``); the leftover ``maximum``/``sqrt`` scalars are noise at vector
-    sizes.  Only the direct classic/pipelined single-chip tiers have a
-    probe: the replacement/fused tiers restructure the loop, and the
+    sizes.  Where the pipelined recurrence replaces residuals
+    (:func:`_body_copies`) the probe adds one replacement's four SpMVs.
+    Only the direct classic/pipelined single-chip tiers have a probe:
+    the replace_every/fused tiers restructure the loop, and the
     shard_map program's setup has no standalone form."""
     import jax
     import jax.numpy as jnp
@@ -184,6 +186,7 @@ def _setup_probe_costs(solver, b, x0) -> dict | None:
     spmv_ = _spmv_fn(kern)
     dot, _sdt = _scalar_setup(b.dtype, solver.precise_dots)
     pipelined = solver.pipelined
+    replaced = _body_copies(solver, b) > 1
 
     @jax.jit
     def probe(A, b, x0):
@@ -195,6 +198,9 @@ def _setup_probe_costs(solver, b, x0) -> dict | None:
         if pipelined:
             w = spmv_(A, r)
             out = out + (w, dot(r, r))
+        if replaced:
+            rr = b - spmv_(A, x0)
+            out = out + (rr, spmv_(A, rr), spmv_(A, spmv_(A, w)))
         return out
 
     try:
@@ -204,11 +210,22 @@ def _setup_probe_costs(solver, b, x0) -> dict | None:
     return cost_analysis(compiled)
 
 
+def _body_copies(solver, b) -> int:
+    """Copies of the loop body in the solve program: two where the
+    pipelined recurrence replaces residuals (its first leg peeled,
+    ``recurrence._iterate``), else one."""
+    from acg_tpu.recurrence import replaces
+
+    return 2 if replaces(solver.pipelined, b.dtype,
+                         solver.precond_spec is not None) else 1
+
+
 def per_iteration_cost(solver, b, x0=None, criteria=None,
                        whole: dict | None = None) -> dict | None:
     """Compiler-derived per-iteration flops/bytes for the direct
     single-chip tiers: HloCostAnalysis counts a while/fori body ONCE, so
-    per-iteration = cost(whole program) - cost(setup probe).  None
+    per-iteration = cost(whole program) - cost(setup probe), halved
+    where the body is compiled twice (:func:`_body_copies`).  None
     where either half is unavailable.
 
     Counting conventions differ from the analytic counters BY DESIGN --
@@ -237,10 +254,11 @@ def per_iteration_cost(solver, b, x0=None, criteria=None,
     if setup is None:
         return None
     out: dict = {}
+    copies = _body_copies(solver, b)
     for k in ("flops", "bytes_accessed", "transcendentals"):
         w, s = whole["cost"].get(k), setup.get(k)
         if w is not None and s is not None:
-            out[k] = max(w - s, 0.0)
+            out[k] = max(w - s, 0.0) / copies
     return out or None
 
 
@@ -290,14 +308,22 @@ def attach(stats, analysis: dict | None, ledger: dict | None = None,
 
 def analytic_bytes_per_iteration(nnz: int, n: int, idx_bytes: float,
                                  mat_itemsize: int, vec_itemsize: int,
-                                 pipelined: bool) -> float:
+                                 pipelined: bool,
+                                 precond: bool = False) -> float:
     """OUR analytic HBM traffic per CG iteration: matrix reads in the
     matrix storage dtype (+ per-nonzero index bytes) plus the vector
     passes of the loop (15 classic / 21 pipelined -- the pass count
     implied by the measured 335 MB/iter f32 flagship, BASELINE.md) in
-    the vector storage dtype.  ``bench._our_bytes_per_iter`` delegates
-    here so the harness and the explain tier cannot drift apart."""
+    the vector storage dtype, two more pipelined where the recurrence
+    replaces residuals (f32 vectors, unpreconditioned: its fused
+    reduction also reads s and p, recurrence.replaces).
+    ``bench._our_bytes_per_iter`` delegates here so the harness and the
+    explain tier cannot drift apart."""
+    from acg_tpu.recurrence import replaces
+
     passes = 21 if pipelined else 15
+    if replaces(pipelined, np.dtype(f"f{vec_itemsize}"), precond):
+        passes += 2
     return nnz * (mat_itemsize + idx_bytes) + passes * n * vec_itemsize
 
 
@@ -566,17 +592,19 @@ def _explain_tier(name, solver, b, csr, K, bw_gbs, dispatch_s, on_tpu,
         if hasattr(A, "matfree_apply"):
             matfree_tables = int(A.table_bytes())
     flops_it_analytic = cg_flops_per_iteration(nnz, n, solver.pipelined)
+    spec = getattr(solver, "precond_spec", None)
     if matfree_tables is not None:
         # matrix-free operator tier: the roofline's matrix-bytes term
         # goes to (nearly) zero -- the apply reads the O(grid-side)
         # coefficient tables, not nnz * itemsize of planes.  Flops are
         # unchanged (the multiply-adds still happen)
         bytes_it_analytic = (analytic_bytes_per_iteration(
-            0, n, 0.0, 0, vec_b, solver.pipelined) + matfree_tables)
+            0, n, 0.0, 0, vec_b, solver.pipelined, spec is not None)
+            + matfree_tables)
     else:
         bytes_it_analytic = analytic_bytes_per_iteration(
-            nnz, n, idx_b, mat_b, vec_b, solver.pipelined)
-    spec = getattr(solver, "precond_spec", None)
+            nnz, n, idx_b, mat_b, vec_b, solver.pipelined,
+            spec is not None)
     if spec is not None:
         # reclassify the roofline for PCG: one M^-1 apply per iteration
         # joins both analytic models (the compiler-derived numbers see

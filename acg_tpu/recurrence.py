@@ -229,6 +229,11 @@ class TierOps:
     n: int = 0               # global unknown count (the ABFT scale)
     leader: object = None    # traced bool: the part that speaks for all
     what: str = "cg"         # the heartbeat's label
+    # (v, s, k) -> spmv(v, k) whose halo exchange starts only once the
+    # global scalar s is reduced: the mesh's one-sided puts (comm="dma")
+    # need a collective between two exchanges (_spmv_after); None
+    # elsewhere
+    spmv_after: callable = None
 
     def gram(self, V):
         """Global Gram matrix of the stacked basis V ((m, n) rows):
@@ -316,7 +321,7 @@ def _breakdown_guard(gamma, denom):
 
 def _iterate(iter_body, init_state, gamma_of, maxits, res_tol,
              diff_tol, dx_of, unbounded: bool, init_gamma=None,
-             bad_of=None):
+             bad_of=None, pause_of=None, resume=None):
     """Run the CG iteration to maxits (traced scalar) or convergence.
 
     ``iter_body(k, state)`` receives the 0-based iteration index -- the
@@ -344,8 +349,17 @@ def _iterate(iter_body, init_state, gamma_of, maxits, res_tol,
     host recovery policy can act.  Detection forces the while path even
     for unbounded solves -- the predicate's cost is the price of early
     exit, paid only when recovery is requested.
+
+    ``pause_of`` / ``resume`` split the loop into legs: an inner loop
+    runs iterations until ``pause_of(state)`` holds (or the loop is
+    done), and while it holds and the loop is not done ``resume(state)``
+    runs once and the next leg follows -- the pipelined residual
+    replacement, kept out of the per-iteration body.  The first leg is
+    peeled so that ``resume`` needs no conditional (replace_pending);
+    the loop body is compiled twice.  A paused loop takes the while path
+    even when unbounded.
     """
-    if unbounded and bad_of is None:
+    if unbounded and bad_of is None and pause_of is None:
         state = jax.lax.fori_loop(0, maxits, iter_body, init_state)
         return maxits, state, jnp.asarray(True)
 
@@ -369,36 +383,53 @@ def _iterate(iter_body, init_state, gamma_of, maxits, res_tol,
     init_done = (jnp.asarray(False) if unbounded else _converged(
         gamma_of(init_state) if init_gamma is None else init_gamma,
         dx_of(init_state), res_tol, diff_tol))
-    k, state, done = jax.lax.while_loop(cond, body,
-                                        (jnp.int32(0), init_state,
-                                         init_done))
+    carry = (jnp.int32(0), init_state, init_done)
+    if pause_of is None:
+        k, state, done = jax.lax.while_loop(cond, body, carry)
+    else:
+        def leg(carry):
+            return jax.lax.while_loop(lambda c: cond(c) & ~pause_of(c[1]),
+                                      body, carry)
+
+        def resumed(carry):
+            k, state, done = carry
+            return leg((k, resume(state), done))
+
+        k, state, done = jax.lax.while_loop(
+            lambda c: cond(c) & pause_of(c[1]), resumed, leg(carry))
     if unbounded:
         # unbounded semantics: "converged" = ran the full budget without
         # a detected breakdown (the only early exit on this path)
-        done = ~bad_of(state)
+        done = (jnp.asarray(True) if bad_of is None
+                else ~bad_of(state))
     return k, state, done
 
 
-def _split_tail(state, trace: int, health):
-    """``(ring, audit, core)`` of a loop carry: the audit vector and the
-    ring buffer ride LAST (in that order), so every core index -- dx,
-    the deferred-bad flag -- stays where it is."""
-    buf = aud = None
+def _split_tail(state, trace: int, health, replace=None):
+    """``(ring, audit, replacement, core)`` of a loop carry: the
+    replacement triple, the audit vector and the ring buffer ride LAST
+    (in that order), so every core index -- dx, the deferred-bad flag
+    -- stays where it is."""
+    buf = aud = rep = None
     if trace:
         buf, state = state[-1], state[:-1]
     if health is not None:
         aud, state = state[-1], state[:-1]
-    return buf, aud, state
+    if replace is not None:
+        rep, state = state[-3:], state[:-3]
+    return buf, aud, rep, state
 
 
 def _close_tail(ops: TierOps, out, k, aud, fire, buf, ring, trace: int,
-                progress: int, health):
-    """Append the audit vector and this iteration's ring record to a
-    body's output, and emit the heartbeat.  ``ring`` holds the four
-    RAW recorded scalars (a poisoned one stays visible in the window
-    the recovery log quotes); the first is the residual norm^2 the
-    heartbeat prints.  Every recorded scalar is a global reduction, so
-    on a mesh the ring is replicated and one part speaks for all."""
+                progress: int, health, rep=()):
+    """Append the replacement triple, the audit vector and this
+    iteration's ring record to a body's output, and emit the heartbeat.
+    ``ring`` holds the four RAW recorded scalars (a poisoned one stays
+    visible in the window the recovery log quotes); the first is the
+    residual norm^2 the heartbeat prints.  Every recorded scalar is a
+    global reduction, so on a mesh the ring is replicated and one part
+    speaks for all."""
+    out = out + tuple(rep)
     if health is not None:
         out = out + (aud,)
     if trace:
@@ -438,7 +469,7 @@ def classic_recurrence(ops: TierOps, *, needs_diff: bool = False,
     dx_i = len(carry_names(False, precond))
 
     def body(k, state):
-        buf, aud, state = _split_tail(state, trace, health)
+        buf, aud, _, state = _split_tail(state, trace, health)
         x, r, p, gamma = state[:4]
         # NOT the fused dia_spmv_dot: an in-kernel (p, t) scalar was
         # measured ~15% slower in-loop in the project's early rounds,
@@ -513,9 +544,102 @@ def classic_recurrence(ops: TierOps, *, needs_diff: bool = False,
     return body
 
 
+# -- residual replacement of the pipelined recurrence --------------------
+#
+# In f32 the Ghysels-Vanroose recurrence stalls: the rounding errors of
+# its auxiliary recurrences (w = A r, s = A p, z = A s) are committed
+# while the vectors are large and stay while they shrink, so the gaps
+# b - A x - r, A p - s, A r - w grow relative to r until the recursive
+# residual stagnates near 2e-5 and the true one drifts above it (2D
+# Poisson, PERF.md; upstream runs f64 and never meets this).  The cure
+# is residual replacement (Cools, Yetkin, Agullo, Giraud & Vanroose,
+# SIAM J. Matrix Anal. Appl. 39(1), 2018, section 4): r, w, s, z are
+# recomputed from x and p through the tier's own SpMV.  What the CPU
+# prototype (float32 numpy and this loop, 2D Poisson n = 16-2048, the
+# anisotropic n = 32 family) fixed:
+#
+# * when: each time ||r|| has fallen by the factor REPLACE_DROP below
+#   its largest value since the last replacement (or the start) --
+#   Sleijpen & van der Vorst's reliable updating (Computing 56, 1996).
+#   A drop of 0.1 measured 0.95-1.07e-6 true relative residual at
+#   1.00-1.06x classic's iterations with five replacements at rtol
+#   1e-6; 0.03 left 3-4e-6, and 0.01 missed 1e-5 or took 1.9x the
+#   iterations.  Gap-bound tests replace later: van der Vorst & Ye's
+#   (SIAM J. Sci. Comput. 22(5), 2000) stops once eps ||A|| ||x||
+#   exceeds sqrt(eps) ||r||, for f32 2D Poisson from ||r|| ~ 4e-4 ||b||
+#   on, and an onset at ||r|| < sqrt(eps) ||r0|| let the anisotropic
+#   family stall at 1.4e-3 where this rule converges in 158 iterations
+#   (classic: 152);
+# * the scalars: the GV alpha and beta assume the local orthogonality
+#   that a replaced residual breaks (its error eps ||A|| ||x|| is 1e-2
+#   of ||r|| near rtol 1e-6 in f32), and with them no replacement
+#   schedule converged.  Where the rule is armed, alpha and beta take
+#   their Hestenes-Stiefel forms (Hestenes & Stiefel, J. Res. NBS 49,
+#   1952), alpha = (r, p) / (p, A p), beta = -(r, A p') / (p', A p')
+#   for the previous direction p', every inner product expanded over
+#   the carried vectors with A p' = s:
+#     beta     = -(r, s) / (p', s)
+#     (p, A p) = (r, w) + beta (2 (r, s) + beta (p', s))
+#     (r, p)   = (r, r) + beta (r, p')
+#   -- five scalars in the ONE fused reduction, still one SpMV an
+#   iteration.
+#
+# The rule is the storage dtype's: f32 vectors (f32 and the mixed tier)
+# replace; f64 keeps the GV recurrence bit for bit (its gaps stay ~1e-9
+# of f32's), and the bf16 and preconditioned forms keep it too (bf16's
+# contract is the replace_every classic tier).  It fires on every decade
+# of an f32 residual, however small the gap: a system that converges in
+# a few dozen iterations (2D Poisson n = 16) replaces too.
+REPLACE_DROP = {np.dtype(np.float32): 0.1}
+
+
+def replaces(pipelined: bool, dtype, precond: bool = False) -> bool:
+    """Whether the CG loop over ``dtype`` vectors runs the residual
+    replacement (and its program returns the counter): the one
+    decision that run_cg, the mesh frame and the cost model read."""
+    return pipelined and not precond and np.dtype(dtype) in REPLACE_DROP
+
+
+@dataclasses.dataclass(frozen=True)
+class Replacement:
+    """What the pipelined body needs to replace: the drop factor, the
+    fresh ``(r, w, s, z)`` of an iterate ``(x, p)`` through the tier's
+    SpMV, and the squared stop tolerance (no replacement on the
+    iteration that meets it)."""
+
+    drop: float
+    fresh: callable
+    tol2: object
+
+
+def replace_pending(replace: Replacement, state, at: int):
+    """A pipelined loop state with its marked replacement carried out:
+    ``r, w, s, z`` recomputed from x and p in the device scope
+    ``acg.replace`` -- four SpMVs -- the count raised and the flag
+    cleared.  ``at`` indexes the carry's ``(gmax, nrep, pending)``.
+
+    ``_iterate`` calls it only where the pending flag holds, as the
+    body of a loop whose predicate reads that flag (a global scalar, so
+    uniform across a mesh), between legs of the iteration -- not under
+    a ``lax.cond`` and not in the loop body: a conditional there, in
+    the body or between the legs, made XLA stream the carried vectors
+    through HBM on every iteration (the compiled TPU program at 2048^2
+    gained 85-151 MB of temporaries; on a TPU v5e an iteration took
+    0.63 ms under an in-body ``lax.cond``, 0.25 ms without), where
+    conditional-free legs keep all six in VMEM as the unreplaced
+    recurrence does."""
+    x, r, w, p, s, z = state[:6]
+    with jax.named_scope("acg.replace"):
+        r, w, s, z = replace.fresh(x, p)
+    gmax, nrep, _ = state[at:at + 3]
+    return ((x, r, w, p, s, z) + tuple(state[6:at])
+            + (gmax, nrep + 1, jnp.asarray(False)) + tuple(state[at + 3:]))
+
+
 def pipelined_recurrence(ops: TierOps, *, needs_diff: bool = False,
                          detect: bool = False, fault=None, trace: int = 0,
-                         progress: int = 0, health=None, audit=None):
+                         progress: int = 0, health=None, audit=None,
+                         replace: Replacement | None = None):
     """Ghysels-Vanroose pipelined CG as a loop body, with the fused
     6-vector update of the reference's cooperative kernel
     (``cg-kernels-cuda.cu:187-269``).  ONE fused reduction per
@@ -530,25 +654,43 @@ def pipelined_recurrence(ops: TierOps, *, needs_diff: bool = False,
     the A M^-1 A-direction -- one preconditioner apply ``m = M^-1 w``
     and one SpMV ``n = A m`` per iteration, and THREE scalars in the
     fused reduction (gamma = (r, u), delta = (w, u), rr = (r, r)).
-    Then the dx / bad / audit / ring tail as in classic_recurrence.
+    Then the dx / bad / replacement / audit / ring tail as in
+    classic_recurrence.
+
+    ``replace`` (f32 storage, :func:`replaces`) arms residual
+    replacement (the block comment above): five fused scalars, the
+    Hestenes-Stiefel alpha and beta, and after the update the drop
+    test.  The tail triple ``(gmax, nrep, pending)`` carries the
+    largest ||r||^2 since the last replacement, the replacement count
+    and the test's verdict; :func:`replace_pending` recomputes the
+    marked ``r, w, s, z`` between two legs of the loop.
 
     gamma is the carried ||r||^2 (or rr) from BEFORE the update: the
     convergence test, the detection and the telemetry record are all
     one iteration stale, the reference's deferred test
-    (``cgcuda.c:1798-1810``).  The ring records the alpha denominator
-    in the pAp slot -- the recurrence scalars whose drift the
-    deep-pipelining literature plots (arXiv:1801.04728)."""
+    (``cgcuda.c:1798-1810``); after a replacement the test reads the
+    replaced residual.  The ring records the alpha denominator in the
+    pAp slot -- the recurrence scalars whose drift the deep-pipelining
+    literature plots (arXiv:1801.04728)."""
     precond = ops.precond is not None
     store = ops.store
     dx_i = len(carry_names(True, precond))
+    if replace is not None and precond:
+        raise ValueError("the residual replacement covers the "
+                         "unpreconditioned pipelined recurrence")
 
     def body(k, state):
-        buf, aud, state = _split_tail(state, trace, health)
+        buf, aud, rep, state = _split_tail(state, trace, health, replace)
         with jax.named_scope("acg.dot"):
             if precond:
                 x, r, u, w, p, s, q, z, gamma_prev, alpha_prev, rr_prev = \
                     state[:11]
                 gamma, delta, rr = ops.dotk((r, u), (w, u), (r, r))
+            elif replace is not None:
+                x, r, w, p, s, z, gamma_prev, alpha_prev = state[:8]
+                u = r
+                gamma, delta, rs, ps, rp = ops.dotk(
+                    (r, r), (w, r), (r, s), (p, s), (r, p))
             else:
                 x, r, w, p, s, z, gamma_prev, alpha_prev = state[:8]
                 u = r
@@ -558,22 +700,32 @@ def pipelined_recurrence(ops: TierOps, *, needs_diff: bool = False,
         # m is the SpMV input (w itself unpreconditioned); the ABFT test
         # verifies n = A m against it after the update rebinds w
         m = _apply_precond(ops, fault, w, k) if precond else w
-        n = ops.spmv(m, k)
+        # delta reads w, which the last exchange produced: the fused
+        # reduction lies between the two exchanges (TierOps.spmv_after)
+        n = (ops.spmv(m, k) if ops.spmv_after is None
+             else ops.spmv_after(m, delta, k))
         with jax.named_scope("acg.update"):
-            beta = gamma / gamma_prev           # inf -> 0 on first iteration
-            denom = delta - beta * (gamma / alpha_prev)
+            if replace is not None:
+                # p = s = 0 before the first iteration: beta = 0 there
+                beta = jnp.where(ps != 0, -rs / ps, jnp.zeros_like(ps))
+                denom = delta + beta * (2 * rs + beta * ps)
+                num = gamma + beta * rp
+            else:
+                beta = gamma / gamma_prev       # inf -> 0 on first iteration
+                denom = delta - beta * (gamma / alpha_prev)
+                num = gamma
             if detect:
                 # the alpha denominator plays the (p, Ap) role; freeze
                 # the iterate on breakdown (p/s/z are scratch once the
                 # loop exits).  A negative gamma is the non-SPD-M signal
                 # under precond and arithmetic poison under the health
                 # tier -- the finite-value guard cannot see either
-                bad, alpha = _breakdown_guard(gamma, denom)
+                bad, alpha = _breakdown_guard(num, denom)
                 if precond or health is not None:
                     bad = bad | (gamma < 0)
                     alpha = jnp.where(bad, jnp.zeros_like(alpha), alpha)
             else:
-                alpha = gamma / denom
+                alpha = num / denom
             # the 6-vector update stays in XLA even under
             # kernels="pallas": the hand-written fused kernel (ops.
             # pallas_kernels.fused_pipelined_update) won in isolation but
@@ -598,6 +750,16 @@ def pipelined_recurrence(ops: TierOps, *, needs_diff: bool = False,
                 if precond:
                     u = store(u - alpha * q)
                 w = store(w - alpha * z)
+        if replace is not None:
+            # mark the replacement; replace_pending (between legs of
+            # the loop, _iterate) carries it out before iteration k + 1
+            gmax, nrep, _ = rep
+            gmax = jnp.maximum(gmax, gamma)
+            drop2 = jnp.asarray(replace.drop * replace.drop, gamma.dtype)
+            go = (gamma < drop2 * gmax) & (gamma >= replace.tol2)
+            if detect:
+                go = go & ~bad
+            rep = (jnp.where(go, jnp.zeros_like(gmax), gmax), nrep, go)
         if precond:
             out = (x, r, u, w, p, s, q, z, gamma, alpha, rr)
         else:
@@ -620,9 +782,19 @@ def pipelined_recurrence(ops: TierOps, *, needs_diff: bool = False,
             out = out + (flag,)
         return _close_tail(ops, out, k, aud, fire, buf,
                            (gamma, alpha, beta, denom), trace, progress,
-                           health)
+                           health, rep or ())
 
     return body
+
+
+def _spmv_after(ops: TierOps, v, u):
+    """``ops.spmv(v)`` where its exchange must follow the one ``u`` came
+    from: on a tier with ``spmv_after`` the exchange waits for the
+    global ``(u, u)``, a reduction every part joins only once it has
+    left that exchange; elsewhere no reduction is added."""
+    if ops.spmv_after is None:
+        return ops.spmv(v)
+    return ops.spmv_after(v, ops.dot(u, u))
 
 
 def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
@@ -666,7 +838,7 @@ def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
         x0nrm2 = jnp.sqrt(dot(x0, x0))
         if carry is not None:
             # resume: the carry IS the loop state; nothing is recomputed
-            init = (x0,) + tuple(carry)
+            init = (x0,) + tuple(carry[:ncore - 1])
             g0 = carry[conv_i - 1]
             r0nrm2 = jnp.sqrt(jnp.maximum(g0, 0)
                               if pipelined and not precond else g0)
@@ -684,15 +856,27 @@ def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
                 r0nrm2 = jnp.sqrt(g0)
             elif precond:
                 u0 = store(ops.precond(r))
-                w = ops.spmv(u0)
+                w = _spmv_after(ops, u0, u0)
                 g0 = dot(r, r)
                 r0nrm2 = jnp.sqrt(g0)
             else:
-                w = ops.spmv(r)
+                w = _spmv_after(ops, r, r)
                 r0nrm2 = jnp.sqrt(dot(r, r))
         res_tol = jnp.maximum(res_atol, res_rtol * r0nrm2)
         diff_tol = jnp.maximum(diff_atol, diff_rtol * x0nrm2)
     inf = jnp.asarray(jnp.inf, sdt)
+    replace = None
+    if replaces(pipelined, b.dtype, precond):
+        def fresh(x, p):
+            # each exchange follows a reduction of what the last one
+            # produced (x: the loop's; p's chain is independent of r's)
+            r = store(b - _spmv_after(ops, x, x))
+            w = store(_spmv_after(ops, r, r))
+            s = store(_spmv_after(ops, p, w))
+            return r, w, s, store(_spmv_after(ops, s, s))
+
+        replace = Replacement(REPLACE_DROP[np.dtype(b.dtype)], fresh,
+                              res_tol * res_tol)
     if pipelined and carry is None:
         zeros = jnp.zeros_like(b)
         init = ((x0, r, u0, w) + (zeros,) * 4 + (inf, inf, g0) if precond
@@ -710,6 +894,15 @@ def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
     init = init + ((inf,) if needs_diff else ())
     if detect:
         init = init + (jnp.asarray(False),)
+    rep_at = len(init)
+    if replace is not None:
+        # (largest ||r||^2 since the last replacement, replacements,
+        # pending); a resumed chunk continues the window and a pending
+        # replacement of the chunk before (carry_names' gmax, pending)
+        # and counts its own replacements
+        gmax, pending = ((jnp.zeros((), sdt), jnp.asarray(False))
+                         if carry is None else carry[ncore - 1:])
+        init = init + (gmax, jnp.int32(0), pending)
     if health is not None:
         init = init + (_health.audit_init(sdt, health),)
     if trace:
@@ -734,16 +927,21 @@ def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
                                       ops.dotk, sdt, ops.n)
         return aud, fire
 
-    make_body = pipelined_recurrence if pipelined else classic_recurrence
-    body = make_body(ops, needs_diff=needs_diff, detect=detect,
-                     fault=fault, trace=trace, progress=progress,
-                     health=health, audit=audit)
-    bad_i = -1 - (1 if trace else 0) - (1 if health is not None else 0)
+    hooks = dict(needs_diff=needs_diff, detect=detect, fault=fault,
+                 trace=trace, progress=progress, health=health,
+                 audit=audit)
+    body = (pipelined_recurrence(ops, replace=replace, **hooks)
+            if pipelined else classic_recurrence(ops, **hooks))
+    # the tail after the core: dx, bad, the replacement triple, the
+    # audit vector, the ring
+    bad_i = rep_at - 1
     k, state, done = _iterate(
         body, init, lambda s: s[conv_i], maxits, res_tol, diff_tol,
         (lambda s: s[ncore]) if needs_diff else (lambda s: inf),
         unbounded, init_gamma=init_gamma,
-        bad_of=(lambda s: s[bad_i]) if detect else None)
+        bad_of=(lambda s: s[bad_i]) if detect else None,
+        pause_of=(lambda s: s[rep_at + 2]) if replace is not None else None,
+        resume=lambda s: replace_pending(replace, s, rep_at))
     x = state[0]
     dxsqr = state[ncore] if needs_diff else inf
     breakdown = state[bad_i] if detect else jnp.asarray(False)
@@ -761,14 +959,18 @@ def run_cg(ops: TierOps, b, x0, tols, maxits, *, pipelined: bool,
         rnrm2 = jnp.sqrt(state[conv_i])
     res = CGResult(x=x, niterations=k, rnrm2=rnrm2, r0nrm2=r0nrm2,
                    bnrm2=bnrm2, x0nrm2=x0nrm2, dxnrm2=jnp.sqrt(dxsqr),
-                   converged=done, breakdown=breakdown)
+                   converged=done, breakdown=breakdown,
+                   nreplacements=(state[rep_at + 1] if replace is not None
+                                  else None))
     extras = ()
     if trace:
         extras = extras + (state[-1],)
     if health is not None:
         extras = extras + (state[-2] if trace else state[-1],)
     if state_io:
-        extras = extras + (tuple(state[1:ncore]),)
+        window = ((state[rep_at], state[rep_at + 2])
+                  if replace is not None else ())
+        extras = extras + (tuple(state[1:ncore]) + window,)
     return res, extras
 
 

@@ -119,7 +119,7 @@ def _scalar_setup(dtype, precise: bool):
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=["x", "niterations", "rnrm2", "r0nrm2",
                                 "bnrm2", "x0nrm2", "dxnrm2", "converged",
-                                "breakdown"],
+                                "breakdown", "nreplacements"],
                    meta_fields=[])
 @dataclasses.dataclass
 class CGResult:
@@ -139,6 +139,15 @@ class CGResult:
     dxnrm2: jax.Array
     converged: jax.Array
     breakdown: jax.Array
+    # residual replacements of the pipelined recurrence (f32 storage,
+    # recurrence.replaces); None -- no output -- elsewhere
+    nreplacements: jax.Array | None = None
+
+
+def _replacements(res) -> int:
+    """The replacement count of a solve program's result (0 where the
+    recurrence never replaces)."""
+    return 0 if res.nreplacements is None else int(res.nreplacements)
 
 
 _CG_STATIC = ("unbounded", "needs_diff", "precise", "kernels", "detect",
@@ -598,8 +607,10 @@ class JaxCGSolver:
                     "precise_dots or a RefinedSolver there")
             if pipelined:
                 raise ValueError("replace_every implements classic CG "
-                                 "(the pipelined recurrence carries w=Ar, "
-                                 "which replacement would invalidate)")
+                                 "over bf16 vectors (the pipelined "
+                                 "recurrence replaces its own residuals "
+                                 "over f32 vectors, recomputing w = A r, "
+                                 "s = A p and z = A s with r)")
             if precise_dots:
                 raise ValueError("replace_every computes its scalars in "
                                  "plain f32 (the bf16 tier's scalar "
@@ -1180,6 +1191,7 @@ class JaxCGSolver:
                 jax.block_until_ready(res.x)
         with telemetry.span("fetch"):
             niter = int(res.niterations)
+            nrep = _replacements(res)
             broke = detect and bool(res.breakdown)
         first_norms = None
         # the first note_audit of this solve resets the health summary;
@@ -1289,6 +1301,7 @@ class JaxCGSolver:
                     res, tbuf, aud = run(*args, **kwargs)
                     jax.block_until_ready(res.x)
                     niter += int(res.niterations)
+                    nrep += _replacements(res)
                     continue
                 pol = self.recovery
                 if (pol is not None and pol.fallback_host
@@ -1344,6 +1357,7 @@ class JaxCGSolver:
             st.nsolves += 1
             st.niterations = niter
             st.ntotaliterations += niter
+            st.nreplacements = nrep
             if hl and aud is not None:
                 # the health: section's audit summary + the acg_health_*
                 # metrics + (threshold exceeded) the accuracy_degraded
@@ -1556,8 +1570,11 @@ class JaxCGSolver:
             names = ckpt_mod.ca_carry_names(self.algo.kind)
             solver_name = f"cg-{self.algo.kind}"
         else:
-            names = ckpt_mod.carry_names(self.pipelined,
-                                         self.precond_spec is not None)
+            from acg_tpu.recurrence import replaces
+            pc = self.precond_spec is not None
+            names = ckpt_mod.carry_names(
+                self.pipelined, pc, replaces(self.pipelined, b_dev.dtype,
+                                             pc))
             solver_name = ("cg-pipelined" if self.pipelined else "cg")
 
         if self.algo is not None:
@@ -1596,6 +1613,7 @@ class JaxCGSolver:
         # -- resume reconstruction ------------------------------------
         consumed = 0          # trajectory iterations (incl. pre-crash)
         executed = 0          # iterations THIS process actually ran
+        nrep = 0
         resumed_from = None
         carry = None
         x_cur = x0_dev
@@ -1708,6 +1726,7 @@ class JaxCGSolver:
                 jax.block_until_ready(res.x)
                 t_end = time.time()
                 k_chunk = int(res.niterations) - adv_in
+                nrep += _replacements(res)
                 if k_chunk > 0:
                     # measured s/iteration sizes the next chunk under
                     # the wall-clock cadence (cfg.chunk_for)
@@ -1875,6 +1894,7 @@ class JaxCGSolver:
         st.nsolves += 1
         st.niterations = executed
         st.ntotaliterations += executed
+        st.nreplacements = nrep
         st.bnrm2, st.x0nrm2, st.r0nrm2 = first_norms
         st.rnrm2 = float(res.rnrm2)
         st.dxnrm2 = float(res.dxnrm2)

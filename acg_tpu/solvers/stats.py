@@ -169,6 +169,9 @@ class SolverStats:
     # partitioned operator was placed on the mesh -- once per solver,
     # however many solves follow
     operator_placements: int = 0
+    # pipelined CG over f32 vectors (recurrence.replaces): the
+    # residual replacements of the last solve
+    nreplacements: int = 0
 
     def to_dict(self) -> dict:
         """Machine-readable twin of :meth:`fwrite` -- the ``stats`` key
@@ -220,6 +223,7 @@ class SolverStats:
             "batch": dict(self.batch),
             "plan": dict(self.plan),
             "operator_placements": self.operator_placements,
+            "nreplacements": self.nreplacements,
         }
         if self.trace is not None:
             d["trace"] = self.trace.to_dict()
@@ -272,6 +276,9 @@ class SolverStats:
         p(f"  residual 2-norm: {self.rnrm2:.15g}")
         p(f"  difference in solution iterates 2-norm: {self.dxnrm2:.15g}")
         p(f"  floating-point exceptions: {fexcept_str(*self.fexcept_arrays)}")
+        # like the resilience lines: only a solve that replaced shows it
+        if self.nreplacements:
+            p(f"  residual replacements: {self.nreplacements:,}")
         # resilience lines appear only when something happened, so the
         # report stays byte-identical to the reference's on clean solves
         if (self.nbreakdowns or self.nrestarts or self.nfallbacks

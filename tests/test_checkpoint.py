@@ -147,6 +147,9 @@ def test_carry_names_layouts():
                                         "gamma", "alpha")
     assert carry_names(True, True)[-1] == "rr"
     assert len(carry_names(True, True)) == 11
+    # the residual replacement's window (recurrence.replaces)
+    assert carry_names(True, False, True) == (
+        carry_names(True, False) + ("gmax", "pending"))
 
 
 def test_agree_seq_single_process_is_free():
@@ -217,6 +220,51 @@ def test_dist8_chunk_parity_and_resume(system, prob8, tmp_path,
     x_rs = s2.solve(b, criteria=CRIT)
     assert snap.iteration + s2.stats.niterations == it_ref
     assert np.allclose(x_rs, x_ref, rtol=1e-7, atol=1e-10)
+
+
+@pytest.fixture(scope="module")
+def prob8_f32(system):
+    csr, _, _ = system
+    part = partition_rows(csr, 8, seed=0)
+    return DistributedProblem.build(csr, part, 8, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("tier", ["single", "dist8"])
+def test_f32_pipelined_chunk_parity_carries_the_replacement(
+        system, prob8_f32, tmp_path, tier):
+    """Over f32 vectors the pipelined recurrence replaces residuals; its
+    window (the largest ||r||^2 since the last replacement, and a
+    replacement pending at the chunk's last iteration) rides the
+    checkpoint carry, so 16-iteration chunks -- shorter than a residual
+    decade -- keep the uninterrupted trajectory bitwise, with the same
+    replacements, and a resume continues it to the same count."""
+    csr, _, b = system
+    crit = StoppingCriteria(residual_rtol=1e-6, maxits=2000)
+
+    def make(**kw):
+        if tier == "single":
+            A = device_matrix_from_csr(csr, dtype=jnp.float32)
+            return JaxCGSolver(A, pipelined=True, **kw)
+        return DistCGSolver(prob8_f32, pipelined=True, **kw)
+
+    ref = make()
+    x_ref = np.asarray(ref.solve(b, criteria=crit))
+    it_ref, nrep = ref.stats.niterations, ref.stats.nreplacements
+    assert nrep >= 3 and it_ref > 3 * 16
+
+    p = str(tmp_path / "ck")
+    s1 = make(ckpt=CheckpointConfig(path=p, every=16))
+    x_ck = np.asarray(s1.solve(b, criteria=crit))
+    assert np.array_equal(x_ref, x_ck)
+    assert s1.stats.niterations == it_ref
+    assert s1.stats.nreplacements == nrep
+
+    snap = load_snapshot(p)
+    assert {"gmax", "pending"} <= set(snap.arrays)
+    s2 = make(ckpt=CheckpointConfig(resume=snap))
+    x_rs = np.asarray(s2.solve(b, criteria=crit))
+    assert snap.iteration + s2.stats.niterations == it_ref
+    assert np.array_equal(x_rs, x_ref)
 
 
 # -- CA recurrence checkpoint carry (ROADMAP 4c, ISSUE 16) ----------------

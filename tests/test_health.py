@@ -121,11 +121,28 @@ def test_stall_and_trip_primitives():
 
 # -- the audit oracle: pipelined drift vs classic, f64 ground truth -------
 
-def test_fp32_pipelined_gap_exceeds_classic(aniso_csr):
+@pytest.fixture
+def unreplaced(monkeypatch):
+    """The pipelined recurrence without the residual replacement it
+    runs over f32 vectors (recurrence.REPLACE_DROP emptied): the drift
+    the audit exists to see.  The rule is read while a program traces,
+    so compiled programs are dropped on the way in and out."""
+    import jax
+
+    from acg_tpu import recurrence
+
+    jax.clear_caches()
+    monkeypatch.setattr(recurrence, "REPLACE_DROP", {})
+    yield
+    jax.clear_caches()
+
+
+def test_fp32_pipelined_gap_exceeds_classic(aniso_csr, unreplaced):
     """The communication-hiding trade-off, measured: at the same f32
-    budget on the ill-conditioned family, the pipelined recurrences
-    drift measurably further from b - Ax than classic CG's -- both by
-    the in-loop audit and by independent f64 host arithmetic."""
+    budget on the ill-conditioned family, the unreplaced pipelined
+    recurrence drifts measurably further from b - Ax than classic CG's
+    -- both by the in-loop audit and by independent f64 host
+    arithmetic."""
     import jax.numpy as jnp
 
     n = aniso_csr.shape[0]
@@ -152,7 +169,31 @@ def test_fp32_pipelined_gap_exceeds_classic(aniso_csr):
     assert true_gaps[True] <= 2.0 * gaps[True] + 1e-6
 
 
-def test_on_gap_replace_recovers_to_tolerance(aniso_csr):
+def test_fp32_pipelined_replaced_gap_within_classic(aniso_csr):
+    """With its residual replacement (f32 vectors) the pipelined
+    recurrence ends no further from b - Ax than classic CG, by the
+    audit and by f64 host arithmetic, and it replaced at least once."""
+    import jax.numpy as jnp
+
+    b = np.ones(aniso_csr.shape[0])
+    gaps, true_gaps = {}, {}
+    for pipelined in (False, True):
+        s = _jax_solver(aniso_csr, dtype=jnp.float32, pipelined=pipelined,
+                        health=health.make_spec(every=10))
+        x = s.solve(b, criteria=StoppingCriteria(maxits=400,
+                                                 residual_rtol=1e-6),
+                    raise_on_divergence=False)
+        gaps[pipelined] = s.stats.health["gap_last"]
+        rtrue = float(np.linalg.norm(b - aniso_csr
+                                     @ np.asarray(x, np.float64)))
+        true_gaps[pipelined] = abs(rtrue - s.stats.rnrm2) / np.linalg.norm(b)
+        if pipelined:
+            assert s.stats.nreplacements >= 1
+    assert gaps[True] <= 2.0 * gaps[False], (gaps, true_gaps)
+    assert true_gaps[True] <= 2.0 * true_gaps[False], (gaps, true_gaps)
+
+
+def test_on_gap_replace_recovers_to_tolerance(aniso_csr, unreplaced):
     """--on-gap replace: the gap trip exits through the breakdown path
     and the recovery driver's restart recomputes the true residual (a
     residual-replacement restart) -- the f32 pipelined solve then

@@ -46,6 +46,26 @@ def _counts(txt):
             len(re.findall(r"stablehlo\.while|\bwhile\b", txt)))
 
 
+def test_collective_counts_f32_pipelined_replacement():
+    """The f32 pipelined program (residual replacement armed,
+    recurrence.replaces): ONE fused in-loop allreduce -- five
+    scalars now -- in each of the loop's two legs (the first leg is
+    peeled, recurrence._iterate) beside the 4 setup psums; the
+    replacement's four SpMVs, each with its halo, in the loop between
+    the legs and under no conditional: 2 setup + 2 in-loop + 4
+    replacement all_to_alls, three while loops."""
+    r, c, v, N = poisson2d_coo(16)
+    csr = SymCsrMatrix.from_coo(N, r, c, v).to_csr()
+    part = partition_rows(csr, 4, seed=0, method="band")
+    prob32 = DistributedProblem.build(csr, part, 4, dtype=jnp.float32)
+    txt = _lowered_text(prob32, pipelined=True)
+    ar, ata, wl = _counts(txt)
+    assert wl == 3
+    assert ar == 6, f"f32 pipelined program has {ar} all_reduces"
+    assert ata == 8, f"f32 pipelined program has {ata} all_to_alls"
+    assert not re.findall(r"stablehlo\.case", txt)
+
+
 def test_collective_counts(prob):
     """Static collective inventory of the whole-solve programs.
 

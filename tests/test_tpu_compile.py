@@ -177,3 +177,73 @@ def test_dma_exchange_compiles_on_four_chips(topo, tpu_compile, maxcnt):
         fn, jax.ShapeDtypeStruct((4, 4, maxcnt), jnp.float32, sharding=sh),
         *(jax.ShapeDtypeStruct((4, 4), jnp.int32, sharding=sh),) * 2)
     assert "tpu_custom_call" in text
+
+
+def _exchange_order(text: str) -> dict:
+    """Per computation of a compiled program, its one-sided halo
+    kernels (``acg_halo_dma``) and all-reduces in schedule order."""
+    import re
+
+    order, comp = {}, None
+    for line in text.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            comp = line.split(" ")[0].lstrip("%")
+            continue
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = .*? (all-reduce|custom-call)\(",
+                     line)
+        if m and (m[2] == "all-reduce" or m[1].startswith("acg_halo_dma")):
+            order.setdefault(comp, []).append(m[2])
+    return order
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_dma_exchanges_are_separated_by_a_reduction(topo, tpu_compile,
+                                                    pipelined):
+    """The mesh solve over ``--comm dma`` compiled for four v5e chips: in
+    every computation of the program (set-up, loop bodies, the
+    pipelined recurrence's residual replacement) no two one-sided halo
+    kernels run back to back -- an all-reduce lies between each pair,
+    so no part enters an exchange while a neighbour is still inside the
+    previous one (halo_exchange_dma's ``after``).  A computation's first
+    exchange follows the reduction or the loop boundary before it."""
+    from jax.sharding import Mesh
+
+    from acg_tpu.graph import partition_matrix
+    from acg_tpu.io.generators import poisson_mtx
+    from acg_tpu.matrix import SymCsrMatrix
+    from acg_tpu.parallel.dist import DistCGSolver, DistributedProblem
+    from acg_tpu.parallel.mesh import solve_mesh
+    from acg_tpu.partition import partition_rows
+
+    csr = SymCsrMatrix.from_mtx(poisson_mtx(64, dim=2)).to_csr()
+    part = partition_rows(csr, 4, seed=0, method="graph", use_metis="never")
+    prob = DistributedProblem.build(csr, part, 4, dtype=jnp.float32,
+                                    subs=partition_matrix(csr, part, 4),
+                                    vector_dtype=jnp.float32)
+    b = np.ones(csr.shape[0], np.float32)
+    # the argument shapes from a CPU mesh, the program for the chips
+    dev = DistCGSolver(prob, comm="dma", pipelined=pipelined,
+                       mesh=solve_mesh(4)).device_args(b, None)
+    mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
+    solver = DistCGSolver(prob, comm="dma", pipelined=pipelined, mesh=mesh)
+    sh = NamedSharding(mesh, P(PARTS_AXIS))
+    rep = NamedSharding(mesh, P())
+    b_, x0, la, ga, sidx, gsrc, gval, scnt, rcnt = (
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=sh), a)
+        for a in dev)
+    program = solver._program_for(None)
+
+    def fn(*args):
+        return program(*args, unbounded=False, needs_diff=False)
+
+    text = tpu_compile(
+        fn, la, ga, sidx, gsrc, gval, scnt, rcnt, b_, x0,
+        jax.ShapeDtypeStruct((4,), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
+    order = _exchange_order(text)
+    kernels = sum(ops.count("custom-call") for ops in order.values())
+    assert kernels >= (7 if pipelined else 2)
+    for comp, ops in order.items():
+        pairs = zip(ops, ops[1:])
+        assert ("custom-call", "custom-call") not in pairs, (comp, ops)

@@ -67,6 +67,23 @@ def test_loop_ops_carry_acg_scopes(dia, kind):
     assert "acg.halo" not in scopes_under(names, "jit(")
 
 
+def test_pipelined_replacement_carries_acg_replace_scope(dia):
+    """The f32 pipelined program replaces residuals: the conditional
+    and the four SpMVs of its replacement branch sit under acg.replace
+    inside the loop body, the SpMVs under acg.replace/acg.spmv."""
+    from acg_tpu.solvers import jax_cg as jc
+
+    b = jnp.ones(dia.nrows, jnp.float32)
+    z = jnp.float32(0.0)
+    names = op_names(jc._cg_pipelined_program.lower(
+        dia, b, jnp.zeros_like(b), z, jnp.float32(1e-6), z, z,
+        jnp.int32(20), unbounded=False, needs_diff=False,
+        kernels="xla").compile().as_text())
+    assert "acg.replace" in scopes_under(names, BODY)
+    assert "acg.spmv" in scopes_under(names, "acg.replace/")
+    assert "acg.replace" not in scopes_under(names, "acg.setup/")
+
+
 def test_sharded_pallas_roll_carries_halo_scope():
     """The four-shard pallas-roll program (shard_map, ppermute halo,
     interpreted kernel per shard): the permutes and the window
@@ -220,6 +237,27 @@ def test_mesh_loop_ops_carry_acg_scopes(mesh_prob, pipelined):
                for n in names), sorted(names)
     halo = [n for n in names if BODY in n and "acg.halo" in n]
     assert any(n.endswith("/all_to_all") for n in halo), halo
+
+
+def test_dma_mesh_pipelined_program_names_its_halo_puts(mesh_prob):
+    """The four-part pipelined program over the one-sided DMA halo
+    (interpreted on the CPU mesh): the puts are the ``acg_halo_dma``
+    kernel under acg.halo in the loop, and the replacement branch runs
+    its SpMVs, halo included, under acg.replace."""
+    from acg_tpu.parallel.dist import DistCGSolver
+    from acg_tpu.parallel.mesh import solve_mesh
+
+    solver = DistCGSolver(mesh_prob, comm="dma", mesh=solve_mesh(4),
+                          pipelined=True)
+    names = op_names(solver.lower_solve(
+        np.ones(mesh_prob.n), criteria=StoppingCriteria(
+            maxits=200, residual_rtol=1e-6)).compile().as_text())
+    assert {"acg.halo", "acg.replace"} <= scopes_under(names, BODY)
+    halo = [n.partition(BODY)[2] for n in names if BODY in n]
+    assert any(n.startswith("acg.halo/") and "/acg_halo_dma/" in n
+               for n in halo), sorted(halo)
+    assert any(n.startswith("acg.replace/") and "/acg.halo/" in n
+               and "/acg_halo_dma/" in n for n in halo), sorted(halo)
 
 
 @pytest.mark.parametrize("host_result", [False, True])
